@@ -31,9 +31,9 @@ from typing import Callable
 
 import jax
 from jax import numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ....framework import flags as _flags
-from ....framework.jax_compat import shard_map as _shard_map
 
 _flags.define_flag(
     "FLAGS_pipeline_double_buffer",

@@ -47,12 +47,12 @@ from typing import Optional
 
 import jax
 from jax import numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ....core.apply import apply
 from ....core.tensor import Tensor
 from ....framework import flags as _flags
-from ....framework.jax_compat import shard_map as _shard_map
 
 _flags.define_flag(
     "FLAGS_collective_matmul",

@@ -21,15 +21,29 @@ class Generator:
     def __init__(self, seed: int = 0):
         self._lock = threading.Lock()
         self._seed = seed
-        self._key = jax.random.PRNGKey(seed)
+        # built on first use: a PRNGKey is a device array, and importing the
+        # framework must not initialise a backend (a spawned DataLoader
+        # worker or a launcher parent imports it on a host whose chip
+        # belongs to another process)
+        self._lazy_key = None
         # trace-scope state: (base_key_tracer, counter) or None
         self._trace_base = None
         self._trace_counter = 0
 
+    @property
+    def _key(self):
+        if self._lazy_key is None:
+            self._lazy_key = jax.random.PRNGKey(self._seed)
+        return self._lazy_key
+
+    @_key.setter
+    def _key(self, key):
+        self._lazy_key = key
+
     def manual_seed(self, seed: int):
         with self._lock:
             self._seed = int(seed)
-            self._key = jax.random.PRNGKey(self._seed)
+            self._lazy_key = None
         return self
 
     def initial_seed(self) -> int:

@@ -36,7 +36,7 @@ Round 17 — prefix sharing + int8 storage:
   machinery guards every write range (scheduler growth loop) and is what
   makes speculative-decode rollback and evacuate-resume races safe.
 - int8 KV (`kv_dtype="int8"`): pages store int8 with per-slot-per-kv-head
-  f32 scale planes `[N, bs, Hkv]` alongside — written slots are quantized
+  f32 scale planes `[N, Hkv, bs]` alongside — written slots are quantized
   with the absmax observer rule (quantization/observers.absmax_scale — the
   SAME math, not a fork) and dequantized on read inside the paged-attention
   kernel/reference. ~4x pages per pool byte at head_dim 64 (scale overhead
@@ -130,7 +130,7 @@ class PagedCacheView:
     the updated arrays and the engine adopts them into the pool.
 
     Quantized pools add per-layer scale planes (k_scales/v_scales,
-    [N, bs, Hkv] f32): `write` quantizes each slot with the absmax observer
+    [N, Hkv, bs] f32): `write` quantizes each slot with the absmax observer
     rule and scatters value + scale together. `write_mask` [B, S] bool
     (optional) redirects masked positions' writes to the trash page — the
     engine's extend/verify program uses it to neutralize pad queries.
@@ -171,7 +171,9 @@ class PagedCacheView:
 
         k_new/v_new [B, S, Hkv, D]; positions [B, S] int32 absolute token
         positions. Position p of row b lands in page block_tables[b, p//bs]
-        slot p % bs; positions past a row's real pages hit table padding
+        slot p % bs (pages are [N, Hkv, bs, D]: the page and slot indices
+        straddle the head axis, so the scattered update is [B, S, Hkv, D] —
+        exactly k_new's layout); positions past a row's real pages hit table padding
         (the trash page) by construction, and write_mask=False positions
         are redirected to the trash page explicitly.
         """
@@ -191,20 +193,22 @@ class PagedCacheView:
             v_sc = absmax_scale(v_new, axis=-1)
             k_q = quantize_absmax(k_new, k_sc[..., None])
             v_q = quantize_absmax(v_new, v_sc[..., None])
-            self.k_pages[idx] = self.k_pages[idx].at[pages, slots].set(k_q)
-            self.v_pages[idx] = self.v_pages[idx].at[pages, slots].set(v_q)
-            self.k_scales[idx] = self.k_scales[idx].at[pages, slots].set(k_sc)
-            self.v_scales[idx] = self.v_scales[idx].at[pages, slots].set(v_sc)
+            self.k_pages[idx] = self.k_pages[idx].at[pages, :, slots].set(k_q)
+            self.v_pages[idx] = self.v_pages[idx].at[pages, :, slots].set(v_q)
+            self.k_scales[idx] = self.k_scales[idx].at[pages, :, slots].set(k_sc)
+            self.v_scales[idx] = self.v_scales[idx].at[pages, :, slots].set(v_sc)
         else:
-            self.k_pages[idx] = self.k_pages[idx].at[pages, slots].set(k_new)
-            self.v_pages[idx] = self.v_pages[idx].at[pages, slots].set(v_new)
+            self.k_pages[idx] = self.k_pages[idx].at[pages, :, slots].set(k_new)
+            self.v_pages[idx] = self.v_pages[idx].at[pages, :, slots].set(v_new)
 
 
 class BlockPool:
     """Preallocated paged KV pool + host free-list allocator.
 
     Device layout: per layer, k/v pages of shape
-    [num_blocks, block_size, num_kv_heads, head_dim]. `num_blocks` INCLUDES
+    [num_blocks, num_kv_heads, block_size, head_dim] (kv-head major: the
+    paged kernel fetches one (block_size, head_dim) tile per page and
+    head). `num_blocks` INCLUDES
     the reserved trash page 0; usable capacity is num_blocks - 1 pages.
     `kv_dtype="int8"` stores int8 pages with f32 scale planes alongside.
     """
@@ -224,7 +228,7 @@ class BlockPool:
         self.kv_dtype = kv_dtype
         self.compute_dtype = dtype
         self.dtype = jnp.int8 if kv_dtype == "int8" else dtype
-        shape = (self.num_blocks, self.block_size, self.num_kv_heads, self.head_dim)
+        shape = (self.num_blocks, self.num_kv_heads, self.block_size, self.head_dim)
         self.k_pages: List = [jnp.zeros(shape, self.dtype) for _ in range(self.num_layers)]
         self.v_pages: List = [jnp.zeros(shape, self.dtype) for _ in range(self.num_layers)]
         if kv_dtype == "int8":
@@ -627,7 +631,7 @@ def convert_payload(payload: Dict, kv_dtype: Optional[str]) -> Dict:
         for plane, scale_key in (("k", "k_scale"), ("v", "v_scale")):
             for arr in payload[plane]:
                 x = jnp.asarray(arr)
-                sc = absmax_scale(x, axis=-1)  # [n, bs, Hkv] f32
+                sc = absmax_scale(x, axis=-1)  # [n, Hkv, bs] f32
                 out[plane].append(np.asarray(quantize_absmax(x, sc[..., None])))
                 out[scale_key].append(np.asarray(sc))
         return out

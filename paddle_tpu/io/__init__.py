@@ -473,6 +473,12 @@ def _mp_worker_main(task_q, out_q, dataset, collate_fn, use_np_default, worker_i
     second accelerator client per process (documented DataLoader contract)."""
     import pickle
 
+    import jax
+
+    # the chip belongs to the parent: whatever dataset/collate code does in
+    # here, this process may only ever see the host platform (importing the
+    # framework initialises no backend, so the pin lands first)
+    jax.config.update("jax_platforms", "cpu")
     try:
         if worker_init_fn is not None:
             worker_init_fn(w)

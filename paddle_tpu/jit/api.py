@@ -503,7 +503,21 @@ class _CompiledEntry:
         from ..framework import flags as _flags
 
         self.donated = bool(_flags.get_flag("FLAGS_to_static_donate"))
-        self.jitted = jax.jit(pure, donate_argnums=(1, 3) if self.donated else ())
+        # State THREADS through the step: entry i of new_state is written
+        # back and fed to the next call as state_vals[i]. On a mesh, pin
+        # each sharded entry's output to its input sharding — left to
+        # GSPMD, an updated param can come back laid out differently and the
+        # AOT-compiled step then rejects its own output on the next call.
+        pinned = [
+            sh if sh is not None and len(sh.device_set) > 1 else None
+            for sh in (getattr(t._value, "sharding", None) for t in state)
+        ]
+        jit_kwargs = {}
+        if any(sh is not None for sh in pinned):
+            jit_kwargs["out_shardings"] = (None, pinned, None)
+        self.jitted = jax.jit(
+            pure, donate_argnums=(1, 3) if self.donated else (), **jit_kwargs
+        )
 
     def _rebuild_out(self, out_raw):
         return _unflatten_output(out_raw, self.out_spec)

@@ -2,12 +2,15 @@
 
 The shared library is built on first import with g++ (no pybind11 in the
 image; plain C ABI). Build artifacts live next to the source under _build/
-keyed by source mtime, so a source change rebuilds automatically.
+keyed by a hash of the source, so a source change rebuilds automatically and
+a copied checkout (whose mtimes mean nothing) can never load a library built
+from other source.
 Set PADDLE_TPU_NO_NATIVE=1 to disable (pure-Python fallbacks are used).
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -25,7 +28,8 @@ class NativeUnavailable(RuntimeError):
 
 def _build() -> str:
     os.makedirs(_build_dir, exist_ok=True)
-    stamp = int(os.path.getmtime(_src))
+    with open(_src, "rb") as f:
+        stamp = hashlib.sha256(f.read()).hexdigest()[:16]
     so_path = os.path.join(_build_dir, f"libpaddle_tpu_core.{stamp}.so")
     if os.path.exists(so_path):
         return so_path

@@ -159,7 +159,7 @@ def _pallas_apply(p, m, v, g, scal, seed, beta1, beta2, eps, wd, decoupled, m2_b
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
             jax.ShapeDtypeStruct((rows, LANES), v.dtype),
         ],
-        compiler_params=_pk.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=_pk._INTERPRET,
@@ -220,5 +220,9 @@ def fused_adamw_apply(
     from . import pallas as _pk
 
     if _pk._on_tpu():
-        return _pallas_apply(*args)
+        # Mosaic rejects the i64 grid/index types the framework's global
+        # x64 would trace — like the flash and paged wrappers, trace the
+        # kernel with x64 off (every kernel dtype is explicit)
+        with jax.enable_x64(False):
+            return _pallas_apply(*args)
     return _reference_apply(*args)

@@ -28,6 +28,7 @@ from typing import Optional
 
 import jax
 from jax import lax
+from jax import shard_map as _shard_map
 from jax import numpy as jnp
 
 _NEG_INF = -1e30
@@ -98,7 +99,6 @@ def _ring_flash_local(q, k, v, *, axis_name, causal, sm_scale):
 
 
 from .pallas import repeat_kv as _repeat_kv  # shared GQA fallback helper
-from ..framework.jax_compat import shard_map as _shard_map
 
 
 def ring_attention_local(

@@ -22,6 +22,18 @@ from ..framework import dtype as dtype_mod
 from .lr import LRScheduler
 
 
+def _mesh_placement(t):
+    """The sharding of a tensor whose CONCRETE value is spread over more than
+    one device, else None (single-device values, and tracers inside a
+    compiled step — there the step's pinned output shardings hold state in
+    place, jit/api.py)."""
+    v = t._value
+    if isinstance(v, jax.core.Tracer):
+        return None
+    sh = getattr(v, "sharding", None)
+    return sh if sh is not None and len(sh.device_set) > 1 else None
+
+
 class Optimizer:
     def __init__(self, learning_rate=0.001, parameters=None, weight_decay=None, grad_clip=None, name=None):
         if parameters is None:
@@ -45,6 +57,10 @@ class Optimizer:
         # wrappers that need per-param accumulators (shard_optimizer, ZeRO
         # sharding) flip this off to force the per-param path
         self._fuse_allowed = True
+        # ids of params seen placed on a mesh (spec_layout.place): they take
+        # the per-param path too — remembered, because inside a compiled
+        # step's trace the value is a tracer and cannot say where it lives
+        self._mesh_placed: set = set()
 
     # ---- param groups ----
     def _build_param_groups(self, parameters):
@@ -92,7 +108,13 @@ class Optimizer:
             else:
                 shp = tuple(shape) if shape is not None else tuple(param._value.shape)
                 d = dtype or (jnp.float32 if param._value.dtype == jnp.bfloat16 else param._value.dtype)
-                self._accumulators[name][key] = Tensor(jnp.full(shp, fill, d))
+                acc = jnp.full(shp, fill, d)
+                placement = _mesh_placement(param)
+                if placement is not None and shp == tuple(param._value.shape):
+                    # state of a mesh-placed param is born where the param
+                    # lives, not on device 0
+                    acc = jax.device_put(acc, placement)
+                self._accumulators[name][key] = Tensor(acc)
         return self._accumulators[name][key]
 
     def _get_accumulator(self, name, param):
@@ -138,7 +160,28 @@ class Optimizer:
         self._sync_lr()
         self._step_count._replace_value(self._step_count._value + 1)
         for entries in self._collect_entries():
-            self._apply_entries(entries)
+            self._apply_entries(self._grads_like_params(entries))
+
+    def _grads_like_params(self, entries):
+        """Lay each mesh-placed param's grad out like the param before the
+        eager update. An eager op's result lands wherever XLA propagates its
+        operands' shardings, and backward leaves grads laid out as their
+        producing matmul chose; with param, moments (born on the param's
+        placement, _add_accumulator) and grad all on ONE sharding, every
+        update op keeps it, so a tensor placed by spec_layout.place stays
+        where it was put."""
+        out = []
+        for p, g, wd, s in entries:
+            placement = _mesh_placement(p)
+            if placement is not None:
+                self._mesh_placed.add(id(p))
+                gv = g._value
+                if (not isinstance(gv, jax.core.Tracer)
+                        and gv.shape == p._value.shape
+                        and not placement.is_equivalent_to(gv.sharding, gv.ndim)):
+                    g = Tensor(jax.device_put(gv, placement))
+            out.append((p, g, wd, s))
+        return out
 
     def _collect_groups(self):
         """Per param-group: (clip, [(param, grad, weight_decay, lr_scale)])
@@ -533,6 +576,7 @@ class Adam(Optimizer):
                 not isinstance(wd, L1Decay)
                 and p._value.dtype == jnp.float32
                 and getattr(p, "_dist_attr", None) is None
+                and id(p) not in self._mesh_placed
                 and tuple(g.value.shape) == tuple(p._value.shape)
             )
             if fusable:

@@ -387,45 +387,49 @@ def watermark() -> dict:
 # roofline
 # ---------------------------------------------------------------------------
 
-# per-chip bf16 matmul peak FLOP/s and HBM bandwidth (published numbers;
-# bench.py still CO-MEASURES its matmul peak — this table serves quick
-# attribution and the CPU fallback where nothing is co-measured)
+# per-chip bf16 matmul peak FLOP/s and HBM bandwidth, keyed by the lowercased
+# `jax.devices()[0].device_kind` (published numbers: Google Cloud TPU system
+# architecture pages; v5e = 197 TFLOP/s bf16, 819 GB/s). A device that is not
+# in the table is an error, not a default: a utilization against the wrong
+# peak is off by orders of magnitude, silently.
 DEFAULT_PEAK_TABLE = {
     "tpu v4": {"flops_per_s": 275e12, "bytes_per_s": 1.2e12},
     "tpu v5e": {"flops_per_s": 197e12, "bytes_per_s": 0.82e12},
     "tpu v5p": {"flops_per_s": 459e12, "bytes_per_s": 2.77e12},
     "tpu v6e": {"flops_per_s": 918e12, "bytes_per_s": 1.64e12},
-    # conservative single-socket host numbers so CPU runs report a finite,
-    # comparable utilization instead of failing the lookup
-    "cpu": {"flops_per_s": 1.0e11, "bytes_per_s": 5.0e10},
+}
+# device_kind spellings jax reports for a row of the table
+_DEVICE_KIND_ALIASES = {
+    "tpu v5 lite": "tpu v5e",
+    "tpu v5": "tpu v5p",
+    "tpu v6 lite": "tpu v6e",
 }
 
 
-def platform_name() -> str:
-    """Lowercased device kind ('tpu v4', 'cpu', ...)."""
-    try:
-        import jax
+class UnknownDeviceError(LookupError):
+    """No published peak for this device kind — roofline refuses to guess."""
 
-        d = jax.devices()[0]
-        kind = getattr(d, "device_kind", None) or d.platform
-        return str(kind).lower()
-    except Exception:
-        return "unknown"
+
+def platform_name() -> str:
+    """Lowercased device kind ('tpu v5 lite', 'cpu', ...)."""
+    import jax
+
+    return str(jax.devices()[0].device_kind).lower()
 
 
 def peak_for(platform: Optional[str] = None,
              peak_table: Optional[dict] = None) -> Tuple[str, dict]:
-    """(matched platform key, {flops_per_s, bytes_per_s}) with substring
-    matching ('TPU v4 lite' matches 'tpu v4') and a CPU fallback."""
+    """(table key, {flops_per_s, bytes_per_s}) for a device kind: an exact
+    row, or the row a known `device_kind` spelling names ('TPU v5 lite' is
+    the v5e). Anything else raises UnknownDeviceError."""
     table = peak_table if peak_table is not None else DEFAULT_PEAK_TABLE
     p = (platform or platform_name()).lower()
-    if p in table:
-        return p, dict(table[p])
-    for k in table:
-        if k != "cpu" and (k in p or p in k):
-            return k, dict(table[k])
-    fb = table.get("cpu", DEFAULT_PEAK_TABLE["cpu"])
-    return "cpu", dict(fb)
+    key = p if p in table else _DEVICE_KIND_ALIASES.get(p)
+    if key not in table:
+        raise UnknownDeviceError(
+            f"no published peak for device kind {p!r} (known: {sorted(table)})"
+        )
+    return key, dict(table[key])
 
 
 def roofline(flops, bytes_accessed, seconds, platform: Optional[str] = None,

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 
 from .core.apply import apply
 from .core.tensor import Tensor
-from .fft import _run
 
 
 def frame(x, frame_length, hop_length, axis=-1, name=None):
@@ -71,7 +70,7 @@ def stft(
             spec = spec / jnp.sqrt(jnp.asarray(n_fft, spec.real.dtype))
         return jnp.swapaxes(spec, -1, -2)  # [..., freq, num_frames]
 
-    return apply("stft", lambda v: _run(fn, v), x)
+    return apply("stft", fn, x)
 
 
 def istft(
@@ -118,4 +117,4 @@ def istft(
             out = out[..., :length]
         return out
 
-    return apply("istft", lambda v: _run(fn, v), x)
+    return apply("istft", fn, x)

@@ -1,0 +1,145 @@
+"""The main paths' Pallas kernels, compiled for a described TPU v5e.
+
+The sandbox has no chip, but the TPU's compiler is installed and compiles
+for a chip that is described and not attached (on-chip-measurement guide
+§2.3). Interpret mode accepts block shapes, scratch shapes and index types
+that Mosaic refuses — every kernel here passed its interpret-mode tests
+while two of them could not be lowered for the chip — so these compiles
+guard what the CPU tests cannot see, at no chip time: real widths, through
+the public entry points, with the framework's global x64 ON as callers
+have it. Nothing runs; a compile that passes is not a chip run
+(`python chip_smoke.py` is).
+
+The dispatch gate `_on_tpu()` still sees the CPU, so each test steers it
+with monkeypatch — in the test, not through an option of the program.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import paddle_tpu as paddle  # noqa: F401  (turns the global x64 on)
+from paddle_tpu.ops import fused_optimizer as fo
+from paddle_tpu.ops import pallas as pk
+
+KERNEL = "tpu_custom_call"
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """Sharding on one device of a described v5e 2x2; skip where the
+    installation cannot describe it."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / no such topology here
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _chip_compile_env(monkeypatch):
+    """Kernels dispatch as on a TPU; the persistent compilation cache stays
+    off (a described-device compile is written to it but cannot be read back
+    without a chip: the next run would warn and compile again)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    assert jax.config.jax_enable_x64, "the framework's global x64 must be on"
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _kernels(fn, *avals, **kw_avals):
+    """Compile fn for the described chip; count its Pallas kernels."""
+    return jax.jit(fn).lower(*avals, **kw_avals).compile().as_text().count(KERNEL)
+
+
+def _aval(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("shape_q, shape_kv, causal, dropout", [
+    # ERNIE seq 4096, heads 6x128, attention dropout 0.1: the 1024-wide
+    # tiles under the 40 MB _VMEM_LIMIT
+    ((2, 4096, 6, 128), (2, 4096, 6, 128), False, 0.1),
+    # decoder widths: GQA 32q/8kv at head 128, causal
+    ((1, 4096, 32, 128), (1, 4096, 8, 128), True, 0.0),
+    # ERNIE seq 128, heads 12x64 (explicit call; auto-dispatch gates S>=512)
+    ((64, 128, 12, 64), (64, 128, 12, 64), False, 0.0),
+], ids=["s4096_6x128_dropout", "gqa_32q8kv_causal", "s128_12x64"])
+def test_flash_fwd_bwd_compiles(one_chip, shape_q, shape_kv, causal, dropout):
+    def grads(q, k, v):
+        def loss(q, k, v):
+            out = pk.flash_attention_bshd(
+                q, k, v, causal=causal, dropout_p=dropout, dropout_seed=7)
+            return out.astype(jnp.float32).sum()
+
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    q = _aval(one_chip, shape_q, BF16)
+    kv = _aval(one_chip, shape_kv, BF16)
+    assert _kernels(grads, q, kv, kv) == 3  # fwd, dq, dkdv
+
+
+@pytest.mark.parametrize("q_len", [1, 4], ids=["decode", "extend_q4"])
+@pytest.mark.parametrize("pool_dtype, q_dtype", [
+    (BF16, BF16), (jnp.float32, jnp.float32), (jnp.int8, BF16),
+], ids=["bf16", "f32", "int8"])
+def test_paged_attention_compiles(one_chip, pool_dtype, q_dtype, q_len):
+    """Hidden-4096 widths (32q/8kv, head 128), block 16, pool [N, Hkv, bs, D]
+    — through flash_decode_paged / flash_decode_paged_multi."""
+    B, H, HKV, D, BS, N, M = 8, 32, 8, 128, 16, 256, 16
+    pages = _aval(one_chip, (N, HKV, BS, D), pool_dtype)
+    bt = _aval(one_chip, (B, M), jnp.int32)
+    scales = {}
+    if pool_dtype == jnp.int8:
+        sc = _aval(one_chip, (N, HKV, BS), jnp.float32)
+        scales = {"k_scales": sc, "v_scales": sc}
+    if q_len == 1:
+        fn = pk.flash_decode_paged
+        q = _aval(one_chip, (B, H, D), q_dtype)
+        where = _aval(one_chip, (B,), jnp.int32)  # seq_lens
+    else:
+        fn = pk.flash_decode_paged_multi
+        q = _aval(one_chip, (B, q_len, H, D), q_dtype)
+        where = _aval(one_chip, (B, q_len), jnp.int32)  # q_positions
+    assert _kernels(fn, q, pages, pages, bt, where, **scales) == 1
+
+
+@pytest.mark.parametrize("m2_dtype", [jnp.float32, BF16], ids=["m2_f32", "m2_bf16"])
+def test_fused_adamw_compiles_under_global_x64(one_chip, m2_dtype):
+    """Through the public fused_adamw_apply, traced as its callers trace it
+    (optimizer/fused_engine.py, static/executor.py): x64 on."""
+    n = fo.pad_to_tile(85_000_000)  # one ERNIE-3.0-base-sized f32 bucket
+
+    def fn(p, m, v, g, lr):
+        return fo.fused_adamw_apply(
+            p, m, v, g, lr=lr, clip_scale=1.0, c1=0.1, c2=0.001, seed=3,
+            beta1=0.9, beta2=0.999, eps=1e-8, wd=0.01)
+
+    f32 = lambda: _aval(one_chip, (n,), jnp.float32)  # noqa: E731
+    assert _kernels(fn, f32(), f32(), _aval(one_chip, (n,), m2_dtype), f32(),
+                    _aval(one_chip, (), jnp.float32)) == 1
+
+
+def test_fused_rms_norm_compiles(one_chip):
+    import paddle_tpu.incubate.nn.functional as IF
+    from paddle_tpu.core.tensor import Tensor
+
+    def fn(x, w):
+        return IF.fused_rms_norm(Tensor(x), Tensor(w)).value
+
+    assert _kernels(fn, _aval(one_chip, (4096, 4096), BF16),
+                    _aval(one_chip, (4096,), BF16)) == 1

@@ -51,3 +51,49 @@ def test_default_thread_route_unchanged():
     batches = list(dl)
     assert len(batches) == 8
     assert getattr(dl, "_mp_pool", None) is None  # never spawned
+
+
+class PlatformDS(Dataset):
+    """Reports, from inside the worker, which jax platform the worker is
+    held to (a dataset that touches jax there would start a backend)."""
+
+    def __len__(self):
+        return 4
+
+    def __getitem__(self, i):
+        import jax
+
+        pinned = jax.config.jax_platforms == "cpu"
+        return np.asarray([float(pinned), float(jax.default_backend() == "cpu")],
+                          np.float32)
+
+
+def test_spawned_workers_are_held_to_the_host_platform(monkeypatch):
+    """One process per chip: the chip belongs to the training process, so a
+    spawned worker pins jax to the CPU before any dataset code runs — by
+    itself, not by an environment the user happened to export."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    dl = DataLoader(PlatformDS(), batch_size=2, num_workers=1,
+                    persistent_workers=True)
+    rows = np.concatenate([b.numpy() for b in dl])
+    dl._mp_pool.shutdown()
+    assert rows.shape == (4, 2) and (rows == 1.0).all(), rows
+
+
+def test_importing_the_framework_starts_no_backend():
+    """A launcher parent, a bench parent or a spawned worker imports the
+    package on a host whose chip another process holds: the import (and
+    seeding the generator) must not initialise any jax backend."""
+    import subprocess
+    import sys
+
+    code = (
+        "import paddle_tpu, paddle_tpu.io, paddle_tpu.distributed.launch\n"
+        "paddle_tpu.seed(0)\n"
+        "from jax._src import xla_bridge\n"
+        "print('BACKENDS', sorted(xla_bridge._backends))\n"
+    )
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "BACKENDS []" in r.stdout, r.stdout

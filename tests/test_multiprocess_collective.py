@@ -56,10 +56,8 @@ local = np.asarray(
 garr = jax.make_array_from_process_local_data(
     NamedSharding(mesh, P("dp", None)), local, (8, 1)
 )
-from paddle_tpu.framework.jax_compat import shard_map
-
 total = jax.jit(
-    shard_map(
+    jax.shard_map(
         lambda x: jax.lax.psum(x, "dp"),
         mesh=mesh, in_specs=P("dp", None), out_specs=P(None, None),
     )

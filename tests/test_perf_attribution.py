@@ -277,8 +277,7 @@ def test_guardian_step_records_peak_hbm():
 # ---------------------------------------------------------------------------
 
 
-_FAKE_TABLE = {"faketpu": {"flops_per_s": 100.0, "bytes_per_s": 10.0},
-               "cpu": {"flops_per_s": 50.0, "bytes_per_s": 5.0}}
+_FAKE_TABLE = {"faketpu": {"flops_per_s": 100.0, "bytes_per_s": 10.0}}
 
 
 def test_roofline_math_against_pinned_table():
@@ -294,19 +293,38 @@ def test_roofline_math_against_pinned_table():
     assert r["hbm_util"] == pytest.approx(0.45)
     assert r["bound"] == "memory"
 
-    # substring platform matching + cpu fallback
-    assert pa.peak_for("FakeTPU pod", _FAKE_TABLE)[0] == "faketpu"
-    assert pa.peak_for("riscv", _FAKE_TABLE)[0] == "cpu"
+    assert pa.peak_for("FakeTPU", _FAKE_TABLE)[0] == "faketpu"  # case only
     with pytest.raises(ValueError):
-        pa.roofline(1.0, 1.0, 0.0, peak_table=_FAKE_TABLE)
+        pa.roofline(1.0, 1.0, 0.0, platform="faketpu", peak_table=_FAKE_TABLE)
 
 
-def test_default_peak_table_covers_this_platform():
-    plat, peak = pa.peak_for()
-    assert peak["flops_per_s"] > 0 and peak["bytes_per_s"] > 0
-    r = pa.roofline(1e9, 1e8, 0.01)
-    assert 0 < r["mfu"] < 10  # sane, finite
-    assert r["bound"] in ("compute", "memory")
+@pytest.mark.parametrize("kind, row", [
+    ("TPU v5 lite", "tpu v5e"),   # what jax.devices()[0].device_kind says on a v5e
+    ("TPU v5e", "tpu v5e"),
+    ("cpu", None),                # this sandbox: no published peak, no guess
+    ("TPU v5 lite pod", None),    # no substring matching
+])
+def test_peak_table_resolves_device_kind_or_raises(kind, row):
+    """A v5e is priced as a v5e (197 TFLOP/s bf16, 0.82 TB/s), and a device
+    the table does not know raises instead of borrowing another row."""
+    if row is None:
+        with pytest.raises(pa.UnknownDeviceError, match="no published peak"):
+            pa.peak_for(kind)
+        with pytest.raises(pa.UnknownDeviceError):
+            pa.roofline(1e9, 1e8, 0.01, platform=kind)
+        return
+    key, peak = pa.peak_for(kind)
+    assert key == row
+    assert peak == {"flops_per_s": 197e12, "bytes_per_s": 0.82e12}
+    r = pa.roofline(197e12, 0.41e12, 2.0, platform=kind)
+    assert r["mfu"] == pytest.approx(0.5) and r["hbm_util"] == pytest.approx(0.25)
+
+
+def test_peak_table_has_no_cpu_row_and_default_lookup_raises_here():
+    assert "cpu" not in pa.DEFAULT_PEAK_TABLE
+    assert pa.platform_name() == "cpu"  # the test mesh
+    with pytest.raises(pa.UnknownDeviceError):
+        pa.peak_for()
 
 
 # ---------------------------------------------------------------------------

@@ -203,7 +203,7 @@ def test_int8_kv_write_path_calls_observer_math(monkeypatch):
     view.write(0, k, v, np.arange(3, dtype=np.int32)[None])
     assert len(calls) == 2  # one absmax per written tensor (k and v)
     # and the stored values really sit on the observers' grid
-    slot = np.asarray(view.k_pages[0][pages[0], 0])
-    scale = np.asarray(view.k_scales[0][pages[0], 0])
+    slot = np.asarray(view.k_pages[0][pages[0], :, 0])
+    scale = np.asarray(view.k_scales[0][pages[0], :, 0])
     want = np.asarray(observers.quantize_absmax(k[0, 0], scale[:, None]))
     np.testing.assert_array_equal(slot, want)

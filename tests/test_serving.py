@@ -59,8 +59,8 @@ def test_paged_decode_kernel_vs_reference_vs_dense(dtype):
     rng = np.random.RandomState(0)
     B, H, HKV, D, BS, N, M = 3, 8, 2, 64, 16, 12, 4
     q = jnp.asarray(rng.randn(B, H, D), dtype)
-    kp = jnp.asarray(rng.randn(N, BS, HKV, D), dtype)
-    vp = jnp.asarray(rng.randn(N, BS, HKV, D), dtype)
+    kp = jnp.asarray(rng.randn(N, HKV, BS, D), dtype)
+    vp = jnp.asarray(rng.randn(N, HKV, BS, D), dtype)
     bt = np.zeros((B, M), np.int32)
     bt[0] = [7, 3, 11, TRASH_PAGE]   # deliberately out of order
     bt[1] = [5, 1, TRASH_PAGE, TRASH_PAGE]
@@ -85,8 +85,9 @@ def test_paged_decode_kernel_vs_reference_vs_dense(dtype):
     qf = np.asarray(q, np.float32)
     kf, vf = np.asarray(kp, np.float32), np.asarray(vp, np.float32)
     for b in range(B):
-        k_lin = kf[bt[b]].reshape(-1, HKV, D)[: sl[b]]
-        v_lin = vf[bt[b]].reshape(-1, HKV, D)[: sl[b]]
+        # pages are [N, Hkv, bs, D]: linearize to [S, Hkv, D]
+        k_lin = kf[bt[b]].transpose(0, 2, 1, 3).reshape(-1, HKV, D)[: sl[b]]
+        v_lin = vf[bt[b]].transpose(0, 2, 1, 3).reshape(-1, HKV, D)[: sl[b]]
         for h in range(H):
             lg = (qf[b, h] @ k_lin[:, h // group].T) / np.sqrt(D)
             p = np.exp(lg - lg.max())
@@ -654,8 +655,8 @@ def test_paged_extend_kernel_vs_reference_vs_single_query():
     rng = np.random.RandomState(21)
     B, Q, H, HKV, D, BS, N, M = 2, 3, 8, 2, 64, 16, 10, 4
     q = jnp.asarray(rng.randn(B, Q, H, D), jnp.float32)
-    kp = jnp.asarray(rng.randn(N, BS, HKV, D), jnp.float32)
-    vp = jnp.asarray(rng.randn(N, BS, HKV, D), jnp.float32)
+    kp = jnp.asarray(rng.randn(N, HKV, BS, D), jnp.float32)
+    vp = jnp.asarray(rng.randn(N, HKV, BS, D), jnp.float32)
     bt = np.asarray([[7, 3, 9, TRASH_PAGE], [5, 1, 2, 8]], np.int32)
     # per-row frontiers ending mid-page, consecutive positions per query
     qpos = np.asarray([[37, 38, 39], [14, 15, 16]], np.int32)
@@ -691,8 +692,8 @@ def test_paged_decode_int8_pinned_against_f32_oracle():
     rng = np.random.RandomState(22)
     B, H, HKV, D, BS, N, M = 3, 8, 2, 64, 16, 12, 4
     q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
-    kp = jnp.asarray(rng.randn(N, BS, HKV, D), jnp.float32)
-    vp = jnp.asarray(rng.randn(N, BS, HKV, D), jnp.float32)
+    kp = jnp.asarray(rng.randn(N, HKV, BS, D), jnp.float32)
+    vp = jnp.asarray(rng.randn(N, HKV, BS, D), jnp.float32)
     bt = np.asarray([[7, 3, 11, TRASH_PAGE], [5, 1, TRASH_PAGE, TRASH_PAGE],
                      [2, 9, 4, 6]], np.int32)
     sl = np.asarray([50, 17, 64], np.int32)
@@ -719,10 +720,10 @@ def test_paged_decode_int8_pinned_against_f32_oracle():
                                      k_scales=ks, v_scales=vs)
     np.testing.assert_allclose(np.asarray(gotm), np.asarray(refm),
                                rtol=2e-4, atol=2e-5)
-    # scale planes must match the pages' [N, bs, Hkv] — a mismatched plane
+    # scale planes must match the pages' [N, Hkv, bs] — a mismatched plane
     # is a wiring bug, not a broadcast
     with pytest.raises(ValueError, match="scale planes"):
-        pk.flash_decode_paged(q, kq, vq, bt, sl, k_scales=ks[:, :4], v_scales=vs)
+        pk.flash_decode_paged(q, kq, vq, bt, sl, k_scales=ks[:, :, :4], v_scales=vs)
     with pytest.raises(ValueError, match="come together"):
         pk.flash_decode_paged(q, kq, vq, bt, sl, k_scales=ks)
 
@@ -852,9 +853,9 @@ def test_block_pool_cow_make_private():
     rng = np.random.RandomState(25)
     for layer in range(2):
         pool.k_pages[layer] = pool.k_pages[layer].at[page].set(
-            jnp.asarray(rng.randint(-127, 127, (4, 2, 4)), jnp.int8))
+            jnp.asarray(rng.randint(-127, 127, (2, 4, 4)), jnp.int8))
         pool.k_scales[layer] = pool.k_scales[layer].at[page].set(
-            jnp.asarray(rng.rand(4, 2), jnp.float32))
+            jnp.asarray(rng.rand(2, 4), jnp.float32))
     pool.share([page])
     assert pool.refcount(page) == 2
     cow_before = pool.cow_copies
