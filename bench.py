@@ -211,8 +211,8 @@ def _ernie_dims():
 
 def build_train_step(batch, seq, heads, max_pos=None, attn_dropout=0.0):
     """The benchmark workload: ERNIE-3.0-base dims MLM + AdamW, bf16 AMP,
-    to_static. Shared with benchmarks/profile_xplane.py so the profiled
-    model is BY CONSTRUCTION the benchmarked model."""
+    to_static. (The device profile of this step is taken by
+    `chipbench/run.py --trace 1`, reduced by `chipbench/xplane.py`.)"""
     import numpy as np
 
     import paddle_tpu as paddle
@@ -1999,10 +1999,9 @@ def _build_resnet(steps):
 
 
 def build_resnet_step(batch):
-    """ResNet-50 train-step builder shared with benchmarks/profile_resnet.py
-    so the profiled model is BY CONSTRUCTION the benchmarked model (same
-    contract as build_train_step for the ERNIE configs). Returns
-    (model, static_step, eager_step, imgs, labels)."""
+    """ResNet-50 train-step builder (same contract as build_train_step for
+    the ERNIE configs). Returns (model, static_step, eager_step, imgs,
+    labels)."""
     import numpy as np
 
     import paddle_tpu as paddle

@@ -113,6 +113,7 @@ class GradNode:
         "released",
         "op_pure",
         "op_primals",
+        "scope",
     )
 
     def __init__(self, name: str, vjp_fn: Callable, edges: List[Edge], out_avals, single_output: bool,
@@ -131,6 +132,9 @@ class GradNode:
         # d(backward)/d(primal), this can. Recompute-based (jax-idiomatic).
         self.op_pure = op_pure
         self.op_primals = op_primals
+        # the named scopes the forward ran under: the pullback's ops carry
+        # the same path (state.named_scope)
+        self.scope = state.scope_path()
 
     def __repr__(self):
         return f"GradNode({self.name}, n_in={len(self.edges)}, n_out={len(self.out_avals)})"
@@ -282,7 +286,11 @@ def _run_backward_walk(tensors, grad_tensors, retain_graph, accumulate_fn,
                 "set retain_graph=True if you need to."
             )
         cot_struct = cots[0] if node.single_output else tuple(cots)
-        in_cots = node.vjp_fn(cot_struct)
+        if node.scope:
+            with jax.named_scope(node.scope):
+                in_cots = node.vjp_fn(cot_struct)
+        else:
+            in_cots = node.vjp_fn(cot_struct)
         if not retain_graph:
             node.vjp_fn = None
             # op_pure closes over the op's raw inputs and op_primals holds

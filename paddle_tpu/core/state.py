@@ -9,12 +9,15 @@ from __future__ import annotations
 import functools
 import threading
 
+import jax
+
 
 class _TLS(threading.local):
     def __init__(self):
         self.grad_enabled = True
         self.recorder = None  # active StateRecorder during to_static capture
         self.amp_state = None  # active AMP context (paddle_tpu.amp)
+        self.scope = ""  # path of the named scopes around the running op
 
 
 _tls = _TLS()
@@ -26,6 +29,35 @@ def is_grad_enabled() -> bool:
 
 def set_grad_enabled(mode: bool):
     _tls.grad_enabled = bool(mode)
+
+
+class named_scope:
+    """`jax.named_scope(name)` that the autograd tape can follow: every HLO
+    op traced inside carries the path of the scopes around it
+    (`.../encoder/layers.3/self_attn/...`), and so does its pullback, which
+    runs later and elsewhere: a GradNode keeps `scope_path()` and the
+    backward re-enters it. Compile-time metadata only."""
+
+    __slots__ = ("name", "_prev", "_ctx")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self):
+        self._prev = _tls.scope
+        _tls.scope = f"{self._prev}/{self.name}" if self._prev else self.name
+        self._ctx = jax.named_scope(self.name)
+        self._ctx.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ctx.__exit__(*exc)
+        _tls.scope = self._prev
+        return False
+
+
+def scope_path() -> str:
+    return _tls.scope
 
 
 class no_grad:

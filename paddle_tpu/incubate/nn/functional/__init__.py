@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ....core.apply import apply
+from ....core.state import named_scope
 from ....core.tensor import Tensor
 from ....ops import pallas as _pk
 
@@ -90,6 +91,7 @@ def _rms_norm_pallas_fwd_impl(x, w, b, eps, has_bias, interpret=False):
         out_specs=pl.BlockSpec((block_r, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), x.dtype),
         interpret=interpret,
+        name="rms_norm",
     )(x, w, bz)
 
 
@@ -450,7 +452,8 @@ def fused_linear_cross_entropy(
         return _flce(xf, wv, bv, lf, ignore_index, transpose_weight)
 
     args = [x, weight, labels] + ([bias] if bias is not None else [])
-    return apply("fused_linear_cross_entropy", fn, *args)
+    with named_scope("loss"):
+        return apply("fused_linear_cross_entropy", fn, *args)
 
 
 # ---------------------------------------------------------------------------

@@ -31,6 +31,7 @@ import jax
 from jax import numpy as jnp
 
 from .. import telemetry
+from ..profiler.utils import RecordEvent
 from ..telemetry import metrics as _metrics
 from ..telemetry import request_trace as _rt
 from .kv_cache import BlockPool, PagedCacheView
@@ -311,8 +312,20 @@ class InferenceEngine:
                 _rt.record_event("engine", "dispatch", kind=kind, size=sz,
                                  event="hit")
             return ex
+        # a miss inside a serving step is a named span in the step that
+        # paid for it
+        with RecordEvent("engine.compile", args={"kind": kind, "size": sz}) as span:
+            ex, outcome = self._compile_miss(kind, size, sz)
+            span.args["outcome"] = "compile" if outcome == "miss" else outcome
+        return ex
+
+    def _compile_miss(self, kind: str, size, sz):
+        """(executable, outcome) for a bucket this engine has not run yet:
+        shared from a same-signature replica, restored from the store, or
+        compiled (outcome "miss")."""
         from .. import compile_cache as _cc
 
+        key = (kind, size)
         name = f"{kind}_{sz}"
         t0 = time.perf_counter()
         fp, ekey = self._bucket_key(kind, size)
@@ -377,7 +390,7 @@ class InferenceEngine:
                     _cc.record("serving", name, "persist",
                                seconds=time.perf_counter() - tp,
                                fingerprint=fp, signature=sz)
-        return ex
+        return ex, outcome
 
     def prewarm(self, *, include_prefill: bool = True,
                 include_decode: bool = True,
@@ -587,16 +600,20 @@ class InferenceEngine:
         if L < 1 or L > self.max_seq_len:
             raise ValueError(f"prompt length {L} outside [1, {self.max_seq_len}]")
         S = self.bucket_for("prefill", L)
-        ids = np.zeros((1, S), np.int32)
-        ids[0, :L] = np.asarray(prompt_ids, np.int32)
-        bt = np.asarray([self.pool.padded_table(pages, self.max_pages)], np.int32)
-        ex = self._get_compiled("prefill", S)
-        logits, state = ex(
-            self.params, jnp.asarray(ids), jnp.asarray([L], jnp.int32),
-            jnp.asarray(bt), self.pool.device_state(),
-        )
-        self.pool.adopt_state(state)
-        out = np.asarray(logits[0])
+        with RecordEvent("engine.prefill", args={"tokens": L, "bucket": S}):
+            with RecordEvent("engine.prefill.inputs"):
+                ids = np.zeros((1, S), np.int32)
+                ids[0, :L] = np.asarray(prompt_ids, np.int32)
+                bt = np.asarray([self.pool.padded_table(pages, self.max_pages)], np.int32)
+            ex = self._get_compiled("prefill", S)
+            with RecordEvent("engine.prefill.dispatch"):
+                logits, state = ex(
+                    self.params, jnp.asarray(ids), jnp.asarray([L], jnp.int32),
+                    jnp.asarray(bt), self.pool.device_state(),
+                )
+                self.pool.adopt_state(state)
+            with RecordEvent("engine.prefill.fetch"):
+                out = np.asarray(logits[0])
         self._mark_first_token()
         return out
 
@@ -614,22 +631,27 @@ class InferenceEngine:
         if n < 1:
             raise ValueError("decode needs at least one sequence")
         B = self.bucket_for("decode", n)
-        tok = np.zeros((B,), np.int32)
-        pos = np.zeros((B,), np.int32)
-        lens = np.ones((B,), np.int32)  # inactive rows read 1 trash slot
-        bt = np.zeros((B, self.max_pages), np.int32)
-        tok[:n] = np.asarray(tokens, np.int32)
-        pos[:n] = np.asarray(positions, np.int32)
-        lens[:n] = np.asarray(seq_lens, np.int32)
-        for i, row in enumerate(page_rows):
-            bt[i] = self.pool.padded_table(row, self.max_pages)
-        ex = self._get_compiled("decode", B)
-        logits, state = ex(
-            self.params, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(lens),
-            jnp.asarray(bt), self.pool.device_state(),
-        )
-        self.pool.adopt_state(state)
-        out = np.asarray(logits[:n])
+        with RecordEvent("engine.decode", args={"rows": n, "bucket": B}) as span:
+            with RecordEvent("engine.decode.inputs"):
+                tok = np.zeros((B,), np.int32)
+                pos = np.zeros((B,), np.int32)
+                lens = np.ones((B,), np.int32)  # inactive rows read 1 trash slot
+                bt = np.zeros((B, self.max_pages), np.int32)
+                tok[:n] = np.asarray(tokens, np.int32)
+                pos[:n] = np.asarray(positions, np.int32)
+                lens[:n] = np.asarray(seq_lens, np.int32)
+                for i, row in enumerate(page_rows):
+                    bt[i] = self.pool.padded_table(row, self.max_pages)
+                span.args["context"] = int(lens[:n].sum())
+            ex = self._get_compiled("decode", B)
+            with RecordEvent("engine.decode.dispatch"):
+                logits, state = ex(
+                    self.params, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(lens),
+                    jnp.asarray(bt), self.pool.device_state(),
+                )
+                self.pool.adopt_state(state)
+            with RecordEvent("engine.decode.fetch"):
+                out = np.asarray(logits[:n])
         self._mark_first_token()
         return out
 
@@ -650,28 +672,33 @@ class InferenceEngine:
         if n < 1:
             raise ValueError("extend needs at least one sequence")
         B = self.bucket_for("decode", n)
-        tok = np.zeros((B, q_len), np.int32)
-        pos = np.zeros((B, q_len), np.int32)
-        valid = np.zeros((B, q_len), bool)
-        bt = np.zeros((B, self.max_pages), np.int32)
-        for i, (toks, poss) in enumerate(zip(token_rows, position_rows)):
-            r = len(toks)
-            if r < 1 or r > q_len:
-                raise ValueError(f"extend row {i}: {r} tokens outside [1, {q_len}]")
-            if len(poss) != r:
-                raise ValueError(f"extend row {i}: positions/tokens length mismatch")
-            tok[i, :r] = np.asarray(toks, np.int32)
-            pos[i, :r] = np.asarray(poss, np.int32)
-            valid[i, :r] = True
-        for i, row in enumerate(page_rows):
-            bt[i] = self.pool.padded_table(row, self.max_pages)
-        ex = self._get_compiled("extend", (B, q_len))
-        logits, state = ex(
-            self.params, jnp.asarray(tok), jnp.asarray(pos),
-            jnp.asarray(valid), jnp.asarray(bt), self.pool.device_state(),
-        )
-        self.pool.adopt_state(state)
-        out = np.asarray(logits[:n])
+        with RecordEvent("engine.extend", args={"rows": n, "bucket": B, "q_len": q_len}) as span:
+            with RecordEvent("engine.extend.inputs"):
+                tok = np.zeros((B, q_len), np.int32)
+                pos = np.zeros((B, q_len), np.int32)
+                valid = np.zeros((B, q_len), bool)
+                bt = np.zeros((B, self.max_pages), np.int32)
+                for i, (toks, poss) in enumerate(zip(token_rows, position_rows)):
+                    r = len(toks)
+                    if r < 1 or r > q_len:
+                        raise ValueError(f"extend row {i}: {r} tokens outside [1, {q_len}]")
+                    if len(poss) != r:
+                        raise ValueError(f"extend row {i}: positions/tokens length mismatch")
+                    tok[i, :r] = np.asarray(toks, np.int32)
+                    pos[i, :r] = np.asarray(poss, np.int32)
+                    valid[i, :r] = True
+                for i, row in enumerate(page_rows):
+                    bt[i] = self.pool.padded_table(row, self.max_pages)
+                span.args["context"] = int(pos.max(axis=1)[:n].sum()) + n
+            ex = self._get_compiled("extend", (B, q_len))
+            with RecordEvent("engine.extend.dispatch"):
+                logits, state = ex(
+                    self.params, jnp.asarray(tok), jnp.asarray(pos),
+                    jnp.asarray(valid), jnp.asarray(bt), self.pool.device_state(),
+                )
+                self.pool.adopt_state(state)
+            with RecordEvent("engine.extend.fetch"):
+                out = np.asarray(logits[:n])
         self._mark_first_token()
         return out
 

@@ -75,6 +75,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import telemetry
+from ..profiler.utils import RecordEvent, record_span
 from ..telemetry import metrics as _metrics
 from ..telemetry import request_trace as _rt
 from ..telemetry import timeline as _tl
@@ -216,6 +217,10 @@ class Request:
     submitted_time: Optional[float] = None
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
+    # (scheduler clock, admission mode, cached tokens) of the FIRST time
+    # the request held a decode slot: where its `request.queue` span ends
+    # and its `request.prompt` span starts
+    slot: Optional[tuple] = None
     token_times: List[float] = field(default_factory=list)
     # token-streamed admission: prompt tokens already written to the cache
     # (cursor == len(prompt) once the request is generating)
@@ -515,16 +520,22 @@ class ContinuousBatchingScheduler:
         """Per-request TTL: requests past their deadline_s (scheduler-clock
         seconds since submit) finish with outcome="expired" and free their
         pages right now — the serving-tier analogue of a dead client."""
-        for queue in (self.waiting, self.running):
-            for req in list(queue):
-                if (
-                    req.deadline_s is not None
-                    and req.submitted_time is not None
-                    and now - req.submitted_time > req.deadline_s
-                ):
-                    queue.remove(req)
-                    req.outcome = "expired"
-                    self._finish(req, now)
+        due = [
+            (queue, req)
+            for queue in (self.waiting, self.running) for req in queue
+            if (
+                req.deadline_s is not None
+                and req.submitted_time is not None
+                and now - req.submitted_time > req.deadline_s
+            )
+        ]
+        if not due:
+            return  # a sweep that finds nothing is no phase of the step
+        with RecordEvent("sched.expire", args={"expired": len(due)}):
+            for queue, req in due:
+                queue.remove(req)
+                req.outcome = "expired"
+                self._finish(req, now)
 
     def _reset_for_resume(self, req: Request) -> Request:
         """Recompute-on-resume bookkeeping shared by preemption and fleet
@@ -638,6 +649,11 @@ class ContinuousBatchingScheduler:
             req.trace.phase("decode", now)
         if req.first_token_time is None:
             req.first_token_time = now
+            if req.slot is not None:
+                t_slot, mode, cached = req.slot
+                record_span("request.prompt", t_slot, now, ident=req.rid,
+                            args={"mode": mode, "prompt_len": req.prompt_len,
+                                  "cached": cached})
             if telemetry.enabled() and req.submitted_time is not None:
                 # both timestamps from the scheduler clock: queue wait
                 # inside the scheduler is included, replay-offset arrival
@@ -700,6 +716,7 @@ class ContinuousBatchingScheduler:
                 self.waiting.pop(idx)
                 self._qos_on_admit(req)
                 req.pages = pool.alloc(need, owner=req.rid)
+                self._note_slot(req, "bucketed")
                 if req.trace is not None:
                     self._trace_admit(req, mode="bucketed")
                 logits = self.engine.prefill(req.prompt, req.pages)
@@ -731,11 +748,24 @@ class ContinuousBatchingScheduler:
         req._registered_pages = len(shared)
         req._chain_digest = keys[len(shared) - 1] if shared else b""
         self.running.append(req)
+        self._note_slot(req, "streamed", cached)
         if req.trace is not None:
             self._trace_admit(req, mode="streamed", cached=cached)
         if telemetry.enabled():
             _req_counter().labels(event="admitted", reason="").inc()
         return 0
+
+    def _note_slot(self, req: Request, mode: str, cached: int = 0) -> None:
+        """The request holds a decode slot from now. On its first admission
+        its wait in the queue ends: one `request.queue` span, submit to
+        here, on the scheduler's clock (`perf_counter` in a server, so the
+        span lies beside the others of the ring)."""
+        if req.slot is not None:
+            return
+        now = self.clock()
+        req.slot = (now, mode, cached)
+        if req.submitted_time is not None:
+            record_span("request.queue", req.submitted_time, now, ident=req.rid)
 
     def _trace_admit(self, req: Request, mode: str, cached: int = 0) -> None:
         """Open the prefill span; `recompute_tokens` counts the generated
@@ -918,21 +948,24 @@ class ContinuousBatchingScheduler:
         byte-identical, so this degrades only step count), and blend this
         tick's wall into `ewma_step_s` — the drain estimate the
         deadline/retry-after hints run on."""
-        t_start = self.clock()
-        if self.qos is not None:
-            self._qos_pre_step(t_start)
-        spec_saved = self.spec
-        if (self.spec is not None and self.qos is not None
-                and not self.qos.brownout.spec_allowed()):
-            self.spec = None
-        try:
-            produced = self._step_inner()
-        finally:
-            self.spec = spec_saved
-        dt = self.clock() - t_start
-        if dt > 0.0:
-            self.ewma_step_s = (dt if self.ewma_step_s is None
-                                else 0.8 * self.ewma_step_s + 0.2 * dt)
+        with RecordEvent("sched.step") as span:
+            t_start = self.clock()
+            if self.qos is not None:
+                self._qos_pre_step(t_start)
+            spec_saved = self.spec
+            if (self.spec is not None and self.qos is not None
+                    and not self.qos.brownout.spec_allowed()):
+                self.spec = None
+            try:
+                produced = self._step_inner()
+            finally:
+                self.spec = spec_saved
+            dt = self.clock() - t_start
+            if dt > 0.0:
+                self.ewma_step_s = (dt if self.ewma_step_s is None
+                                    else 0.8 * self.ewma_step_s + 0.2 * dt)
+            span.args = {"produced": produced, "running": len(self.running),
+                         "waiting": len(self.waiting)}
         return produced
 
     def _qos_pre_step(self, now: float) -> None:
@@ -975,107 +1008,116 @@ class ContinuousBatchingScheduler:
         # admission: fill free decode slots from the waiting line; a
         # blocked high-priority head may preempt a strictly lower-class
         # running victim (its pages free, admission retries)
-        while True:
-            emitted = self._try_admit()
-            if emitted is not None:
-                produced += emitted
-                continue
-            if not self._qos_priority_preempt():
-                break
+        if self.waiting:  # an empty line is no phase of the step
+            with RecordEvent("sched.admit"):
+                while True:
+                    emitted = self._try_admit()
+                    if emitted is not None:
+                        produced += emitted
+                        continue
+                    if not self._qos_priority_preempt():
+                        break
 
         if not self.running:
             if telemetry.enabled():
                 self._sync_gauges()
             return produced
 
-        # speculative plans first: growth must cover every position the
-        # draft chain will write, not just the next token
-        plans: Dict[int, Tuple[str, List[int], List[int]]] = {}
-        if self.spec is not None:
-            for req in self.running:
-                plans[req.rid] = self._plan_row(req)
-
-        # growth: every running sequence needs pages covering the K/V slots
-        # this step writes; allocate at block boundaries, preempting until
-        # the pool yields one
-        pool = self.engine.pool
-        for req in list(self.running):
-            if req not in self.running:
-                # evicted by an earlier iteration's preemption — allocating
-                # into it now would leak the page at re-admission
-                continue
+        with RecordEvent("sched.grow"):
+            # speculative plans first: growth must cover every position the
+            # draft chain will write, not just the next token
+            plans: Dict[int, Tuple[str, List[int], List[int]]] = {}
             if self.spec is not None:
-                need_tokens = plans[req.rid][2][-1] + 1
-            else:
-                need_tokens = self._tokens_needed(req)
-            if need_tokens > self.engine.max_seq_len:
-                # capacity guard (submit() bounds this; belt-and-braces)
-                self._finish(req, self.clock())
-                continue
-            while pool.blocks_for_tokens(need_tokens) > len(req.pages):
-                try:
-                    req.pages.extend(pool.alloc(1, owner=req.rid))
-                except PoolExhausted:
-                    if req in self.running and len(self.running) == 1:
-                        raise  # nothing left to evict but ourselves
-                    if not self._preempt_one():
-                        raise
-                    if req not in self.running:
-                        break  # we were the victim
-            # copy-on-write guard: no position this step writes may land in
-            # a page another request still reads. Full-page-aligned sharing
-            # makes this structurally unreachable in steady state, but the
-            # evacuate/resume and rollback races are exactly where a silent
-            # scribble would corrupt a neighbor — clone instead.
-            if req in self.running and req.pages:
+                for req in self.running:
+                    plans[req.rid] = self._plan_row(req)
+
+            # growth: every running sequence needs pages covering the K/V slots
+            # this step writes; allocate at block boundaries, preempting until
+            # the pool yields one
+            pool = self.engine.pool
+            for req in list(self.running):
+                if req not in self.running:
+                    # evicted by an earlier iteration's preemption — allocating
+                    # into it now would leak the page at re-admission
+                    continue
                 if self.spec is not None:
-                    _, _, poss = plans[req.rid]
-                    lo, hi = poss[0], poss[-1]
+                    need_tokens = plans[req.rid][2][-1] + 1
                 else:
-                    hi = self._tokens_needed(req) - 1
-                    lo = hi
-                for pi in range(lo // pool.block_size,
-                                min(hi // pool.block_size, len(req.pages) - 1) + 1):
-                    if pool.refcount(req.pages[pi]) > 1:
-                        req.pages[pi] = pool.make_private(req.pages[pi], owner=req.rid)
-        alive = [r for r in self.running if r.pages]
+                    need_tokens = self._tokens_needed(req)
+                if need_tokens > self.engine.max_seq_len:
+                    # capacity guard (submit() bounds this; belt-and-braces)
+                    self._finish(req, self.clock())
+                    continue
+                while pool.blocks_for_tokens(need_tokens) > len(req.pages):
+                    try:
+                        req.pages.extend(pool.alloc(1, owner=req.rid))
+                    except PoolExhausted:
+                        if req in self.running and len(self.running) == 1:
+                            raise  # nothing left to evict but ourselves
+                        if not self._preempt_one():
+                            raise
+                        if req not in self.running:
+                            break  # we were the victim
+                # copy-on-write guard: no position this step writes may land in
+                # a page another request still reads. Full-page-aligned sharing
+                # makes this structurally unreachable in steady state, but the
+                # evacuate/resume and rollback races are exactly where a silent
+                # scribble would corrupt a neighbor — clone instead.
+                if req in self.running and req.pages:
+                    if self.spec is not None:
+                        _, _, poss = plans[req.rid]
+                        lo, hi = poss[0], poss[-1]
+                    else:
+                        hi = self._tokens_needed(req) - 1
+                        lo = hi
+                    for pi in range(lo // pool.block_size,
+                                    min(hi // pool.block_size, len(req.pages) - 1) + 1):
+                        if pool.refcount(req.pages[pi]) > 1:
+                            req.pages[pi] = pool.make_private(req.pages[pi], owner=req.rid)
+            alive = [r for r in self.running if r.pages]
 
         if alive and self.spec is not None:
-            produced += self._spec_decode_step(alive, plans)
-            self.running = [r for r in self.running if not r.done]
+            with RecordEvent("sched.spec"):
+                produced += self._spec_decode_step(alive, plans)
+                self.running = [r for r in self.running if not r.done]
         elif alive:
-            rows = []
-            for r in alive:
-                if r.cursor < len(r.prompt):  # streaming its prompt in
-                    rows.append((r, r.prompt[r.cursor], r.cursor))
-                else:
-                    rows.append((r, r.generated[-1], r.context_len - 1))
+            with RecordEvent("sched.rows"):
+                rows = []
+                for r in alive:
+                    if r.cursor < len(r.prompt):  # streaming its prompt in
+                        rows.append((r, r.prompt[r.cursor], r.cursor))
+                    else:
+                        rows.append((r, r.generated[-1], r.context_len - 1))
+                tokens = [t for _, t, _ in rows]
+                positions = [p for _, _, p in rows]
+                seq_lens = [p + 1 for _, _, p in rows]
+                page_rows = [r.pages for r, _, _ in rows]
             logits = self.engine.decode(
-                tokens=[t for _, t, _ in rows],
-                positions=[p for _, _, p in rows],
-                seq_lens=[p + 1 for _, _, p in rows],
-                page_rows=[r.pages for r, _, _ in rows],
+                tokens=tokens, positions=positions, seq_lens=seq_lens,
+                page_rows=page_rows,
             )
-            now = self.clock()
-            for (r, _, _), lg in zip(rows, logits):
-                if r.cursor < len(r.prompt):
-                    r.cursor += 1
-                    if r.cursor == len(r.prompt):
-                        # the last prompt token's logits ARE the first
-                        # generated token
+            with RecordEvent("sched.emit"):
+                now = self.clock()
+                for (r, _, _), lg in zip(rows, logits):
+                    if r.cursor < len(r.prompt):
+                        r.cursor += 1
+                        if r.cursor == len(r.prompt):
+                            # the last prompt token's logits ARE the first
+                            # generated token
+                            self._emit_token(r, lg, now)
+                            produced += 1
+                    else:
                         self._emit_token(r, lg, now)
                         produced += 1
-                else:
-                    self._emit_token(r, lg, now)
-                    produced += 1
-            self.running = [r for r in self.running if not r.done]
-        if self.prefix_cache:
-            for r in self.running:
-                self._register_committed(r)
-        if telemetry.enabled():
-            self._sync_gauges()
-            active_tokens = sum(self._tokens_needed(r) for r in self.running)
-            pool.note_fragmentation(active_tokens)
+                self.running = [r for r in self.running if not r.done]
+        with RecordEvent("sched.publish"):
+            if self.prefix_cache:
+                for r in self.running:
+                    self._register_committed(r)
+            if telemetry.enabled():
+                self._sync_gauges()
+                active_tokens = sum(self._tokens_needed(r) for r in self.running)
+                pool.note_fragmentation(active_tokens)
         return produced
 
 

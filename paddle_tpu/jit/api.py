@@ -22,6 +22,7 @@ from jax import numpy as jnp, tree_util
 
 from ..core import state as core_state
 from ..core.tensor import Tensor
+from ..profiler.utils import RecordEvent
 from ..framework import random as random_mod
 
 
@@ -96,6 +97,7 @@ class StaticFunction:
     def __init__(self, fn: Callable, build_strategy=None, full_graph=True):
         self._fn = fn
         self._cache: dict = {}
+        self._calls = 0  # numbers the `to_static.call` spans, from 1
         self._warned_fallback = False
         functools.update_wrapper(self, fn, updated=[])
 
@@ -121,23 +123,30 @@ class StaticFunction:
     def __call__(self, *args, **kwargs):
         if not _TO_STATIC_ENABLED[0]:
             return self._fn(*args, **kwargs)
-        key = self._guard_key(args, kwargs)
-        entry = self._cache.get(key)
-        from .. import telemetry as _tm
+        self._calls += 1
+        fname = getattr(self._fn, "__name__", "<fn>")
+        # in the profiler's trace a StepTraceAnnotation: one per train step
+        with RecordEvent("to_static.call", args={"fn": fname, "step": self._calls},
+                         step_num=self._calls):
+            with RecordEvent("to_static.guard"):
+                key = self._guard_key(args, kwargs)
+                entry = self._cache.get(key)
+                from .. import telemetry as _tm
 
-        if _tm.enabled():
-            _tm.counter(
-                "paddle_tpu_jit_cache_total",
-                "to_static guard-cache lookups", ("function", "result"),
-            ).labels(
-                function=getattr(self._fn, "__name__", "<fn>"),
-                result="hit" if entry is not None else "miss",
-            ).inc()
-        if entry is None:
-            entry = self._trace(args, kwargs, key)
-            if entry is None:  # recording run already produced the result
-                return self._last_record_output
-        return self._run_compiled(entry, args, kwargs)
+                if _tm.enabled():
+                    _tm.counter(
+                        "paddle_tpu_jit_cache_total",
+                        "to_static guard-cache lookups", ("function", "result"),
+                    ).labels(
+                        function=fname,
+                        result="hit" if entry is not None else "miss",
+                    ).inc()
+            if entry is None:
+                with RecordEvent("to_static.record"):
+                    entry = self._trace(args, kwargs, key)
+                if entry is None:  # recording run already produced the result
+                    return self._last_record_output
+            return self._run_compiled(entry, args, kwargs)
 
     # ---- phase 1: eager recording run ----
     def _trace(self, args, kwargs, key):
@@ -240,181 +249,31 @@ class _CompiledEntry:
         return mask, [v for v in vals if v is not None]
 
     def run(self, args, kwargs):
-        raw_args, t_idx, leaves, treedef, _ = _tensor_flatten((args, kwargs))
-        rng = random_mod.next_key()
+        with RecordEvent("to_static.gather"):
+            raw_args, t_idx, leaves, treedef, _ = _tensor_flatten((args, kwargs))
+        # the key's split is the call's first work for the device (two small
+        # programs): where a full queue holds the host back, so it counts
+        # with the dispatch and not with the Python around it
+        with RecordEvent("to_static.dispatch"):
+            rng = random_mod.next_key()
 
         if self.jitted is not None and self._grad_inputs()[0] != self.grad_in_mask:
             self.jitted = None  # grad presence changed -> rebuild
 
         if self.jitted is None:
-            # Fixpoint state discovery: any CONCRETE tensor read during tracing
-            # is framework state the eager recording missed (e.g. optimizer
-            # accumulators created lazily inside the recorded step) — it must
-            # become a program input, not a baked constant. Re-trace until the
-            # trace touches no concrete framework tensors.
-            for _ in range(8):
-                self._build(args, kwargs, treedef, t_idx, leaves)
-                rec = _Recorder(exclude_ids=set())
-                prev = core_state.set_recorder(rec)
-                try:
-                    traced = self.jitted.trace(
-                        raw_args, [t._value for t in self.state], rng, self._grad_inputs()[1]
-                    )
-                except Exception:
-                    # failed mid-trace (e.g. concretization error): pure()'s
-                    # finally restored the KNOWN state; scrub any tensor
-                    # discovered only this iteration that still carries a
-                    # tracer, so the eager fallback starts from clean values
-                    for _tid, (t, orig) in rec.writes.items():
-                        if isinstance(t._value, jax.core.Tracer):
-                            t._value = orig
-                            t._grad_node = None
-                    for _tid, (t, orig_g) in rec.grad_writes.items():
-                        if t.grad is not None and isinstance(t.grad._value, jax.core.Tracer):
-                            t.grad = orig_g
-                    raise
-                finally:
-                    core_state.set_recorder(prev)
-                known = {id(t) for t in self.state}
-                # undo trace-time mutation of tensors pure()'s finally doesn't
-                # cover (state discovered only this iteration)
-                for tid, (t, orig) in rec.writes.items():
-                    if tid not in known and isinstance(t._value, jax.core.Tracer):
-                        t._value = orig
-                        t._grad_node = None
-                known_grads = {id(g) for g in self.grad_tensors}
-                for tid, (t, orig_g) in rec.grad_writes.items():
-                    if tid not in known_grads and t.grad is not None and isinstance(t.grad._value, jax.core.Tracer):
-                        t.grad = orig_g
-                missed = [t for t in rec.reads.values() if id(t) not in known]
-                new_grad_ts = [
-                    t for t, _ in rec.grad_writes.values() if id(t) not in known_grads
-                ]
-                self.grad_tensors.extend(new_grad_ts)
-                if not missed and not new_grad_ts:
-                    import time as _time
+            with RecordEvent("to_static.compile") as span:
+                span.args = {"outcome": self._compile(
+                    args, kwargs, treedef, t_idx, leaves, raw_args, rng)}
 
-                    if self.donated:
-                        # donation safety over EVERYTHING donate_argnums
-                        # covers — discovered state (argnum 1) AND incoming
-                        # grads (argnum 3): two entries sharing one buffer
-                        # would donate it twice — fail HERE naming the
-                        # tensors, not inside XLA's anonymous
-                        # duplicate-donation error
-                        from ..static.analysis import (
-                            verify_donated_state,
-                            verify_enabled,
-                        )
+        with RecordEvent("to_static.gather"):
+            state_vals = [t._value for t in self.state]
+            grad_vals = self._grad_inputs()[1]
+        with RecordEvent("to_static.dispatch"):
+            outs, new_state, new_grads = self.jitted(raw_args, state_vals, rng, grad_vals)
+        with RecordEvent("to_static.writeback"):
+            return self._write_back(outs, new_state, new_grads)
 
-                        if verify_enabled():
-                            donated = list(self.state)
-                            labels = [f"state[{i}]" for i in range(len(donated))]
-                            for j, t in enumerate(self.grad_tensors):
-                                if t.grad is not None:
-                                    donated.append(t.grad)
-                                    name = getattr(t, "name", None) or f"#{j}"
-                                    labels.append(f"grad-of[{name}]")
-                            try:
-                                verify_donated_state(
-                                    donated,
-                                    origin=f"to_static:{getattr(self.fn, '__name__', '<fn>')}",
-                                    labels=labels,
-                                )
-                            except Exception:
-                                # _build already installed the donating jit
-                                # wrapper; leaving it set would let the NEXT
-                                # call skip this check and hit XLA's
-                                # anonymous duplicate-donation error
-                                self.jitted = None
-                                raise
-                    t0 = _time.perf_counter()
-                    # round 18: fingerprint the traced jaxpr (the PR 12
-                    # textual IR of a to_static step) and try the persistent
-                    # cache before paying XLA compile. Fingerprinting is
-                    # telemetry-gated like the rest of the attribution path.
-                    from .. import compile_cache as _cc
-                    from .. import telemetry as _tm
-
-                    fname = getattr(self.fn, "__name__", "<fn>")
-                    fp = ekey = st = None
-                    if _tm.enabled():
-                        try:
-                            fp = _cc.fingerprint_text(
-                                f"to_static-v1|{fname}|"
-                                f"donate={self.donated}|{traced.jaxpr}"
-                            )
-                            ekey = _cc.entry_key(fp)
-                            st = _cc.active_store()
-                        except Exception:
-                            fp = ekey = st = None
-                    restored = None
-                    if st is not None and ekey is not None:
-                        got = st.get(ekey, expect_meta=_cc.topology_meta())
-                        if got is not None:
-                            restored = got[0]
-                    if restored is not None:
-                        self.jitted = restored
-                        # a restored step must not LOSE its attribution
-                        # record: cost/memory analysis comes off the
-                        # deserialized executable, so warm runs report the
-                        # same FLOPs/HBM the cold compile did (perf_gate
-                        # hard-fails configs that regress from measured
-                        # attribution back to unavailable)
-                        from ..profiler import perf_attribution as _pa
-
-                        _pa.record_compiled(
-                            "to_static",
-                            fname,
-                            compiled=restored,
-                            compile_seconds=0.0,
-                            extra={"n_state": len(self.state),
-                                   "restored": True},
-                        )
-                        _cc.record(
-                            "to_static", fname, "restore",
-                            seconds=_time.perf_counter() - t0,
-                            fingerprint=fp,
-                            signature=f"n_state={len(self.state)}",
-                        )
-                        break
-                    lowered = traced.lower()
-                    self.jitted = lowered.compile()
-                    dt = _time.perf_counter() - t0
-                    # attribution capture at the one place the whole train
-                    # step exists as a compiled XLA program: FLOPs, HBM
-                    # bytes, memory footprint, compile time (telemetry-gated
-                    # inside record_compiled; never raises)
-                    from ..profiler import perf_attribution as _pa
-
-                    _pa.record_compiled(
-                        "to_static",
-                        fname,
-                        lowered=lowered,
-                        compiled=self.jitted,
-                        compile_seconds=dt,
-                        extra={"n_state": len(self.state)},
-                    )
-                    _cc.record(
-                        "to_static", fname, "miss", seconds=dt,
-                        fingerprint=fp,
-                        signature=f"n_state={len(self.state)}",
-                    )
-                    if st is not None and ekey is not None:
-                        tp = _time.perf_counter()
-                        if st.put(ekey, self.jitted,
-                                  _cc.make_meta("to_static", fname, fp)):
-                            _cc.record(
-                                "to_static", fname, "persist",
-                                seconds=_time.perf_counter() - tp,
-                                fingerprint=fp,
-                            )
-                    break
-                self.state.extend(missed)
-            else:
-                raise RuntimeError("to_static: state discovery did not converge")
-
-        state_vals = [t._value for t in self.state]
-        outs, new_state, new_grads = self.jitted(raw_args, state_vals, rng, self._grad_inputs()[1])
+    def _write_back(self, outs, new_state, new_grads):
         # write back state. Donated runs must adopt EVERY entry's (aliased)
         # output buffer — the input arrays are dead after the call. Without
         # donation, touch only mutated entries so read-only state keeps its
@@ -446,6 +305,174 @@ class _CompiledEntry:
                 origin=f"to_static:{getattr(self.fn, '__name__', '<fn>')}",
             )
         return self._rebuild_out(outs)
+
+    def _compile(self, args, kwargs, treedef, t_idx, leaves, raw_args, rng):
+        """Discover the step's state, then restore or compile its program;
+        returns which ("restore" or "compile")."""
+        # Fixpoint state discovery: any CONCRETE tensor read during tracing
+        # is framework state the eager recording missed (e.g. optimizer
+        # accumulators created lazily inside the recorded step) — it must
+        # become a program input, not a baked constant. Re-trace until the
+        # trace touches no concrete framework tensors.
+        for _ in range(8):
+            self._build(args, kwargs, treedef, t_idx, leaves)
+            rec = _Recorder(exclude_ids=set())
+            prev = core_state.set_recorder(rec)
+            try:
+                traced = self.jitted.trace(
+                    raw_args, [t._value for t in self.state], rng, self._grad_inputs()[1]
+                )
+            except Exception:
+                # failed mid-trace (e.g. concretization error): pure()'s
+                # finally restored the KNOWN state; scrub any tensor
+                # discovered only this iteration that still carries a
+                # tracer, so the eager fallback starts from clean values
+                for _tid, (t, orig) in rec.writes.items():
+                    if isinstance(t._value, jax.core.Tracer):
+                        t._value = orig
+                        t._grad_node = None
+                for _tid, (t, orig_g) in rec.grad_writes.items():
+                    if t.grad is not None and isinstance(t.grad._value, jax.core.Tracer):
+                        t.grad = orig_g
+                raise
+            finally:
+                core_state.set_recorder(prev)
+            known = {id(t) for t in self.state}
+            # undo trace-time mutation of tensors pure()'s finally doesn't
+            # cover (state discovered only this iteration)
+            for tid, (t, orig) in rec.writes.items():
+                if tid not in known and isinstance(t._value, jax.core.Tracer):
+                    t._value = orig
+                    t._grad_node = None
+            known_grads = {id(g) for g in self.grad_tensors}
+            for tid, (t, orig_g) in rec.grad_writes.items():
+                if tid not in known_grads and t.grad is not None and isinstance(t.grad._value, jax.core.Tracer):
+                    t.grad = orig_g
+            missed = [t for t in rec.reads.values() if id(t) not in known]
+            new_grad_ts = [
+                t for t, _ in rec.grad_writes.values() if id(t) not in known_grads
+            ]
+            self.grad_tensors.extend(new_grad_ts)
+            if not missed and not new_grad_ts:
+                import time as _time
+
+                if self.donated:
+                    # donation safety over EVERYTHING donate_argnums
+                    # covers — discovered state (argnum 1) AND incoming
+                    # grads (argnum 3): two entries sharing one buffer
+                    # would donate it twice — fail HERE naming the
+                    # tensors, not inside XLA's anonymous
+                    # duplicate-donation error
+                    from ..static.analysis import (
+                        verify_donated_state,
+                        verify_enabled,
+                    )
+
+                    if verify_enabled():
+                        donated = list(self.state)
+                        labels = [f"state[{i}]" for i in range(len(donated))]
+                        for j, t in enumerate(self.grad_tensors):
+                            if t.grad is not None:
+                                donated.append(t.grad)
+                                name = getattr(t, "name", None) or f"#{j}"
+                                labels.append(f"grad-of[{name}]")
+                        try:
+                            verify_donated_state(
+                                donated,
+                                origin=f"to_static:{getattr(self.fn, '__name__', '<fn>')}",
+                                labels=labels,
+                            )
+                        except Exception:
+                            # _build already installed the donating jit
+                            # wrapper; leaving it set would let the NEXT
+                            # call skip this check and hit XLA's
+                            # anonymous duplicate-donation error
+                            self.jitted = None
+                            raise
+                t0 = _time.perf_counter()
+                # round 18: fingerprint the traced jaxpr (the PR 12
+                # textual IR of a to_static step) and try the persistent
+                # cache before paying XLA compile. Fingerprinting is
+                # telemetry-gated like the rest of the attribution path.
+                from .. import compile_cache as _cc
+                from .. import telemetry as _tm
+
+                fname = getattr(self.fn, "__name__", "<fn>")
+                fp = ekey = st = None
+                if _tm.enabled():
+                    try:
+                        fp = _cc.fingerprint_text(
+                            f"to_static-v1|{fname}|"
+                            f"donate={self.donated}|{traced.jaxpr}"
+                        )
+                        ekey = _cc.entry_key(fp)
+                        st = _cc.active_store()
+                    except Exception:
+                        fp = ekey = st = None
+                restored = None
+                if st is not None and ekey is not None:
+                    got = st.get(ekey, expect_meta=_cc.topology_meta())
+                    if got is not None:
+                        restored = got[0]
+                if restored is not None:
+                    self.jitted = restored
+                    # a restored step must not LOSE its attribution
+                    # record: cost/memory analysis comes off the
+                    # deserialized executable, so warm runs report the
+                    # same FLOPs/HBM the cold compile did (perf_gate
+                    # hard-fails configs that regress from measured
+                    # attribution back to unavailable)
+                    from ..profiler import perf_attribution as _pa
+
+                    _pa.record_compiled(
+                        "to_static",
+                        fname,
+                        compiled=restored,
+                        compile_seconds=0.0,
+                        extra={"n_state": len(self.state),
+                               "restored": True},
+                    )
+                    _cc.record(
+                        "to_static", fname, "restore",
+                        seconds=_time.perf_counter() - t0,
+                        fingerprint=fp,
+                        signature=f"n_state={len(self.state)}",
+                    )
+                    return "restore"
+                lowered = traced.lower()
+                self.jitted = lowered.compile()
+                dt = _time.perf_counter() - t0
+                # attribution capture at the one place the whole train
+                # step exists as a compiled XLA program: FLOPs, HBM
+                # bytes, memory footprint, compile time (telemetry-gated
+                # inside record_compiled; never raises)
+                from ..profiler import perf_attribution as _pa
+
+                _pa.record_compiled(
+                    "to_static",
+                    fname,
+                    lowered=lowered,
+                    compiled=self.jitted,
+                    compile_seconds=dt,
+                    extra={"n_state": len(self.state)},
+                )
+                _cc.record(
+                    "to_static", fname, "miss", seconds=dt,
+                    fingerprint=fp,
+                    signature=f"n_state={len(self.state)}",
+                )
+                if st is not None and ekey is not None:
+                    tp = _time.perf_counter()
+                    if st.put(ekey, self.jitted,
+                              _cc.make_meta("to_static", fname, fp)):
+                        _cc.record(
+                            "to_static", fname, "persist",
+                            seconds=_time.perf_counter() - tp,
+                            fingerprint=fp,
+                        )
+                return "compile"
+            self.state.extend(missed)
+        raise RuntimeError("to_static: state discovery did not converge")
 
     def _build(self, args, kwargs, treedef, t_idx, template_leaves):
         entry = self

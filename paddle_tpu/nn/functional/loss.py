@@ -11,6 +11,7 @@ import jax
 from jax import numpy as jnp
 
 from ...core.apply import apply
+from ...core.state import named_scope
 from ...core.tensor import Tensor, _ensure_tensor
 
 
@@ -83,7 +84,8 @@ def cross_entropy(
     args = [x, y]
     if weight is not None:
         args.append(_t(weight))
-    return apply("cross_entropy", f, *args)
+    with named_scope("loss"):
+        return apply("cross_entropy", f, *args)
 
 
 def softmax_with_cross_entropy(logits, label, soft_label=False, ignore_index=-100, numeric_stable_mode=True, return_softmax=False, axis=-1):

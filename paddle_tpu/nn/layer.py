@@ -241,17 +241,38 @@ class Layer:
     def forward(self, *inputs, **kwargs):
         raise NotImplementedError
 
+    def _name_scopes(self) -> str:
+        """Name this layer (a root, or one attached after its root was
+        named) by its class, and every layer below by its dotted path under
+        the nearest layer above it that is called: a container with no
+        forward of its own (LayerList) lends its name to its members
+        (`layers.3`). Returns this layer's name."""
+        def walk(layer, prefix):
+            for name, sub in layer._sub_layers.items():
+                if sub is None:
+                    continue
+                sub._scope_name = prefix + name
+                called = type(sub).forward is not Layer.forward
+                walk(sub, "" if called else prefix + name + ".")
+
+        self._scope_name = type(self).__name__
+        walk(self, "")
+        return self._scope_name
+
     def __call__(self, *inputs, **kwargs):
-        for hook in self._forward_pre_hooks.values():
-            out = hook(self, inputs)
-            if out is not None:
-                inputs = out if isinstance(out, tuple) else (out,)
-        outputs = self.forward(*inputs, **kwargs)
-        for hook in self._forward_post_hooks.values():
-            res = hook(self, inputs, outputs)
-            if res is not None:
-                outputs = res
-        return outputs
+        # compile-time metadata only: every HLO op traced below carries the
+        # path of the layers around it (`.../encoder/layers.3/self_attn/...`)
+        with core_state.named_scope(self.__dict__.get("_scope_name") or self._name_scopes()):
+            for hook in self._forward_pre_hooks.values():
+                out = hook(self, inputs)
+                if out is not None:
+                    inputs = out if isinstance(out, tuple) else (out,)
+            outputs = self.forward(*inputs, **kwargs)
+            for hook in self._forward_post_hooks.values():
+                res = hook(self, inputs, outputs)
+                if res is not None:
+                    outputs = res
+            return outputs
 
     # ---- state dict ----
     def state_dict(self, destination=None, include_sublayers=True, structured_name_prefix="", use_hook=True):

@@ -163,6 +163,7 @@ def _pallas_apply(p, m, v, g, scal, seed, beta1, beta2, eps, wd, decoupled, m2_b
             dimension_semantics=("parallel",),
         ),
         interpret=_pk._INTERPRET,
+        name="fused_adamw",
     )(scal, seed, view(p), view(m), view(v), view(g))
     return p2.reshape(n), m2.reshape(n), v2.reshape(n)
 

@@ -460,6 +460,7 @@ def _flash_fwd_impl(q, k, v, seed, causal, sm_scale, dropout_p):
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=_INTERPRET,
+        name="flash_fwd",
     )(seed, qr, kr, vr)
     return jnp.swapaxes(out.reshape(b, h, sq, d), 1, 2), lse
 
@@ -641,6 +642,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, g_lse, seed, causal, sm_scale, dropout
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         compiler_params=_COMPILER_PARAMS,
         interpret=_INTERPRET,
+        name="flash_dq",
     )(seed, qr, kr, vr, gr, lse, delta)
 
     # dkdv holds the WHOLE q/do streams VMEM-resident on top of its tiles —
@@ -682,6 +684,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, g_lse, seed, causal, sm_scale, dropout
         ],
         compiler_params=_COMPILER_PARAMS_3D,
         interpret=_INTERPRET,
+        name="flash_dkdv",
     )(seed, qr, kr, vr, gr, lse, delta)
 
     unshape = lambda a, s, hh, dt: jnp.swapaxes(
@@ -716,7 +719,7 @@ def _core_bwd(causal, sm_scale, dropout_p, res, g):
     q, k, v, seed, out, lse = res
     g_out, g_lse = g
     with jax.enable_x64(False):
-        dq, dk, dv = _flash_bwd_impl(
+        dq, dk, dv = _flash_bwd_jit(
             q, k, v, out, lse, g_out, g_lse, seed, causal, sm_scale, dropout_p
         )
     seed_ct = np.zeros(np.shape(seed), jax.dtypes.float0)
@@ -781,6 +784,18 @@ def _flash_fwd_x32_wrap(q, k, v, seed, causal, sm_scale, dropout_p):
 )
 def _flash_fwd_jit(q, k, v, seed, causal=False, sm_scale=None, dropout_p=0.0):
     return _flash_fwd_impl(q, k, v, seed, causal, sm_scale, dropout_p)
+
+
+# A jit of its own, like the forward's: inside it the name stack starts
+# afresh, so the kernels' names (`flash_dq`, `flash_dkdv`) reach the compiled
+# program as they are and not wrapped in the transpose(jvp(...)) that runs
+# the backward.
+@functools.partial(
+    jax.jit, static_argnames=("causal", "sm_scale", "dropout_p")
+)
+def _flash_bwd_jit(q, k, v, out, lse, g, g_lse, seed, causal=False,
+                   sm_scale=None, dropout_p=0.0):
+    return _flash_bwd_impl(q, k, v, out, lse, g, g_lse, seed, causal, sm_scale, dropout_p)
 
 
 # ---------------------------------------------------------------------------
@@ -1025,6 +1040,7 @@ def _paged_extend_impl(q, k_pages, v_pages, block_tables, q_positions,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
         compiler_params=params,
         interpret=_INTERPRET,
+        name="paged_attn",
     )(block_tables, q_positions, *operands)
     return (
         out[:, :, :qn * group]

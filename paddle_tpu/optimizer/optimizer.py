@@ -154,7 +154,8 @@ class Optimizer:
 
     @no_grad()
     def step(self):
-        return self._record_step(self._step_impl)
+        with core_state.named_scope("optimizer"):  # the update's ops in a traced step
+            return self._record_step(self._step_impl)
 
     def _step_impl(self):
         self._sync_lr()

@@ -143,3 +143,123 @@ def test_fused_rms_norm_compiles(one_chip):
 
     assert _kernels(fn, _aval(one_chip, (4096, 4096), BF16),
                     _aval(one_chip, (4096,), BF16)) == 1
+
+
+# ---------------------------------------------------------------------------
+# names: what a trace of the chip will call each kernel, and the path every
+# op of a traced step carries
+# ---------------------------------------------------------------------------
+
+def _kernel_names(fn, *avals, **kw_avals):
+    """Instruction names of the Pallas kernels in fn's program compiled for
+    the described chip, version suffix dropped (`flash_fwd.1` -> `flash_fwd`):
+    what chipbench/xplane.short_name keeps of a device event."""
+    import re
+
+    text = jax.jit(fn).lower(*avals, **kw_avals).compile().as_text()
+    return sorted(re.sub(r"\.\d+$", "", m) for m in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"" + KERNEL + "\"", text))
+
+
+def _named_flash(sh):
+    def grads(q, k, v):
+        def loss(q, k, v):
+            return pk.flash_attention_bshd(q, k, v).astype(jnp.float32).sum()
+
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    q = _aval(sh, (16, 512, 12, 64), BF16)  # the trainer cell's shapes
+    return _kernel_names(grads, q, q, q)
+
+
+def _named_paged(sh):
+    pages = _aval(sh, (256, 8, 16, 128), BF16)
+    return (_kernel_names(pk.flash_decode_paged, _aval(sh, (8, 32, 128), BF16), pages, pages,
+                          _aval(sh, (8, 16), jnp.int32), _aval(sh, (8,), jnp.int32))
+            + _kernel_names(pk.flash_decode_paged_multi, _aval(sh, (8, 4, 32, 128), BF16), pages,
+                            pages, _aval(sh, (8, 16), jnp.int32), _aval(sh, (8, 4), jnp.int32)))
+
+
+def _named_adamw(sh):
+    n = fo.pad_to_tile(1_000_000)
+
+    def fn(p, m, v, g):
+        return fo.fused_adamw_apply(p, m, v, g, lr=1e-3, clip_scale=1.0, c1=0.1, c2=0.001,
+                                    seed=3, beta1=0.9, beta2=0.999, eps=1e-8, wd=0.01)
+
+    f32 = _aval(sh, (n,), jnp.float32)
+    return _kernel_names(fn, f32, f32, f32, f32)
+
+
+def _named_rms_norm(sh):
+    import paddle_tpu.incubate.nn.functional as IF
+    from paddle_tpu.core.tensor import Tensor
+
+    def fn(x, w):
+        return IF.fused_rms_norm(Tensor(x), Tensor(w)).value
+
+    return _kernel_names(fn, _aval(sh, (4096, 4096), BF16), _aval(sh, (4096,), BF16))
+
+
+@pytest.mark.parametrize("build, names", [
+    (_named_flash, ["flash_dkdv", "flash_dq", "flash_fwd"]),
+    (_named_paged, ["paged_attn", "paged_attn"]),   # one pallas_call serves decode and extend
+    (_named_adamw, ["fused_adamw"]),
+    (_named_rms_norm, ["rms_norm"]),
+], ids=["flash", "paged", "adamw", "rms_norm"])
+def test_kernels_carry_stable_names(one_chip, build, names):
+    """Whatever jit, jvp or transpose is around a kernel, the instruction the
+    chip's trace shows is named after the kernel."""
+    assert build(one_chip) == names
+
+
+def test_train_step_hlo_carries_layer_loss_and_optimizer_paths(monkeypatch):
+    """Compile-time metadata only (the host's own compiler will do): every
+    op of a traced train step carries the path of the scopes around it."""
+    import numpy as np
+
+    monkeypatch.setattr(pk, "_on_tpu", lambda: False)  # a CPU program: no Mosaic kernels
+
+    class Block(paddle.nn.Layer):
+        def __init__(self):
+            super().__init__()
+            self.proj = paddle.nn.Linear(8, 8)
+
+        def forward(self, x):
+            return self.proj(x)
+
+    class Net(paddle.nn.Layer):
+        def __init__(self):
+            super().__init__()
+            self.layers = paddle.nn.LayerList([Block() for _ in range(4)])
+            self.head = paddle.nn.Linear(8, 5)
+
+        def forward(self, x):
+            for layer in self.layers:
+                x = layer(x)
+            return self.head(x)
+
+    net = Net()
+    opt = paddle.optimizer.AdamW(learning_rate=0.01, parameters=net.parameters())
+
+    @paddle.jit.to_static
+    def train_step(x, y):
+        loss = paddle.nn.functional.cross_entropy(net(x), y)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    x = paddle.to_tensor(np.ones((4, 8), "float32"))
+    y = paddle.to_tensor(np.arange(4) % 5)
+    for _ in range(2):
+        train_step(x, y)
+    (entry,) = [e for e in train_step.concrete_program().values() if e.jitted is not None]
+    text = entry.jitted.as_text()
+    import re
+
+    for path in ("Net/layers.3/proj", "Net/head", "/loss/", "/optimizer/"):
+        assert path in text, path
+    # the pullbacks run later, in backward(), and carry their layer's path too
+    assert re.search(r'op_name="[^"]*Net/layers\.3/proj/[^"]*transpose\(jvp', text)
+    assert re.search(r'op_name="[^"]*/loss/[^"]*transpose\(jvp', text)

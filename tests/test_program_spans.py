@@ -1,0 +1,373 @@
+"""The program's one span primitive (`paddle_tpu.profiler.RecordEvent`):
+the ring, and the spans of the scheduler, the engine and `to_static`.
+
+CPU, a tiny engine and a tiny `to_static` step. What a span costs is kept
+here as a test with a loose limit; the measured figure is in PERF.md.
+"""
+import glob
+import os
+import time
+
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.profiler import RecordEvent
+from paddle_tpu.profiler import utils as spans
+
+
+@pytest.fixture(autouse=True)
+def _clean_ring():
+    spans.clear()
+    yield
+    spans.clear()
+
+
+@pytest.fixture(scope="module")
+def tiny_engine():
+    from paddle_tpu.inference.engine import InferenceEngine
+    from paddle_tpu.models.llama import llama_tiny
+
+    paddle.seed(0)
+    model = llama_tiny(num_key_value_heads=2)
+    model.eval()
+    return InferenceEngine(model, max_seq_len=64, block_size=8, max_batch=4)
+
+
+def _scheduler(engine, **kw):
+    from paddle_tpu.inference.scheduler import ContinuousBatchingScheduler
+
+    return ContinuousBatchingScheduler(engine, clock=time.perf_counter, **kw)
+
+
+def _request(rid, n_prompt=5, max_new=4):
+    from paddle_tpu.inference.scheduler import Request
+
+    return Request(rid=rid, prompt=[3 + (rid + i) % 50 for i in range(n_prompt)],
+                   max_new_tokens=max_new)
+
+
+def _tiny_step():
+    lin = paddle.nn.Linear(4, 2)
+    opt = paddle.optimizer.AdamW(learning_rate=0.01, parameters=lin.parameters())
+
+    @paddle.jit.to_static
+    def train_step(x, y):
+        loss = ((lin(x) - y) ** 2).mean()
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    x = paddle.to_tensor(np.ones((8, 4), "float32"))
+    y = paddle.to_tensor(np.zeros((8, 2), "float32"))
+    return train_step, x, y
+
+
+def _by_name(recs):
+    out = {}
+    for r in recs:
+        out.setdefault(r[0], []).append(r)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# the ring
+# ---------------------------------------------------------------------------
+
+def test_ring_nests_by_parent():
+    with RecordEvent("outer", ident=7, args={"n": 1}) as outer:
+        with RecordEvent("inner"):
+            pass
+        with RecordEvent("second"):
+            pass
+        outer.args["n"] = 2  # args may be filled until the span ends
+    by = {r[0]: r for r in spans.records()}
+    name, t0, t1, sid, parent, ident, args = by["outer"][:7]
+    assert parent == 0 and ident == 7 and args == {"n": 2}
+    assert by["inner"][4] == sid and by["second"][4] == sid
+    assert t0 <= by["inner"][1] <= by["inner"][2] <= by["second"][1] <= by["second"][2] <= t1
+    # children end first: the ring is in order of ending
+    assert [r[0] for r in spans.records()] == ["inner", "second", "outer"]
+    # lo/hi keep what lies inside
+    assert [r[0] for r in spans.records(lo=by["inner"][1], hi=by["second"][2])] == ["inner", "second"]
+
+
+def test_ring_is_bounded_and_counts_evictions():
+    extra = 10
+    for _ in range(spans.RING_LEN + extra):
+        spans.record_span("x", 0.0, 1.0)
+    assert len(spans.records()) == spans.RING_LEN
+    assert spans.evicted() == extra
+    spans.clear()
+    assert spans.records() == [] and spans.evicted() == 0
+
+
+def test_ring_keeps_count_under_threads():
+    """More threads than cores, a short switch interval: no append and no
+    eviction is lost, ids stay unique, and a span's parent is a span of its
+    own thread."""
+    import sys
+    import threading
+
+    n_threads, n_each = 16, 3000   # 2 records a turn: 96000 in all, past the ring's end
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+
+    def work():
+        for _ in range(n_each):
+            with RecordEvent("outer"):
+                with RecordEvent("inner"):
+                    pass
+
+    try:
+        threads = [threading.Thread(target=work) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(was)
+    recs = spans.records()
+    total = 2 * n_threads * n_each
+    assert total > spans.RING_LEN
+    assert len(recs) + spans.evicted() == total
+    assert len({r[3] for r in recs}) == len(recs)
+    outer = {r[3]: r[8] for r in recs if r[0] == "outer"}
+    for r in recs:
+        if r[0] == "inner" and r[4] in outer:
+            assert outer[r[4]] == r[8]   # same thread
+        if r[0] == "outer":
+            assert r[4] == 0
+
+
+def test_ring_records_nothing_with_telemetry_off():
+    paddle.set_flags({"PADDLE_TPU_TELEMETRY": False})
+    try:
+        with RecordEvent("dark"):
+            pass
+        spans.record_span("dark.after", 0.0, 1.0)
+        assert spans.records() == []
+    finally:
+        paddle.set_flags({"PADDLE_TPU_TELEMETRY": True})
+    with RecordEvent("lit"):
+        pass
+    assert [r[0] for r in spans.records()] == ["lit"]
+
+
+def test_span_cost_with_no_profiler_session():
+    """Two clock reads, one ring append, a session check: a few microseconds
+    at the most on a loaded CPU worker (measured figure: PERF.md)."""
+    n = 20000
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with RecordEvent("cost"):
+                pass
+        best = min(best, (time.perf_counter() - t0) / n)
+    assert best < 25e-6, f"{best * 1e9:.0f} ns a span"
+
+
+# ---------------------------------------------------------------------------
+# scheduler and engine
+# ---------------------------------------------------------------------------
+
+def test_scheduler_step_yields_named_phases_that_add_up(tiny_engine):
+    sched = _scheduler(tiny_engine)
+    sched.submit(_request(0))
+    sched.step()          # admission by a bucketed prefill, then a decode
+    sched.submit(_request(1))
+    spans.clear()
+    produced = sched.step()  # a busy step: request 1 streams in beside request 0
+    recs = spans.records()
+    by = _by_name(recs)
+    (step,) = by["sched.step"]
+    assert step[6] == {"produced": produced, "running": len(sched.running),
+                       "waiting": len(sched.waiting)}
+    # nothing expires here: a sweep that finds nothing is no phase of the step
+    phases = ["sched.admit", "sched.grow", "sched.rows", "engine.decode",
+              "sched.emit", "sched.publish"]
+    children = [r for r in recs if r[4] == step[3]]
+    assert [r[0] for r in children] == phases  # in order of ending = order of running
+    for c in children:
+        assert step[1] <= c[1] <= c[2] <= step[2]
+    for a, b in zip(children, children[1:]):
+        assert a[2] <= b[1]  # phases do not overlap
+    self_time = (step[2] - step[1]) - sum(c[2] - c[1] for c in children)
+    assert 0.0 <= self_time < 0.5 * (step[2] - step[1])
+    while not sched.idle():
+        sched.step()
+
+
+def test_phases_that_do_nothing_are_skipped_and_expiry_is_named(tiny_engine):
+    sched = _scheduler(tiny_engine)
+    sched.submit(_request(0, max_new=6))
+    sched.step()
+    spans.clear()
+    sched.step()          # nobody waits, nothing expires
+    names = [r[0] for r in spans.records()]
+    assert "sched.admit" not in names and "sched.expire" not in names
+    assert {"sched.step", "sched.grow", "sched.rows", "sched.emit", "sched.publish"} <= set(names)
+    late = _request(1)
+    late.deadline_s = 0.0
+    sched.submit(late)
+    spans.clear()
+    sched.step()
+    (expire,) = [r for r in spans.records() if r[0] == "sched.expire"]
+    assert expire[6] == {"expired": 1} and late.outcome == "expired"
+    while not sched.idle():
+        sched.step()
+
+
+def test_bucketed_prefill_is_a_child_of_admit(tiny_engine):
+    sched = _scheduler(tiny_engine)
+    sched.submit(_request(0, n_prompt=6))
+    sched.step()
+    by = _by_name(spans.records())
+    (admit,) = by["sched.admit"]
+    (prefill,) = by["engine.prefill"]
+    assert prefill[4] == admit[3]
+    assert prefill[6]["tokens"] == 6 and prefill[6]["bucket"] >= 6
+    kids = [r[0] for r in spans.records() if r[4] == prefill[3]]
+    assert kids == ["engine.prefill.inputs", "engine.prefill.dispatch", "engine.prefill.fetch"]
+    while not sched.idle():
+        sched.step()
+
+
+def test_request_queue_plus_prompt_is_ttft(tiny_engine):
+    sched = _scheduler(tiny_engine)
+    reqs = [_request(i, n_prompt=4 + i) for i in range(3)]
+    for r in reqs:
+        sched.submit(r)
+    while not sched.idle():
+        sched.step()
+    by = _by_name(spans.records())
+    queue = {r[5]: r for r in by["request.queue"]}
+    prompt = {r[5]: r for r in by["request.prompt"]}
+    assert set(queue) == set(prompt) == {0, 1, 2}
+    for r in reqs:
+        q, p = queue[r.rid], prompt[r.rid]
+        assert q[1] == r.submitted_time and q[2] == p[1] and p[2] == r.first_token_time
+        assert (q[2] - q[1]) + (p[2] - p[1]) == pytest.approx(r.ttft(), abs=1e-9)
+        assert p[6]["prompt_len"] == r.prompt_len and p[6]["cached"] == 0
+    # the first found an idle scheduler: one bucketed prefill; the others streamed
+    assert prompt[0][6]["mode"] == "bucketed"
+    assert {prompt[1][6]["mode"], prompt[2][6]["mode"]} == {"streamed"}
+
+
+def test_engine_decode_carries_rows_bucket_context(tiny_engine):
+    engine = tiny_engine
+    pages = [engine.pool.alloc(1, owner=i) for i in range(3)]
+    try:
+        engine.decode(tokens=[5, 6, 7], positions=[0, 1, 2], seq_lens=[1, 2, 3], page_rows=pages)
+    finally:
+        for i, p in enumerate(pages):
+            engine.pool.free(p, owner=i, retain=False)
+    recs = spans.records()
+    (dec,) = [r for r in recs if r[0] == "engine.decode"]
+    assert dec[6] == {"rows": 3, "bucket": 4, "context": 6}
+    kids = [r for r in recs if r[4] == dec[3] and r[0].startswith("engine.decode.")]
+    assert [r[0] for r in kids] == ["engine.decode.inputs", "engine.decode.dispatch",
+                                    "engine.decode.fetch"]
+    assert sum(r[2] - r[1] for r in kids) <= dec[2] - dec[1]
+
+
+def test_engine_compile_is_a_span_of_the_call_that_paid(tiny_engine):
+    from paddle_tpu.inference.engine import InferenceEngine
+
+    fresh = InferenceEngine(tiny_engine._model, max_seq_len=32, block_size=8, max_batch=2)
+    page = fresh.pool.alloc(1, owner=0)
+    fresh.decode(tokens=[5], positions=[0], seq_lens=[1], page_rows=[page])
+    fresh.decode(tokens=[5], positions=[1], seq_lens=[2], page_rows=[page])
+    fresh.pool.free(page, owner=0, retain=False)
+    recs = spans.records()
+    first, second = [r for r in recs if r[0] == "engine.decode"]
+    (comp,) = [r for r in recs if r[0] == "engine.compile"]
+    assert comp[4] == first[3]
+    assert comp[6]["kind"] == "decode" and comp[6]["size"] == 1
+    assert comp[6]["outcome"] in ("compile", "restore", "shared")
+    assert not [r for r in recs if r[4] == second[3] and r[0] == "engine.compile"]
+
+
+# ---------------------------------------------------------------------------
+# to_static
+# ---------------------------------------------------------------------------
+
+def test_to_static_call_numbers_steps_and_names_phases():
+    step, x, y = _tiny_step()
+    for _ in range(4):
+        step(x, y)
+    recs = spans.records()
+    calls = [r for r in recs if r[0] == "to_static.call"]
+    assert [c[6]["step"] for c in calls] == [1, 2, 3, 4]
+    assert {c[6]["fn"] for c in calls} == {"train_step"}
+
+    def kids(c):
+        return [r[0] for r in recs if r[4] == c[3]]
+
+    # call 1 is the eager recording pass; call 2 builds the program; after
+    # that neither is seen again
+    assert kids(calls[0]) == ["to_static.guard", "to_static.record"]
+    assert kids(calls[1]) == ["to_static.guard", "to_static.gather", "to_static.dispatch",
+                              "to_static.compile", "to_static.gather", "to_static.dispatch",
+                              "to_static.writeback"]
+    (comp,) = [r for r in recs if r[0] == "to_static.compile"]
+    assert comp[6]["outcome"] in ("compile", "restore")
+    # gather and dispatch twice each: the arguments, then the key's split (the
+    # call's first work for the device); the state's values, then the program
+    steady = ["to_static.guard", "to_static.gather", "to_static.dispatch",
+              "to_static.gather", "to_static.dispatch", "to_static.writeback"]
+    assert kids(calls[2]) == steady and kids(calls[3]) == steady
+
+
+# ---------------------------------------------------------------------------
+# the profiler's own trace, and Profiler's export
+# ---------------------------------------------------------------------------
+
+def test_jax_profiler_capture_holds_the_programs_spans(tmp_path, tiny_engine):
+    import jax
+    from jax.profiler import ProfileData
+
+    step, x, y = _tiny_step()
+    for _ in range(2):
+        step(x, y)
+    sched = _scheduler(tiny_engine)
+    sched.submit(_request(0))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        step(x, y)
+        while not sched.idle():
+            sched.step()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True)
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(ev.name for ev in line.events if ev.name.startswith("paddle_tpu:"))
+    assert {"paddle_tpu:sched.step", "paddle_tpu:sched.admit", "paddle_tpu:engine.decode",
+            "paddle_tpu:engine.decode.fetch", "paddle_tpu:to_static.call",
+            "paddle_tpu:to_static.dispatch"} <= names
+
+
+def test_profiler_host_events_are_the_rings_records():
+    from paddle_tpu.profiler import Profiler, ProfilerTarget
+
+    with RecordEvent("before"):
+        pass
+    with Profiler(targets=[ProfilerTarget.CPU]) as prof:
+        with RecordEvent("inside", args={"k": 1}):
+            pass
+    with RecordEvent("after"):
+        pass
+    names = [e.name for e in prof.profiler_result.host_events]
+    assert "inside" in names and "before" not in names and "after" not in names
+    trace = prof.profiler_result.to_chrome_trace()
+    (ev,) = [e for e in trace["traceEvents"] if e["name"] == "inside"]
+    assert ev["args"] == {"k": 1} and ev["dur"] >= 0
+    # one store: what Profiler reported is what the ring holds
+    ring = {r[0]: r for r in spans.records()}
+    assert ev["ts"] == pytest.approx(ring["inside"][1] * 1e6, abs=1.0)
