@@ -31,6 +31,7 @@ import jax
 from jax import numpy as jnp
 
 from .. import telemetry
+from ..ops.pallas import paged_live_blocks, paged_page_blocks
 from ..profiler.utils import RecordEvent
 from ..telemetry import metrics as _metrics
 from ..telemetry import request_trace as _rt
@@ -617,6 +618,16 @@ class InferenceEngine:
         self._mark_first_token()
         return out
 
+    def _count_page_blocks(self, span, frontiers):
+        """What the paged kernel's grid does with this call, on the span:
+        `page_blocks_grid` steps a layer (bucket rows x page blocks of the
+        table), of which `page_blocks_live` reach a page someone wrote (up
+        to each row's frontier, pad rows one) and the rest start no copy."""
+        live = paged_live_blocks(frontiers, self.block_size, self.max_pages)
+        _, blocks = paged_page_blocks(self.block_size, self.max_pages)
+        span.args["page_blocks_live"] = int(live.sum())
+        span.args["page_blocks_grid"] = int(live.size * blocks)
+
     def decode(
         self,
         tokens: Sequence[int],
@@ -643,6 +654,7 @@ class InferenceEngine:
                 for i, row in enumerate(page_rows):
                     bt[i] = self.pool.padded_table(row, self.max_pages)
                 span.args["context"] = int(lens[:n].sum())
+                self._count_page_blocks(span, lens - 1)
             ex = self._get_compiled("decode", B)
             with RecordEvent("engine.decode.dispatch"):
                 logits, state = ex(
@@ -690,6 +702,7 @@ class InferenceEngine:
                 for i, row in enumerate(page_rows):
                     bt[i] = self.pool.padded_table(row, self.max_pages)
                 span.args["context"] = int(pos.max(axis=1)[:n].sum()) + n
+                self._count_page_blocks(span, pos.max(axis=1))
             ex = self._get_compiled("extend", (B, q_len))
             with RecordEvent("engine.extend.dispatch"):
                 logits, state = ex(

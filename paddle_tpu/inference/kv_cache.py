@@ -207,9 +207,10 @@ class BlockPool:
 
     Device layout: per layer, k/v pages of shape
     [num_blocks, num_kv_heads, block_size, head_dim] (kv-head major: the
-    paged kernel fetches one (block_size, head_dim) tile per page and
-    head). `num_blocks` INCLUDES
-    the reserved trash page 0; usable capacity is num_blocks - 1 pages.
+    paged kernel fetches a page's whole (num_kv_heads, block_size,
+    head_dim) slab, one contiguous read, eight pages of 16 a grid step).
+    `num_blocks` INCLUDES the reserved trash page 0; usable capacity is
+    num_blocks - 1 pages.
     `kv_dtype="int8"` stores int8 pages with f32 scale planes alongside.
     """
 

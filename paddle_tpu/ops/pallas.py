@@ -91,6 +91,20 @@ def _dot_nn(a, b):
     )
 
 
+def _dot_bnt(a, b):
+    """_dot_nt batched over a leading axis: [h, r, d] x [h, k, d] -> [h, r, k]."""
+    return jax.lax.dot_general(
+        a, b, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
+    )
+
+
+def _dot_bnn(a, b):
+    """_dot_nn batched over a leading axis: [h, r, k] x [h, k, d] -> [h, r, d]."""
+    return jax.lax.dot_general(
+        a, b, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
+    )
+
+
 def _dot_tn(a, b):
     """a.T @ b with f32 accumulation (see _dot_nt)."""
     return jax.lax.dot_general(
@@ -807,31 +821,44 @@ def _flash_bwd_jit(q, k, v, out, lse, g, g_lse, seed, causal=False,
 # sequence against a long, NON-CONTIGUOUS context — the KV lives in
 # fixed-size pages scattered through a preallocated pool, addressed by a
 # per-sequence block table (vLLM's PagedAttention layout). The kernel grid
-# is (batch, kv_head, page): the block table rides in as a SCALAR-PREFETCH
-# operand so the k/v BlockSpec index maps pick the right page for each grid
-# step (the page fetch is a table lookup, never a gather in HBM), and the
-# online-softmax state (m, l, acc) for one (batch, kv_head) lives in VMEM
-# scratch across the sequential page axis — the same accumulator pattern as
-# the dkdv kernel's group axis. GQA is native: q is viewed [B, Hkv, group,
-# D], so the whole q-head group of a kv head shares its page stream and the
-# MXU does one [group, bs] logits tile per page.
+# is (batch, page block): a step reads a block of P pages (P * bs = 128
+# positions, one lane tile of logits; 8 pages of 16) for EVERY kv head. The
+# block table rides in as a SCALAR-PREFETCH operand and each of the P page
+# operands has an index map that picks its own page of the step's block
+# (the page fetch is a table lookup, never a gather in HBM); the
+# online-softmax state (m, l, acc) of a sequence lives in VMEM scratch
+# across the sequential page-block axis — the same accumulator pattern as
+# the dkdv kernel's group axis. GQA is native: q is viewed
+# [B, Hkv, group, D], so the whole q-head group of a kv head shares its page
+# stream and the MXU does one [group, P * bs] logits tile per kv head and
+# step, batched over the kv heads.
+#
+# The page axis stops at the row's frontier. A third scalar-prefetch
+# operand counts each row's live page blocks (up to its last query
+# position); a step past them computes nothing, and its index maps name the
+# row's last live block again, so the pipeline sees the block it already
+# holds and starts no copy. The step still costs its fixed ~1 us, so the
+# grid is rows x table width / P whatever the contexts; what it reads is
+# the pages the rows hold. A pad row (seq_lens 1, a table of zeros) costs
+# one live block.
 #
 # Pool layout: pages are [N, Hkv, bs, D] (kv-head major, the layout of
-# jax's own TPU paged attention), so one (page, kv head) fetch is a
-# (bs, D) block whose minor two dims are whole array dims — the only block
+# jax's own TPU paged attention), so a page's (Hkv, bs, D) slab is one
+# contiguous read whose minor two dims are whole array dims — the only block
 # shape Mosaic accepts here without bs/D being multiples of the (8, 128)
-# tile. The int8 pool's scale planes are [N, Hkv, bs]; a grid step fetches
-# the page's whole (Hkv, bs) plane and slices its own head's row.
+# tile. The int8 pool's scale planes are [N, Hkv, bs]; a step fetches each
+# page's whole (Hkv, bs) plane beside it.
 #
 # Masking contract: positions >= seq_lens[b] score -1e30 (the page slots
-# past the sequence end — including every slot of table entries past the
-# last real page — contribute exp(-1e30 - m) == 0). Callers pad block
+# past the sequence end inside the last live block contribute
+# exp(-1e30 - m) == 0; later blocks are never scored). Callers pad block
 # tables with a valid page index (the pool's reserved page 0), so a masked
 # slot may READ garbage but can never fault or influence the output.
 # seq_lens must be >= 1 (a zero-length row would normalize an all-masked
 # softmax).
 
 _DECODE_SUBLANE = 8  # page slots must tile the VPU sublane dimension
+_DECODE_LANES = 128  # positions a grid step scores: one lane tile of logits
 
 
 def paged_decode_usable(q, k_pages) -> bool:
@@ -850,6 +877,23 @@ def paged_decode_usable(q, k_pages) -> bool:
     if bs % _DECODE_SUBLANE != 0:
         return False
     return hkv <= h and h % hkv == 0
+
+
+def paged_page_blocks(block_size, table_width):
+    """(pages a grid step of the paged kernel reads, page blocks a table of
+    `table_width` columns makes): a step scores one lane tile of positions
+    (8 pages of 16), or the whole of a narrower table."""
+    pages = min(max(1, _DECODE_LANES // block_size), table_width)
+    return pages, -(-table_width // pages)
+
+
+def paged_live_blocks(frontiers, block_size, table_width):
+    """Page blocks of each row that reach a position someone wrote: up to
+    the row's frontier (its last query position), one for a pad row. The
+    kernel's grid steps past them compute nothing and start no copy; numpy
+    or jax `frontiers` alike (the serving engine counts with this too)."""
+    pages, blocks = paged_page_blocks(block_size, table_width)
+    return (frontiers // (pages * block_size) + 1).clip(1, blocks)
 
 
 def _dequant_pages(pages, scales):
@@ -919,24 +963,33 @@ def paged_extend_reference(q, k_pages, v_pages, block_tables, q_positions,
     return jax.vmap(one)(q, block_tables, q_positions)
 
 
-def _paged_attn_kernel(bs, group, q_count, rows, scale, quantized):
-    """Unified paged-attention kernel body: Q >= 1 query tokens per
-    sequence packed as rows [rows, d] (query-major, so row r is query
-    r // group of kv-head-group slot r % group; rows past Q * group are
-    sublane padding), each masked to its own causal frontier
-    q_positions[b, r // group]. `quantized` adds per-page scale-plane
-    operands; the per-slot scales are a [1, bs] lane row, so they apply on
-    the logits / probability side of the two matmuls (algebraically the
-    dequantized K/V, without a lane->sublane relayout of the scales)."""
+def _paged_attn_kernel(bs, pages, group, q_count, rows, scale, quantized):
+    """Unified paged-attention kernel body. One grid step is one sequence's
+    block of `pages` pages, every kv head at once: the page refs are
+    [hkv, bs, d] slabs that stack along the slot axis into a
+    [hkv, width, d] tile (width = pages * bs), and both matmuls batch over
+    kv heads. Q >= 1 query tokens per sequence ride as [hkv, rows, d]
+    (query-major, so row r is query r // group of kv-head-group slot
+    r % group; rows past Q * group are sublane padding), each masked to its
+    own causal frontier q_positions[b, r // group]. `quantized` adds
+    per-page scale-plane operands; the per-slot scales are [hkv, bs] lane
+    rows, so they apply on the logits / probability side of the two matmuls
+    (algebraically the dequantized K/V, without a lane->sublane relayout of
+    the scales)."""
+    width = pages * bs
 
-    def kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref, *rest):
+    def stacked(refs):
+        # pages [hkv, bs, d] along the slots, scale planes [hkv, bs] along the lanes
+        tiles = [r[...].astype(jnp.float32) if quantized else r[...] for r in refs]
+        return jnp.concatenate(tiles, axis=1)
+
+    def kernel(bt_ref, qpos_ref, live_ref, q_ref, *rest):
+        k_refs, v_refs, rest = rest[:pages], rest[pages:2 * pages], rest[2 * pages:]
         if quantized:
-            ksc_ref, vsc_ref, o_ref, m_scr, l_scr, acc_scr = rest
-        else:
-            o_ref, m_scr, l_scr, acc_scr = rest
+            ksc_refs, vsc_refs, rest = rest[:pages], rest[pages:2 * pages], rest[2 * pages:]
+        o_ref, m_scr, l_scr, acc_scr = rest
         b = pl.program_id(0)
-        hi = pl.program_id(1)
-        i = pl.program_id(2)
+        i = pl.program_id(1)
 
         @pl.when(i == 0)
         def _init():
@@ -944,38 +997,41 @@ def _paged_attn_kernel(bs, group, q_count, rows, scale, quantized):
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        qb = q_ref[...]  # [rows, d] — storage dtype, MXU at bf16 rate
-        kb = k_ref[...]  # [bs, d]   — one page of this kv head
-        vb = v_ref[...]
-        if quantized:
-            qb = qb.astype(jnp.float32)
-            kb = kb.astype(jnp.float32)
-            vb = vb.astype(jnp.float32)
-        logits = _dot_nt(qb, kb) * scale  # [rows, bs] f32
-        if quantized:
-            logits = logits * (ksc_ref[pl.ds(hi, 1), :] * (1.0 / 127.0))
-        row = lax.broadcasted_iota(jnp.int32, (rows, bs), 0)
-        pos = i * bs + lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
-        # per-query frontier: Q is static and small, so it unrolls as Q
-        # scalar-prefetch reads selected by row range (SMEM scalars never
-        # vector-gather)
-        frontier = jnp.full((rows, bs), qpos_ref[b, 0], jnp.int32)
-        for qi in range(1, q_count):
-            frontier = jnp.where(row >= qi * group, qpos_ref[b, qi], frontier)
-        logits = jnp.where(pos <= frontier, logits, -1e30)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-        p = jnp.exp(logits - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if quantized:
-            p = p * (vsc_ref[pl.ds(hi, 1), :] * (1.0 / 127.0))
-        acc_new = acc_scr[...] * alpha + _dot_nn(p.astype(vb.dtype), vb)
-        m_scr[...] = m_new
-        l_scr[...] = l_new
-        acc_scr[...] = acc_new
+        # a page block past the row's frontier holds nothing anyone wrote:
+        # no compute, and the index maps name the last live block again, so
+        # the pipeline starts no copy for it either
+        @pl.when(i < live_ref[b])
+        def _block():
+            qb = q_ref[...]  # [hkv, rows, d] — storage dtype, MXU at bf16 rate
+            if quantized:
+                qb = qb.astype(jnp.float32)
+            kb = stacked(k_refs)  # [hkv, width, d]
+            vb = stacked(v_refs)
+            logits = _dot_bnt(qb, kb) * scale  # [hkv, rows, width] f32
+            if quantized:
+                logits = logits * (stacked(ksc_refs)[:, None, :] * (1.0 / 127.0))
+            row = lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+            pos = i * width + lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+            # per-query frontier: Q is static and small, so it unrolls as Q
+            # scalar-prefetch reads selected by row range (SMEM scalars never
+            # vector-gather)
+            frontier = jnp.full((rows, width), qpos_ref[b, 0], jnp.int32)
+            for qi in range(1, q_count):
+                frontier = jnp.where(row >= qi * group, qpos_ref[b, qi], frontier)
+            logits = jnp.where((pos <= frontier)[None], logits, -1e30)
+            m_prev = m_scr[...]
+            m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+            p = jnp.exp(logits - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            if quantized:
+                p = p * (stacked(vsc_refs)[:, None, :] * (1.0 / 127.0))
+            acc_new = acc_scr[...] * alpha + _dot_bnn(p.astype(vb.dtype), vb)
+            m_scr[...] = m_new
+            l_scr[...] = l_new
+            acc_scr[...] = acc_new
 
-        @pl.when(i == pl.num_programs(2) - 1)
+        @pl.when(i == pl.num_programs(1) - 1)
         def _emit():
             o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
@@ -991,6 +1047,13 @@ def _paged_extend_impl(q, k_pages, v_pages, block_tables, q_positions,
     # finite uniform softmax and are sliced away below)
     rows = -(-qn * group // _DECODE_SUBLANE) * _DECODE_SUBLANE
     m = block_tables.shape[1]
+    # a table `pages` does not divide is padded with the reserved page 0
+    # like the rest of its padding
+    pages, blocks = paged_page_blocks(bs, m)
+    block_tables = jnp.pad(block_tables, ((0, 0), (0, blocks * pages - m)))
+    # the row's frontier is the max over Q, because pad slots of an extend
+    # row carry position 0
+    live = paged_live_blocks(jnp.max(q_positions, axis=1), bs, m)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     quantized = k_scales is not None
     # pack queries query-major per kv head: row qi*group + g is query qi of
@@ -1002,46 +1065,52 @@ def _paged_extend_impl(q, k_pages, v_pages, block_tables, q_positions,
     )
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows - qn * group), (0, 0)))
 
-    # page fetch: the block table names the pool page for grid step
-    # (bi, pi); padded table entries point at the reserved page 0
-    page_spec = pl.BlockSpec(
-        (None, None, bs, d), lambda bi, hi, pi, bt, qp: (bt[bi, pi], hi, 0, 0)
-    )
-    scale_spec = pl.BlockSpec(
-        (None, hkv, bs), lambda bi, hi, pi, bt, qp: (bt[bi, pi], 0, 0)
-    )
-    q_spec = pl.BlockSpec((None, None, rows, d), lambda bi, hi, pi, *_: (bi, hi, 0, 0))
-    in_specs = [q_spec, page_spec, page_spec]
-    operands = [qg, k_pages, v_pages]
+    # page fetch: the block table names the pool page for page j of grid
+    # step (bi, pi), a dead step that of the row's last live block; padded
+    # table entries point at the reserved page 0
+    def page_specs(block):
+        def spec(j):
+            def index(bi, pi, bt, qp, lv):
+                col = jnp.minimum(pi, lv[bi] - 1) * pages + j
+                return (bt[bi, col],) + (0,) * (len(block) - 1)
+
+            return pl.BlockSpec(block, index)
+
+        return [spec(j) for j in range(pages)]
+
+    q_spec = pl.BlockSpec((None, hkv, rows, d), lambda bi, pi, *_: (bi, 0, 0, 0))
+    in_specs = [q_spec] + 2 * page_specs((None, hkv, bs, d))
+    operands = [qg] + pages * [k_pages] + pages * [v_pages]
     if quantized:
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scales, v_scales]
+        in_specs += 2 * page_specs((None, hkv, bs))
+        operands += pages * [k_scales] + pages * [v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block table + query frontiers drive the maps
-        grid=(b, hkv, m),
+        # block table, query frontiers, live block counts drive the maps
+        num_scalar_prefetch=3,
+        grid=(b, blocks),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((hkv, rows, d), jnp.float32),
         ],
     )
-    # the page axis REVISITS the (bi, hi) accumulator scratch + out block on
-    # consecutive steps — it must stay sequential ("arbitrary"); batch/head
-    # steps each start a fresh accumulator at pi == 0
+    # the page axis REVISITS the row's accumulator scratch + out block on
+    # consecutive steps — it must stay sequential ("arbitrary"); each row
+    # starts a fresh accumulator at pi == 0
     params = pltpu.CompilerParams(
-        dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+        dimension_semantics=("arbitrary", "arbitrary"),
         vmem_limit_bytes=_VMEM_LIMIT,
     )
     out = pl.pallas_call(
-        _paged_attn_kernel(bs, group, qn, rows, scale, quantized),
+        _paged_attn_kernel(bs, pages, group, qn, rows, scale, quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
         compiler_params=params,
         interpret=_INTERPRET,
         name="paged_attn",
-    )(block_tables, q_positions, *operands)
+    )(block_tables, q_positions, live, *operands)
     return (
         out[:, :, :qn * group]
         .reshape(b, hkv, qn, group, d)

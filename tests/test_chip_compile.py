@@ -93,14 +93,10 @@ def test_flash_fwd_bwd_compiles(one_chip, shape_q, shape_kv, causal, dropout):
     assert _kernels(grads, q, kv, kv) == 3  # fwd, dq, dkdv
 
 
-@pytest.mark.parametrize("q_len", [1, 4], ids=["decode", "extend_q4"])
-@pytest.mark.parametrize("pool_dtype, q_dtype", [
-    (BF16, BF16), (jnp.float32, jnp.float32), (jnp.int8, BF16),
-], ids=["bf16", "f32", "int8"])
-def test_paged_attention_compiles(one_chip, pool_dtype, q_dtype, q_len):
+def _paged_kernels(one_chip, pool_dtype, q_dtype, q_len, B, N, M):
     """Hidden-4096 widths (32q/8kv, head 128), block 16, pool [N, Hkv, bs, D]
     — through flash_decode_paged / flash_decode_paged_multi."""
-    B, H, HKV, D, BS, N, M = 8, 32, 8, 128, 16, 256, 16
+    H, HKV, D, BS = 32, 8, 128, 16
     pages = _aval(one_chip, (N, HKV, BS, D), pool_dtype)
     bt = _aval(one_chip, (B, M), jnp.int32)
     scales = {}
@@ -115,7 +111,25 @@ def test_paged_attention_compiles(one_chip, pool_dtype, q_dtype, q_len):
         fn = pk.flash_decode_paged_multi
         q = _aval(one_chip, (B, q_len, H, D), q_dtype)
         where = _aval(one_chip, (B, q_len), jnp.int32)  # q_positions
-    assert _kernels(fn, q, pages, pages, bt, where, **scales) == 1
+    return _kernels(fn, q, pages, pages, bt, where, **scales)
+
+
+@pytest.mark.parametrize("q_len", [1, 4], ids=["decode", "extend_q4"])
+@pytest.mark.parametrize("pool_dtype, q_dtype", [
+    (BF16, BF16), (jnp.float32, jnp.float32), (jnp.int8, BF16),
+], ids=["bf16", "f32", "int8"])
+def test_paged_attention_compiles(one_chip, pool_dtype, q_dtype, q_len):
+    assert _paged_kernels(one_chip, pool_dtype, q_dtype, q_len, B=8, N=256, M=16) == 1
+
+
+@pytest.mark.parametrize("q_len", [1, 4], ids=["decode", "extend_q4"])
+@pytest.mark.parametrize("B, M", [(32, 64), (1, 256), (8, 20)],
+                         ids=["chat_open_b32_m64", "doc_single_b1_m256", "ragged_m20"])
+def test_paged_attention_compiles_at_the_cells_shapes(one_chip, B, M, q_len):
+    """The benchmark's two serving cells (the 32-row bucket over a 64-page
+    table, one row over 256 pages; the pool of 4097 pages), and a table the
+    kernel's block of 8 pages does not divide."""
+    assert _paged_kernels(one_chip, BF16, BF16, q_len, B=B, N=4097, M=M) == 1
 
 
 @pytest.mark.parametrize("m2_dtype", [jnp.float32, BF16], ids=["m2_f32", "m2_bf16"])

@@ -267,11 +267,36 @@ def test_engine_decode_carries_rows_bucket_context(tiny_engine):
             engine.pool.free(p, owner=i, retain=False)
     recs = spans.records()
     (dec,) = [r for r in recs if r[0] == "engine.decode"]
-    assert dec[6] == {"rows": 3, "bucket": 4, "context": 6}
+    # a 64-position table is one page block of the paged kernel: 4 rows, 4 live
+    assert dec[6] == {"rows": 3, "bucket": 4, "context": 6,
+                      "page_blocks_live": 4, "page_blocks_grid": 4}
     kids = [r for r in recs if r[4] == dec[3] and r[0].startswith("engine.decode.")]
     assert [r[0] for r in kids] == ["engine.decode.inputs", "engine.decode.dispatch",
                                     "engine.decode.fetch"]
     assert sum(r[2] - r[1] for r in kids) <= dec[2] - dec[1]
+
+
+def test_engine_counts_the_page_blocks_the_paged_kernel_reads(tiny_engine):
+    """512 positions at block 8 are 64 table columns = 4 page blocks of 16
+    pages (128 positions); a row's live blocks reach its frontier, a pad
+    row's (and an extend row's pad slots, position 0) the first block."""
+    from paddle_tpu.inference.engine import InferenceEngine
+
+    wide = InferenceEngine(tiny_engine._model, max_seq_len=512, block_size=8, max_batch=4)
+    lens = [1, 129, 300]
+    pages = [wide.pool.alloc(-(-n // 8), owner=i) for i, n in enumerate(lens)]
+    try:
+        wide.decode(tokens=[5, 6, 7], positions=[n - 1 for n in lens], seq_lens=lens,
+                    page_rows=pages)
+        wide.extend([[5, 6], [7, 8, 9]], [[126, 127], [127, 128, 129]], pages[1:], q_len=4)
+    finally:
+        for i, p in enumerate(pages):
+            wide.pool.free(p, owner=i, retain=False)
+    recs = spans.records()
+    (dec,) = [r for r in recs if r[0] == "engine.decode"]
+    (ext,) = [r for r in recs if r[0] == "engine.extend"]
+    assert (dec[6]["page_blocks_live"], dec[6]["page_blocks_grid"]) == (1 + 2 + 3 + 1, 16)
+    assert (ext[6]["page_blocks_live"], ext[6]["page_blocks_grid"]) == (1 + 2, 8)
 
 
 def test_engine_compile_is_a_span_of_the_call_that_paid(tiny_engine):

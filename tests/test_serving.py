@@ -728,6 +728,83 @@ def test_paged_decode_int8_pinned_against_f32_oracle():
         pk.flash_decode_paged(q, kq, vq, bt, sl, k_scales=ks)
 
 
+def _ragged_tables(rng, frontiers, m, bs, n):
+    """A block table [B, m] whose row b holds distinct shuffled pages up to
+    position frontiers[b] and the reserved page 0 past them (a pad row,
+    frontier 0 with nothing written, holds page 0 only)."""
+    free = list(rng.permutation(np.arange(1, n)))
+    bt = np.full((len(frontiers), m), TRASH_PAGE, np.int32)
+    for b, f in enumerate(frontiers):
+        if f > 0:
+            held = f // bs + 1
+            bt[b, :held] = [free.pop() for _ in range(held)]
+    return bt
+
+
+# (id, block size, table width, per-row query positions [B, Q], pool dtype):
+# what the (batch, page block) grid has to tell apart and the old
+# (batch, kv head, page) grid never did — at block 16 a grid step holds 8
+# pages = 128 positions, at block 8 it holds 16
+_RAGGED = [
+    ("table_far_wider_than_contexts", 16, 64, [[0], [16], [129], [63]], jnp.float32),
+    ("ends_on_page_and_block_edges", 16, 24, [[31], [127], [255], [128]], jnp.float32),
+    ("pad_rows_all_zero_table", 16, 16, [[0], [77], [0], [0], [200]], jnp.float32),
+    ("table_narrower_than_a_block", 16, 3, [[0], [39], [47]], jnp.float32),
+    ("width_no_multiple_of_block", 16, 20, [[299], [128], [319], [4]], jnp.float32),
+    ("block8_width_no_multiple", 8, 21, [[167], [127], [128], [9]], jnp.float32),
+    ("bf16_wide_table", 16, 64, [[5], [500], [1023], [0]], jnp.bfloat16),
+    ("extend_q4_pad_slots_at_0", 16, 20,
+     [[200, 201, 0, 0], [5, 6, 7, 8], [0, 0, 0, 0], [126, 127, 128, 129]], jnp.float32),
+    ("int8_pool_ragged", 16, 20, [[299], [128], [0], [40]], jnp.int8),
+    ("int8_pool_extend_q4", 16, 12, [[100, 101, 102, 0], [0, 0, 0, 0], [13, 14, 15, 16]],
+     jnp.int8),
+]
+
+
+@pytest.mark.parametrize("bs, m, qpos, pool_dtype", [c[1:] for c in _RAGGED],
+                         ids=[c[0] for c in _RAGGED])
+def test_paged_kernel_vs_reference_on_ragged_rows(bs, m, qpos, pool_dtype):
+    """interpret-mode kernel == jnp reference where rows differ in how many
+    page blocks they hold: contexts of one token beside ones that fill the
+    table, frontiers on page and page-block edges, pad rows (seq_lens 1,
+    a table of zeros), extend rows whose pad slots carry position 0."""
+    from paddle_tpu.quantization.observers import absmax_scale, quantize_absmax
+
+    rng = np.random.RandomState(26)
+    qpos = np.asarray(qpos, np.int32)
+    B, Q = qpos.shape
+    H, HKV, D = 8, 2, 64
+    N = int(sum(f // bs + 1 for f in qpos.max(axis=1))) + 2
+    bt = _ragged_tables(rng, qpos.max(axis=1), m, bs, N)
+    quantized = pool_dtype == jnp.int8
+    f = jnp.float32 if quantized else pool_dtype
+    q = jnp.asarray(rng.randn(B, Q, H, D), f)
+    kp = jnp.asarray(rng.randn(N, HKV, bs, D), f)
+    vp = jnp.asarray(rng.randn(N, HKV, bs, D), f)
+    scales = {}
+    if quantized:
+        ks, vs = absmax_scale(kp, axis=-1), absmax_scale(vp, axis=-1)
+        kp, vp = quantize_absmax(kp, ks[..., None]), quantize_absmax(vp, vs[..., None])
+        scales = {"k_scales": ks, "v_scales": vs}
+    old = pk._INTERPRET
+    pk._INTERPRET = True
+    try:
+        if Q == 1:
+            sl = qpos[:, 0] + 1
+            ref = pk.paged_decode_reference(q[:, 0], kp, vp, bt, sl, **scales)
+            got = pk._paged_decode_jit(q[:, 0], kp, vp, jnp.asarray(bt), jnp.asarray(sl),
+                                       **scales)
+        else:
+            ref = pk.paged_extend_reference(q, kp, vp, bt, qpos, **scales)
+            got = pk._paged_extend_jit(q, kp, vp, jnp.asarray(bt), jnp.asarray(qpos),
+                                       **scales)
+    finally:
+        pk._INTERPRET = old
+    tol = {jnp.float32: dict(rtol=2e-5, atol=2e-6), jnp.bfloat16: dict(rtol=3e-2, atol=3e-2),
+           jnp.int8: dict(rtol=2e-4, atol=2e-5)}[pool_dtype]
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(ref, np.float32), **tol)
+
+
 def test_engine_extend_matches_sequential_decode(tiny_model, shared_engine):
     """engine.extend over [last committed, d1, d2] returns per-position
     logits equal to running each token through the sequential full-forward
