@@ -1,5 +1,6 @@
 """Decode-optimized inference engine: AOT shape buckets over the paged KV
-cache.
+cache and, for a model with recurrent layers, the recurrent-state cache
+beside it.
 
 The serving-tier compute core (PAPER.md L3c `jit/serving`). One engine owns:
 
@@ -19,6 +20,16 @@ tail's K/V writes land past `seq_len` — masked on every later read, and
 overwritten by decode before the sequence grows into them). Decode pads
 the batch with inactive rows whose block table is all trash-page and whose
 seq_len is 1 — they compute garbage that is discarded.
+
+Layer kinds: the model's `.config` may name each layer's kind
+(`layer_kinds`: "attention", "mamba", "moe"; all attention when absent). The
+pool holds K/V pages for the attention layers only and one fixed-size state
+slot a sequence for the recurrent ("mamba") ones; the programs of such a
+model take each row's slot as one more operand (resolved here from the
+row's first page: `decode` and `prefill` keep their signatures), thread the
+state arrays through with the pages, and return the expert layers' counters
+beside the logits in the same fetch. For a model whose layers are all
+attention every array and every operand is as it was.
 """
 from __future__ import annotations
 
@@ -35,7 +46,7 @@ from ..ops.pallas import paged_live_blocks, paged_page_blocks
 from ..profiler.utils import RecordEvent
 from ..telemetry import metrics as _metrics
 from ..telemetry import request_trace as _rt
-from .kv_cache import BlockPool, PagedCacheView
+from .kv_cache import BlockPool, PagedCacheView, StateSpec
 
 __all__ = ["InferenceEngine"]
 
@@ -70,10 +81,16 @@ def _default_batch_buckets(max_batch: int) -> Tuple[int, ...]:
 class InferenceEngine:
     """Greedy-decode serving engine over a paged KV cache.
 
-    `model` is an LlamaForCausalLM-shaped layer: a `.config` dict naming the
-    stack's dims and a `forward(ids, cache=, positions=, last_index=)`
-    decode mode. `mesh` + `layout_table` place the weights for TP-sharded
-    decode (PR 7 SpecLayout); single-device when omitted.
+    `model` is a causal LM with a `forward(ids, cache=, positions=,
+    last_index=)` decode mode (LlamaForCausalLM, NemotronHForCausalLM) and a
+    `.config` dict that holds `num_hidden_layers`, `num_attention_heads`,
+    `hidden_size`, `vocab_size`, optionally `num_key_value_heads`,
+    `head_dim` (default hidden_size // num_attention_heads) and
+    `layer_kinds` (one of "attention", "mamba", "moe" a layer; default all
+    attention); with a "mamba" layer also `mamba_num_heads`,
+    `mamba_head_dim`, `ssm_state_size`, `n_groups`, `conv_kernel`. `mesh` +
+    `layout_table` place the weights for TP-sharded decode (PR 7
+    SpecLayout); single-device when omitted.
     """
 
     def __init__(
@@ -96,14 +113,23 @@ class InferenceEngine:
         cfg = dict(getattr(model, "config", {}))
         if not cfg:
             raise ValueError(
-                "InferenceEngine needs a model with a .config dict "
-                "(LlamaForCausalLM-shaped)"
+                "InferenceEngine needs a model with a .config dict: num_hidden_layers, "
+                "num_attention_heads, hidden_size, vocab_size, and optionally "
+                "num_key_value_heads, head_dim, layer_kinds (see the class docstring)"
             )
         self._model = model
         self.num_layers = int(cfg["num_hidden_layers"])
+        self.layer_kinds = tuple(cfg.get("layer_kinds") or ("attention",) * self.num_layers)
+        if len(self.layer_kinds) != self.num_layers:
+            raise ValueError(
+                f"model.config names {len(self.layer_kinds)} layer kinds for {self.num_layers} layers")
+        # layers that keep K/V pages, layers that keep a recurrent state
+        self.num_kv_layers = self.layer_kinds.count("attention")
+        self.num_state_layers = self.layer_kinds.count("mamba")
+        self._has_moe = "moe" in self.layer_kinds
         heads = int(cfg["num_attention_heads"])
         self.num_kv_heads = int(cfg.get("num_key_value_heads") or heads)
-        self.head_dim = int(cfg["hidden_size"]) // heads
+        self.head_dim = int(cfg.get("head_dim") or int(cfg["hidden_size"]) // heads)
         self.vocab_size = int(cfg["vocab_size"])
         self.max_seq_len = int(max_seq_len)
         self.block_size = int(block_size)
@@ -155,10 +181,18 @@ class InferenceEngine:
         if num_blocks is None:
             # worst case: every decode slot at full context, plus the trash page
             num_blocks = 1 + self.max_batch * self.max_pages
+        state_spec = None
+        if self.num_state_layers:
+            m_heads, m_dim = int(cfg["mamba_num_heads"]), int(cfg["mamba_head_dim"])
+            m_state = int(cfg["ssm_state_size"])
+            state_spec = StateSpec(
+                m_heads, m_dim, m_state, int(cfg["conv_kernel"]) - 1,
+                m_heads * m_dim + 2 * int(cfg["n_groups"]) * m_state)
         self.pool = BlockPool(
-            num_blocks, self.block_size, self.num_layers,
+            num_blocks, self.block_size, self.num_kv_layers,
             self.num_kv_heads, self.head_dim, dtype=w_dtype,
-            kv_dtype=kv_dtype,
+            kv_dtype=kv_dtype, state_layers=self.num_state_layers,
+            state_spec=state_spec, state_slots=self.max_batch,
         )
         # donation keeps exactly one pool copy live on TPU; CPU's donation
         # path only warns, so gate it on the platform
@@ -436,17 +470,20 @@ class InferenceEngine:
         step threads through (and donates)."""
         shape = (self.pool.num_blocks, self.num_kv_heads, self.block_size, self.head_dim)
         one = jax.ShapeDtypeStruct(shape, self.pool.dtype)
-        avals = {"k": [one] * self.num_layers, "v": [one] * self.num_layers}
+        avals = {"k": [one] * self.num_kv_layers, "v": [one] * self.num_kv_layers}
         if self.pool.quantized:
             sc = jax.ShapeDtypeStruct(shape[:3], jnp.float32)
-            avals["k_scale"] = [sc] * self.num_layers
-            avals["v_scale"] = [sc] * self.num_layers
+            avals["k_scale"] = [sc] * self.num_kv_layers
+            avals["v_scale"] = [sc] * self.num_kv_layers
+        if self.num_state_layers:
+            for key in ("ssm", "conv"):
+                avals[key] = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in getattr(self.pool, key)]
         return avals
 
     def _state_shardings(self):
         """NamedShardings matching _state_avals: pages follow the kv-head
         TP split; scale planes share it (their head axis is axis 1 too)."""
-        pages = [self._page_sharding] * self.num_layers
+        pages = [self._page_sharding] * self.num_kv_layers
         sh = {"k": pages, "v": list(pages)}
         if self.pool.quantized:
             if self._page_sharding is not self._repl:
@@ -456,25 +493,46 @@ class InferenceEngine:
                 sc = NamedSharding(self._mesh, P(*spec[:3]))
             else:
                 sc = self._repl
-            sh["k_scale"] = [sc] * self.num_layers
-            sh["v_scale"] = [sc] * self.num_layers
+            sh["k_scale"] = [sc] * self.num_kv_layers
+            sh["v_scale"] = [sc] * self.num_kv_layers
+        if self.num_state_layers:
+            # a recurrent layer's state is whole on every device
+            sh["ssm"] = [self._repl] * self.num_state_layers
+            sh["conv"] = [self._repl] * self.num_state_layers
         return sh
 
-    @staticmethod
-    def _view_from_state(state, bt, seq_lens, block_size, write_mask=None):
-        return PagedCacheView(
-            state["k"], state["v"], bt, seq_lens, block_size,
-            k_scales=state.get("k_scale"), v_scales=state.get("v_scale"),
-            write_mask=write_mask,
-        )
+    def _slot_avals(self, rows: int):
+        """The operand a model with recurrent layers adds to each program,
+        between the block tables and the state: every row's state slot."""
+        return (jax.ShapeDtypeStruct((rows,), jnp.int32),) if self.num_state_layers else ()
+
+    def _slots_of(self, page_rows, rows: int):
+        """That operand's value: each row's slot by its first page, the
+        trash slot for a row with no pages and for the bucket's padding."""
+        if not self.num_state_layers:
+            return ()
+        slots = np.zeros((rows,), np.int32)
+        for i, row in enumerate(page_rows):
+            if len(row):
+                slots[i] = self.pool.state_slot(row[0])
+        return (jnp.asarray(slots),)
 
     @staticmethod
-    def _state_from_view(view):
-        state = {"k": view.k_pages, "v": view.v_pages}
-        if view.k_scales is not None:
-            state["k_scale"] = view.k_scales
-            state["v_scale"] = view.v_scales
-        return state
+    def _with_counters(logits, view):
+        """What a program hands back to the host: the logits, and beside
+        them the expert layers' counters where the model has such layers."""
+        return logits if view.moe_counts is None else (logits, view.moe_counts)
+
+    def _fetch(self, out, take, span):
+        """The step's ONE fetch: the logits at `take` (the real rows) and,
+        for a model with expert layers, its counters onto the span."""
+        if not self._has_moe:
+            return np.asarray(out[take])
+        logits, counts = jax.device_get((out[0][take], out[1]))
+        span.args["moe_assignments"] = int(counts[0])
+        span.args["moe_experts_touched"] = int(counts[1])
+        span.args["moe_layers"] = int(counts[2])
+        return logits
 
     def _jit(self, fn, n_args: int):
         """fn's signature is (params, *scalars, cache_state) with the state
@@ -509,16 +567,17 @@ class InferenceEngine:
         from ..autograd import no_grad
 
         model, block_size = self._model, self.block_size
-        view_from, state_from = self._view_from_state, self._state_from_view
+        with_counters = self._with_counters
 
-        def fn(params, ids, true_len, bt, state):
-            view = view_from(state, bt, true_len, block_size)
+        def fn(params, ids, true_len, bt, *rest):
+            *slots, state = rest
+            view = PagedCacheView.from_state(state, bt, true_len, block_size, slots=slots[0] if slots else None)
             with no_grad():
                 logits = functional_call(
                     model, params, Tensor(ids), cache=view,
                     last_index=true_len - 1, training=False,
                 )
-            return logits.value, state_from(view)
+            return with_counters(logits.value, view), PagedCacheView.state_of(view)
 
         i32 = jnp.int32
         avals = (
@@ -526,9 +585,10 @@ class InferenceEngine:
             jax.ShapeDtypeStruct((1, S), i32),
             jax.ShapeDtypeStruct((1,), i32),
             jax.ShapeDtypeStruct((1, self.max_pages), i32),
+            *self._slot_avals(1),
             self._state_avals(),
         )
-        return self._jit(fn, 5).lower(*avals).compile()
+        return self._jit(fn, len(avals)).lower(*avals).compile()
 
     def _compile_decode(self, B: int):
         from ..core.tensor import Tensor
@@ -536,16 +596,17 @@ class InferenceEngine:
         from ..autograd import no_grad
 
         model, block_size = self._model, self.block_size
-        view_from, state_from = self._view_from_state, self._state_from_view
+        with_counters = self._with_counters
 
-        def fn(params, tokens, positions, seq_lens, bt, state):
-            view = view_from(state, bt, seq_lens, block_size)
+        def fn(params, tokens, positions, seq_lens, bt, *rest):
+            *slots, state = rest
+            view = PagedCacheView.from_state(state, bt, seq_lens, block_size, slots=slots[0] if slots else None)
             with no_grad():
                 logits = functional_call(
                     model, params, Tensor(tokens[:, None]), cache=view,
                     positions=positions, training=False,
                 )
-            return logits.value[:, 0], state_from(view)
+            return with_counters(logits.value[:, 0], view), PagedCacheView.state_of(view)
 
         i32 = jnp.int32
         avals = (
@@ -554,9 +615,10 @@ class InferenceEngine:
             jax.ShapeDtypeStruct((B,), i32),
             jax.ShapeDtypeStruct((B,), i32),
             jax.ShapeDtypeStruct((B, self.max_pages), i32),
+            *self._slot_avals(B),
             self._state_avals(),
         )
-        return self._jit(fn, 6).lower(*avals).compile()
+        return self._jit(fn, len(avals)).lower(*avals).compile()
 
     def _compile_extend(self, B: int, Q: int):
         """The extend/verify program (round 17): Q tokens per row written +
@@ -570,17 +632,16 @@ class InferenceEngine:
         from ..autograd import no_grad
 
         model, block_size = self._model, self.block_size
-        view_from, state_from = self._view_from_state, self._state_from_view
 
         def fn(params, tokens, positions, valid, bt, state):
-            view = view_from(state, bt, positions[:, -1] + 1, block_size,
-                             write_mask=valid)
+            view = PagedCacheView.from_state(state, bt, positions[:, -1] + 1, block_size,
+                                             write_mask=valid)
             with no_grad():
                 logits = functional_call(
                     model, params, Tensor(tokens), cache=view,
                     positions=positions, training=False,
                 )
-            return logits.value, state_from(view)
+            return logits.value, PagedCacheView.state_of(view)
 
         i32 = jnp.int32
         avals = (
@@ -601,20 +662,23 @@ class InferenceEngine:
         if L < 1 or L > self.max_seq_len:
             raise ValueError(f"prompt length {L} outside [1, {self.max_seq_len}]")
         S = self.bucket_for("prefill", L)
-        with RecordEvent("engine.prefill", args={"tokens": L, "bucket": S}):
+        with RecordEvent("engine.prefill", args={"tokens": L, "bucket": S}) as span:
             with RecordEvent("engine.prefill.inputs"):
                 ids = np.zeros((1, S), np.int32)
                 ids[0, :L] = np.asarray(prompt_ids, np.int32)
                 bt = np.asarray([self.pool.padded_table(pages, self.max_pages)], np.int32)
+                slots = self._slots_of([pages], 1)
+                if slots:
+                    span.args["state_slots"] = self.pool.state_slots_used()
             ex = self._get_compiled("prefill", S)
             with RecordEvent("engine.prefill.dispatch"):
                 logits, state = ex(
                     self.params, jnp.asarray(ids), jnp.asarray([L], jnp.int32),
-                    jnp.asarray(bt), self.pool.device_state(),
+                    jnp.asarray(bt), *slots, self.pool.device_state(),
                 )
                 self.pool.adopt_state(state)
             with RecordEvent("engine.prefill.fetch"):
-                out = np.asarray(logits[0])
+                out = self._fetch(logits, 0, span)
         self._mark_first_token()
         return out
 
@@ -655,15 +719,18 @@ class InferenceEngine:
                     bt[i] = self.pool.padded_table(row, self.max_pages)
                 span.args["context"] = int(lens[:n].sum())
                 self._count_page_blocks(span, lens - 1)
+                slots = self._slots_of(page_rows, B)
+                if slots:
+                    span.args["state_slots"] = self.pool.state_slots_used()
             ex = self._get_compiled("decode", B)
             with RecordEvent("engine.decode.dispatch"):
                 logits, state = ex(
                     self.params, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(lens),
-                    jnp.asarray(bt), self.pool.device_state(),
+                    jnp.asarray(bt), *slots, self.pool.device_state(),
                 )
                 self.pool.adopt_state(state)
             with RecordEvent("engine.decode.fetch"):
-                out = np.asarray(logits[:n])
+                out = self._fetch(logits, slice(0, n), span)
         self._mark_first_token()
         return out
 
@@ -683,6 +750,11 @@ class InferenceEngine:
         n = len(token_rows)
         if n < 1:
             raise ValueError("extend needs at least one sequence")
+        if self.num_state_layers:
+            raise NotImplementedError(
+                "extend: the model has recurrent layers, and several query tokens a row over a "
+                "live recurrent state need state snapshots (speculative verify and chunked "
+                "suffix prefill are for models whose layers all keep K/V)")
         B = self.bucket_for("decode", n)
         with RecordEvent("engine.extend", args={"rows": n, "bucket": B, "q_len": q_len}) as span:
             with RecordEvent("engine.extend.inputs"):

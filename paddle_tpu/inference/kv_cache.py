@@ -15,6 +15,16 @@ Page 0 is RESERVED as the trash page: block tables are padded with 0 past
 a sequence's last real page, so masked reads land on a valid page (never a
 fault) and padded-position writes scribble somewhere harmless.
 
+Two kinds of state live in one pool. K/V pages grow with a sequence and exist
+only for a model's ATTENTION layers. A recurrent layer (a state-space mixer)
+keeps a state of FIXED size a sequence instead: `StateSpec` describes it, and
+the pool then holds, a recurrent layer, `ssm` `[slots + 1, heads, head_dim,
+state]` float32 and `conv` `[slots + 1, kernel - 1, channels]` arrays. Slot 0
+is the trash slot (pad rows of a decode bucket step it); a slot is bound to a
+sequence through its first page (`state_slot`) and released when that page
+goes back to the free list. A recurrent state holds its whole prefix in one
+value, so such a pool has no prefix reuse and no page migration.
+
 Round 17 — prefix sharing + int8 storage:
 
 - Pages are REF-COUNTED. A page's KV depends on its whole token prefix, so
@@ -62,6 +72,7 @@ from ..telemetry import request_trace as _rt
 __all__ = [
     "BlockPool",
     "PagedCacheView",
+    "StateSpec",
     "PoolExhausted",
     "TRASH_PAGE",
     "chain_extend",
@@ -121,6 +132,20 @@ def prefix_chain_keys(tokens: Sequence[int], block_size: int) -> List[bytes]:
     return keys
 
 
+class StateSpec:
+    """The recurrent state ONE sequence keeps in each recurrent layer: the
+    SSM state `[heads, head_dim, state]` (float32) and the conv window, the
+    last `kernel - 1` rows of `channels` pre-conv features (the pool's
+    compute dtype)."""
+
+    def __init__(self, heads: int, head_dim: int, state: int, conv_rows: int, channels: int):
+        self.ssm_shape = (int(heads), int(head_dim), int(state))
+        self.conv_shape = (int(conv_rows), int(channels))
+
+    def slot_bytes(self, conv_itemsize: int) -> int:
+        return 4 * math.prod(self.ssm_shape) + conv_itemsize * math.prod(self.conv_shape)
+
+
 class PagedCacheView:
     """Functional view of the pool's device arrays for ONE traced step.
 
@@ -134,11 +159,20 @@ class PagedCacheView:
     rule and scatters value + scale together. `write_mask` [B, S] bool
     (optional) redirects masked positions' writes to the trash page — the
     engine's extend/verify program uses it to neutralize pad queries.
+
+    The second kind of state: `ssm` / `conv` hold one array a RECURRENT
+    layer (`[slots + 1, ...]`, see StateSpec) and `slots` [B] each row's slot
+    (0, the trash slot, for a pad row). `read_state` gives the rows' state (a
+    row at position 0 starts from zero) and `write_state` puts it back: by
+    row, or over the whole array in slot order when the step holds a third of
+    the slots or more (`slot_major`, with `to_slots` / `from_slots`).
+    `moe_counts` adds up what the expert layers report of one step.
     """
 
     def __init__(self, k_pages: Sequence, v_pages: Sequence, block_tables,
                  seq_lens, block_size: int, k_scales: Optional[Sequence] = None,
-                 v_scales: Optional[Sequence] = None, write_mask=None):
+                 v_scales: Optional[Sequence] = None, write_mask=None,
+                 ssm: Optional[Sequence] = None, conv: Optional[Sequence] = None, slots=None):
         self.k_pages = list(k_pages)
         self.v_pages = list(v_pages)
         self.k_scales = list(k_scales) if k_scales is not None else None
@@ -147,6 +181,29 @@ class PagedCacheView:
         self.seq_lens = jnp.asarray(seq_lens, jnp.int32)
         self.block_size = int(block_size)
         self.write_mask = write_mask
+        self.ssm = list(ssm) if ssm is not None else None
+        self.conv = list(conv) if conv is not None else None
+        self.slots = None if slots is None else jnp.asarray(slots, jnp.int32)
+        self.moe_counts = None  # [assignments, experts touched, layers] once an expert layer ran
+
+    @classmethod
+    def from_state(cls, state, block_tables, seq_lens, block_size, write_mask=None, slots=None):
+        """A view over a pool's state pytree (`BlockPool.device_state()`)."""
+        return cls(state["k"], state["v"], block_tables, seq_lens, block_size,
+                   k_scales=state.get("k_scale"), v_scales=state.get("v_scale"),
+                   write_mask=write_mask, ssm=state.get("ssm"), conv=state.get("conv"), slots=slots)
+
+    @staticmethod
+    def state_of(view) -> Dict[str, List]:
+        """The state pytree after the step, keys as `from_state` got them."""
+        state = {"k": view.k_pages, "v": view.v_pages}
+        if view.k_scales is not None:
+            state["k_scale"] = view.k_scales
+            state["v_scale"] = view.v_scales
+        if view.ssm is not None:
+            state["ssm"] = view.ssm
+            state["conv"] = view.conv
+        return state
 
     @property
     def num_layers(self) -> int:
@@ -165,6 +222,77 @@ class PagedCacheView:
         if self.k_scales is None:
             return None, None
         return self.k_scales[idx], self.v_scales[idx]
+
+    # ---- recurrent state ----
+    @property
+    def slot_major(self) -> bool:
+        """Whether this step visits the recurrent state in SLOT order. Rows
+        that gather their slots, step them and scatter them back move each
+        state three times (the gather's copy, the step, the scatter); a step
+        over the whole array in place moves every slot once. With a third of
+        the slots or more in the step the whole array is the cheaper, with
+        few rows (a small decode bucket, a prefill) the rows are."""
+        return 3 * self.slots.shape[0] >= self.ssm[0].shape[0]
+
+    def to_slots(self, x):
+        """Rows [B, ...] laid out by slot [slots + 1, ...] (a slot no row of
+        this step holds, and the trash slot, get some row's values: what they
+        compute is dropped by `write_state`)."""
+        n = self.ssm[0].shape[0]
+        row_of_slot = jnp.zeros((n,), jnp.int32).at[self.slots].set(
+            jnp.arange(self.slots.shape[0], dtype=jnp.int32))
+        return x[row_of_slot]
+
+    def from_slots(self, y):
+        """The inverse: each row's entry of a slot-ordered [slots + 1, ...]."""
+        return y[self.slots]
+
+    def read_state(self, idx: int, positions) -> Tuple:
+        """(ssm [R, heads, head_dim, state] f32, conv [R, rows, channels]) of
+        recurrent layer `idx`: one entry a row of this step, or one a slot
+        when `slot_major`. A sequence whose token sits at position 0 starts
+        here and reads zeros, whatever its slot held."""
+        raw = getattr(positions, "value", positions)
+        keep = jnp.asarray(raw, jnp.int32).reshape(-1) != 0
+        h, c = self.ssm[idx], self.conv[idx]
+        if self.slot_major:
+            keep = self.to_slots(keep)
+        else:
+            h, c = h[self.slots], c[self.slots]
+        return (jnp.where(keep[:, None, None, None], h, 0.0),
+                jnp.where(keep[:, None, None], c, jnp.zeros((), c.dtype)))
+
+    def write_state(self, idx: int, h, conv_rows) -> None:
+        """The step's new state back into the layer's arrays: row entries
+        scatter into their slots (pad rows all land on the trash slot 0),
+        slot entries replace the slots a real row of this step holds."""
+        h, conv_rows = h.astype(self.ssm[idx].dtype), conv_rows.astype(self.conv[idx].dtype)
+        n = self.ssm[idx].shape[0]
+        if h.shape[0] == n:  # slot entries (a step never has as many rows as the array has slots)
+            held = jnp.zeros((n,), bool).at[self.slots].set(True).at[0].set(False)
+            self.ssm[idx] = jnp.where(held[:, None, None, None], h, self.ssm[idx])
+            self.conv[idx] = jnp.where(held[:, None, None], conv_rows, self.conv[idx])
+        else:
+            self.ssm[idx] = self.ssm[idx].at[self.slots].set(h)
+            self.conv[idx] = self.conv[idx].at[self.slots].set(conv_rows)
+
+    def token_mask(self, b: int, s: int, positions):
+        """[B, S] bool: the tokens of this step that are real. A prefill
+        (positions None) pads its row past `seq_lens`; a decode or extend row
+        is real when it holds a page (`write_mask` narrows it further)."""
+        if positions is None:
+            return jnp.arange(s, dtype=jnp.int32)[None, :] < self.seq_lens.reshape(b, 1)
+        real = jnp.broadcast_to((self.block_tables[:, 0] != TRASH_PAGE)[:, None], (b, s))
+        if self.write_mask is not None:
+            real = real & jnp.asarray(self.write_mask, bool)
+        return real
+
+    def count_moe(self, assignments, experts_touched) -> None:
+        """One expert layer's report: (token, expert) pairs it computed and
+        held experts that got at least one."""
+        new = jnp.stack([jnp.asarray(assignments, jnp.int32), jnp.asarray(experts_touched, jnp.int32),
+                         jnp.ones((), jnp.int32)])
+        self.moe_counts = new if self.moe_counts is None else self.moe_counts + new
 
     def write(self, idx: int, k_new, v_new, positions) -> None:
         """Scatter new K/V into layer `idx`'s pages.
@@ -212,15 +340,25 @@ class BlockPool:
     `num_blocks` INCLUDES the reserved trash page 0; usable capacity is
     num_blocks - 1 pages.
     `kv_dtype="int8"` stores int8 pages with f32 scale planes alongside.
+
+    `num_layers` counts the layers that keep K/V (a model's attention
+    layers). `state_layers` recurrent layers, each a `state_spec` a sequence,
+    add the second kind of state: `state_slots` slots beside the trash slot 0
+    (module docstring); `used()`, `pool_bytes()` per page and `num_blocks`
+    keep their meaning (pages), `state_slots_used()` counts the slots bound
+    and `pool_bytes()` counts the state arrays too.
     """
 
     def __init__(self, num_blocks: int, block_size: int, num_layers: int,
                  num_kv_heads: int, head_dim: int, dtype=jnp.float32,
-                 kv_dtype: Optional[str] = None):
+                 kv_dtype: Optional[str] = None, state_layers: int = 0,
+                 state_spec: Optional[StateSpec] = None, state_slots: int = 0):
         if num_blocks < 2:
             raise ValueError("BlockPool needs >= 2 blocks (page 0 is reserved)")
         if kv_dtype not in (None, "int8"):
             raise ValueError(f"unsupported kv_dtype {kv_dtype!r} (int8 or None)")
+        if state_layers and (state_spec is None or state_slots < 1):
+            raise ValueError("a pool with recurrent layers needs their StateSpec and >= 1 slot")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.num_layers = int(num_layers)
@@ -243,6 +381,17 @@ class BlockPool:
         else:
             self.k_scales = None
             self.v_scales = None
+        # recurrent state: one array a recurrent layer, slot 0 the trash slot
+        self.state_layers = int(state_layers)
+        self.state_spec = state_spec if self.state_layers else None
+        self.state_slots = int(state_slots) if self.state_layers else 0
+        n_slots = self.state_slots + 1
+        self.ssm: List = [jnp.zeros((n_slots,) + state_spec.ssm_shape, jnp.float32)
+                          for _ in range(self.state_layers)]
+        self.conv: List = [jnp.zeros((n_slots,) + state_spec.conv_shape, dtype)
+                           for _ in range(self.state_layers)]
+        self._free_slots: List[int] = list(range(self.state_slots, 0, -1))
+        self._slot_of_page: Dict[int, int] = {}  # a sequence's FIRST page -> its slot
         # LIFO free list: recently-freed (cache-warm) pages hand out first
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
         # page -> refcount, for every page a request currently holds
@@ -304,7 +453,41 @@ class BlockPool:
         return data + scales
 
     def pool_bytes(self) -> int:
-        return self.num_blocks * self.page_bytes()
+        """Device bytes of everything the pool holds: pages and, where the
+        model has recurrent layers, their state arrays."""
+        return self.num_blocks * self.page_bytes() + self.state_bytes()
+
+    def state_bytes(self) -> int:
+        if not self.state_layers:
+            return 0
+        per_slot = self.state_spec.slot_bytes(jnp.dtype(self.compute_dtype).itemsize)
+        return self.state_layers * (self.state_slots + 1) * per_slot
+
+    # ---- recurrent-state slots ----
+    @property
+    def has_recurrent_state(self) -> bool:
+        return self.state_layers > 0
+
+    def state_slots_used(self) -> int:
+        """Slots bound to a sequence."""
+        return len(self._slot_of_page)
+
+    def state_slot(self, first_page: int) -> int:
+        """The slot of the sequence whose first page is `first_page`, bound
+        on first sight; it is released when that page returns to the free
+        list. The trash page (a row with no pages) has the trash slot."""
+        page = int(first_page)
+        if page == TRASH_PAGE or not self.state_layers:
+            return 0
+        slot = self._slot_of_page.get(page)
+        if slot is None:
+            if page not in self._refs:
+                raise ValueError(f"state_slot of page {page} that no sequence holds")
+            if not self._free_slots:
+                raise PoolExhausted(
+                    f"recurrent-state slots exhausted: {self.state_slots} sequences hold one")
+            slot = self._slot_of_page[page] = self._free_slots.pop()
+        return slot
 
     def _sync_gauges(self) -> None:
         _pool_gauge("used").set(self.used())
@@ -403,6 +586,9 @@ class BlockPool:
                 self._refs[p] = ref - 1
                 continue
             del self._refs[p]
+            slot = self._slot_of_page.pop(p, None)
+            if slot is not None:
+                self._free_slots.append(slot)
             key = self._page_key.get(p)
             if retain and key is not None:
                 self._retained[p] = key  # MRU end
@@ -422,6 +608,8 @@ class BlockPool:
 
     def reset(self) -> None:
         self._free = list(range(self.num_blocks - 1, 0, -1))
+        self._free_slots = list(range(self.state_slots, 0, -1))
+        self._slot_of_page.clear()
         self._refs.clear()
         self._retained.clear()
         self._prefix.clear()
@@ -544,23 +732,25 @@ class BlockPool:
         return new
 
     # ---- device-array plumbing ----
-    def view(self, block_tables, seq_lens, write_mask=None) -> PagedCacheView:
+    def view(self, block_tables, seq_lens, write_mask=None, slots=None) -> PagedCacheView:
         """Eager-path view over the pool's current arrays: run the model
-        with `cache=view`, then `adopt(view.k_pages, view.v_pages)` (or
-        `adopt_state(...)` on a quantized pool)."""
-        return PagedCacheView(
-            self.k_pages, self.v_pages, block_tables, seq_lens, self.block_size,
-            k_scales=self.k_scales, v_scales=self.v_scales, write_mask=write_mask,
-        )
+        with `cache=view`, then `adopt_state(PagedCacheView.state_of(view))`
+        (`adopt(view.k_pages, view.v_pages)` does for a plain pool). A pool
+        with recurrent state wants each row's `slots`."""
+        return PagedCacheView.from_state(self.device_state(), block_tables, seq_lens,
+                                         self.block_size, write_mask=write_mask, slots=slots)
 
     def device_state(self) -> Dict[str, List]:
         """The pool's device arrays as ONE pytree, for threading through
         compiled steps (donated whole; scale planes ride along when
-        quantized)."""
+        quantized, the recurrent layers' state arrays where there are any)."""
         state = {"k": list(self.k_pages), "v": list(self.v_pages)}
         if self.k_scales is not None:
             state["k_scale"] = list(self.k_scales)
             state["v_scale"] = list(self.v_scales)
+        if self.state_layers:
+            state["ssm"] = list(self.ssm)
+            state["conv"] = list(self.conv)
         return state
 
     def adopt_state(self, state: Dict[str, List]) -> None:
@@ -570,6 +760,11 @@ class BlockPool:
                 raise ValueError("quantized pool state is missing scale planes")
             self.k_scales = list(state["k_scale"])
             self.v_scales = list(state["v_scale"])
+        if self.state_layers:
+            if len(state.get("ssm", ())) != self.state_layers:
+                raise ValueError("pool state is missing the recurrent layers' arrays")
+            self.ssm = list(state["ssm"])
+            self.conv = list(state["conv"])
 
     def adopt(self, k_pages: Sequence, v_pages: Sequence) -> None:
         """Install a step's updated page arrays back into the pool."""
@@ -598,10 +793,18 @@ class BlockPool:
 # single read, and the caller falls back to recompute-on-resume.
 
 
+def _refuse_recurrent(pool: BlockPool, what: str) -> None:
+    if pool.has_recurrent_state:
+        raise ValueError(
+            f"{what}: the pool holds recurrent-layer state, which pages do not carry — "
+            "a sequence of such a model cannot migrate by its pages (recompute on the destination)")
+
+
 def export_pages(pool: BlockPool, pages: Sequence[int]) -> Dict:
     """Gather `pages`' K/V (plus scale planes on a quantized pool) into a
     host payload for cross-pool migration. Page order is preserved — entry
     j of every plane is the content of pages[j]."""
+    _refuse_recurrent(pool, "export_pages")
     idx = jnp.asarray(list(pages), jnp.int32)
     payload: Dict = {
         "kv_dtype": pool.kv_dtype,
@@ -661,6 +864,7 @@ def payload_page_crcs(payload: Dict) -> List[int]:
 def import_pages(pool: BlockPool, pages: Sequence[int], payload: Dict) -> None:
     """Scatter a (converted) payload into already-allocated `pages` of
     `pool`. The payload's kv_dtype must match the pool's — convert first."""
+    _refuse_recurrent(pool, "import_pages")
     if payload["kv_dtype"] != pool.kv_dtype:
         raise ValueError(
             f"payload kv_dtype {payload['kv_dtype']!r} does not match the "

@@ -48,6 +48,12 @@ Round 17 — prefix sharing + speculative decoding:
   the tokens plain decode would — byte-identical outputs, fewer steps.
   Prompt streaming rides the same program `draft_len + 1` tokens per
   step (chunked prefill at chunk granularity).
+- A model with recurrent layers (the pool then holds a state slot a
+  sequence, `pool.has_recurrent_state`) gets neither: prefix lookup and
+  registration are skipped and `spec_decode` is refused, because both need
+  snapshots of the state. Its slot is bound with the request's first page
+  and released with it: finish, expiry, shed and preemption free the pages,
+  and a preempted request streams again from position 0, from the zero state.
 
 Round 19 — overload protection & multi-tenant QoS (inference/qos.py):
 
@@ -172,10 +178,13 @@ class SpecDecodeConfig:
             raise ValueError("SpecDecodeConfig.ngram must be >= 1")
 
 
-@dataclass
+@dataclass(eq=False)
 class Request:
     """One generation request. `prompt` is token ids; the scheduler fills
-    the runtime fields."""
+    the runtime fields. A request is itself and no other (`eq=False`): the
+    step asks `req in self.running` several times a row, and compared by
+    value every miss built two tuples of all fields — 16,000 of them, 22 ms
+    of host time, a step over 128 rows."""
 
     rid: int
     prompt: List[int]
@@ -293,7 +302,17 @@ class ContinuousBatchingScheduler:
             raise ValueError("max_running exceeds the engine's decode capacity")
         self.eos_id = eos_id
         self.clock = clock
-        self.prefix_cache = bool(prefix_cache)
+        # a recurrent layer's state holds a sequence's whole prefix in one
+        # value: no prefix of a prompt can be skipped because its pages are
+        # resident, and no draft chain can be verified and rolled back, until
+        # the pool keeps state snapshots — the scheduler acts on what the
+        # engine's pool holds
+        recurrent = engine.pool.has_recurrent_state
+        if recurrent and spec_decode is not None:
+            raise ValueError(
+                "spec_decode: the engine's pool holds recurrent-layer state; verifying a draft "
+                "chain needs engine.extend and a rollback of the state, which need snapshots")
+        self.prefix_cache = bool(prefix_cache) and not recurrent
         self.spec = spec_decode
         # "auto" (default): idle-scheduler admissions run a bucketed prefill
         # program, busy ones stream. "streamed": NEVER bucketed — the
@@ -966,6 +985,8 @@ class ContinuousBatchingScheduler:
                                     else 0.8 * self.ewma_step_s + 0.2 * dt)
             span.args = {"produced": produced, "running": len(self.running),
                          "waiting": len(self.waiting)}
+            if self.engine.pool.has_recurrent_state:
+                span.args["state_slots"] = self.engine.pool.state_slots_used()
         return produced
 
     def _qos_pre_step(self, now: float) -> None:

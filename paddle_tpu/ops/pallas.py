@@ -1217,3 +1217,159 @@ def flash_decode_paged_multi(q, k_pages, v_pages, block_tables, q_positions,
                                      sm_scale, k_scales=k_scales, v_scales=v_scales)
     return paged_extend_reference(q, k_pages, v_pages, block_tables, q_positions,
                                   sm_scale, k_scales=k_scales, v_scales=v_scales)
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul over the experts a mixture-of-experts layer HOLDS (moe_gmm)
+# ---------------------------------------------------------------------------
+#
+# A dropless expert layer sorts its (token, expert) assignments by expert and
+# multiplies each group of rows by its expert's matrix. The rows are laid out
+# so that every tile of `MOE_TILE_M` rows belongs to ONE expert (each group is
+# padded up to whole tiles with zero rows): a grid step is one tile against
+# one expert's matrix, consecutive tiles of an expert name the same weight
+# block (no second copy), and the tiles past the last group compute nothing
+# and start no copy (their index maps name the last live tile again, as the
+# paged kernel's dead steps do).
+
+MOE_TILE_M = 16            # rows a grid step multiplies: one bf16 sublane tile
+_MOE_WEIGHT_BLOCK = 6 << 20  # bytes of one expert's weight block in VMEM (x2 buffers)
+
+
+def moe_padded_rows(assignments: int, groups: int, tile_m: int = MOE_TILE_M) -> int:
+    """Rows the padded layout needs in the worst case: every assignment
+    held, and every group that can be non-empty one row over a tile."""
+    rows = assignments + min(groups, assignments) * (tile_m - 1)
+    return -(-rows // tile_m) * tile_m
+
+
+def moe_group_layout(group_ids, groups: int, tile_m: int = MOE_TILE_M):
+    """Where each assignment's row goes. `group_ids` [A] int32: the group
+    (held expert) of each assignment, `groups` for one that is not computed
+    here (an absent expert, a pad row). Returns
+
+        dest       [A] int32   the assignment's row in the padded layout,
+                               `moe_padded_rows(A, groups)` for a dropped one
+        tile_group [tiles]     the group of each tile of `tile_m` rows
+        live       [1]  int32  tiles that hold rows (the rest are dead)
+        sizes      [groups]    assignments of each group
+
+    Rows of a group are consecutive and in the order of `group_ids` (a
+    stable sort), each group starts on a tile boundary."""
+    group_ids = jnp.asarray(group_ids, jnp.int32)
+    a = group_ids.shape[0]
+    rows = moe_padded_rows(a, groups, tile_m)
+    sizes = jnp.zeros((groups + 1,), jnp.int32).at[group_ids].add(1)[:groups]
+    padded = -(-sizes // tile_m) * tile_m
+    ends_p = jnp.cumsum(padded)
+    starts_p, starts = ends_p - padded, jnp.cumsum(sizes) - sizes
+    order = jnp.argsort(group_ids, stable=True)
+    g = group_ids[order]
+    gc = jnp.minimum(g, groups - 1)
+    dest_sorted = jnp.where(g < groups, starts_p[gc] + jnp.arange(a, dtype=jnp.int32) - starts[gc], rows)
+    dest = jnp.zeros((a,), jnp.int32).at[order].set(dest_sorted.astype(jnp.int32))
+    tile_start = jnp.arange(rows // tile_m, dtype=jnp.int32) * tile_m
+    tile_group = jnp.minimum(jnp.searchsorted(ends_p, tile_start, side="right"), groups - 1)
+    live = (ends_p[-1] // tile_m).astype(jnp.int32).reshape(1)
+    return dest, tile_group.astype(jnp.int32), live, sizes
+
+
+def _moe_tile_n(k: int, n: int, itemsize: int) -> int:
+    """Columns of an expert's matrix a grid step holds: all of them where
+    the block fits the budget (one contiguous copy), else the largest
+    lane-aligned divisor that does."""
+    if n % _DECODE_LANES or k * n * itemsize <= _MOE_WEIGHT_BLOCK:
+        return n
+    fits = [t for t in range(_DECODE_LANES, n, _DECODE_LANES)
+            if n % t == 0 and k * t * itemsize <= _MOE_WEIGHT_BLOCK]
+    return max(fits) if fits else _DECODE_LANES
+
+
+def _apply_moe_activation(acc, activation):
+    if activation == "relu2":
+        return jnp.square(jnp.maximum(acc, 0.0))
+    if activation is None:
+        return acc
+    raise ValueError(f"moe_gmm: unknown activation {activation!r}")
+
+
+def moe_gmm_reference(x_rows, w, tile_group, live, activation=None, out_dtype=None,
+                      tile_m: int = MOE_TILE_M):
+    """jnp oracle for the grouped matmul (and the off-TPU path): each tile
+    of rows against its group's matrix, f32 accumulation; dead tiles give
+    zeros (the kernel leaves them unwritten: nothing may read them)."""
+    out_dtype = out_dtype or x_rows.dtype
+    tiles = x_rows.shape[0] // tile_m
+    xt = x_rows.reshape(tiles, tile_m, -1)
+    acc = jnp.einsum("tmk,tkn->tmn", xt, w[tile_group], preferred_element_type=jnp.float32)
+    acc = _apply_moe_activation(acc, activation)
+    alive = jnp.arange(tiles)[:, None, None] < live[0]
+    return jnp.where(alive, acc, 0.0).astype(out_dtype).reshape(tiles * tile_m, -1)
+
+
+def _moe_gmm_impl(x_rows, w, tile_group, live, activation, out_dtype, tile_m):
+    rows, k = x_rows.shape
+    _, _, n = w.shape
+    tn = _moe_tile_n(k, n, jnp.dtype(w.dtype).itemsize)
+
+    def kernel(tg_ref, live_ref, x_ref, w_ref, o_ref):
+        @pl.when(pl.program_id(1) < live_ref[0])
+        def _tile():
+            acc = _dot_nn(x_ref[...], w_ref[...])
+            o_ref[...] = _apply_moe_activation(acc, activation).astype(o_ref.dtype)
+
+    def tile(i, lv):
+        # a dead step names the last live tile again: no copy starts
+        return jnp.maximum(jnp.minimum(i, lv[0] - 1), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # the group of each tile, the live tile count
+        grid=(n // tn, rows // tile_m),
+        in_specs=[
+            pl.BlockSpec((tile_m, k), lambda j, i, tg, lv: (tile(i, lv), 0)),
+            pl.BlockSpec((None, k, tn), lambda j, i, tg, lv: (tg[tile(i, lv)], 0, j)),
+        ],
+        out_specs=pl.BlockSpec((tile_m, tn), lambda j, i, tg, lv: (tile(i, lv), j)),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, n), out_dtype),
+        # a dead step REVISITS the last live tile's out block: sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=_INTERPRET,
+        name="moe_gmm",
+    )(tile_group, live, x_rows, w)
+
+
+@functools.partial(jax.jit, static_argnames=("activation", "out_dtype", "tile_m"))
+def _moe_gmm_jit(x_rows, w, tile_group, live, activation=None, out_dtype=None,
+                 tile_m=MOE_TILE_M):
+    return _moe_gmm_impl(x_rows, w, tile_group, live, activation, out_dtype, tile_m)
+
+
+def moe_gmm(x_rows, w, tile_group, live, activation=None, out_dtype=None,
+            tile_m: int = MOE_TILE_M):
+    """Grouped matmul over the experts held: rows `x_rows` [R, K] in the
+    layout of `moe_group_layout` (R a multiple of `tile_m`, every tile one
+    group's rows), `w` [groups, K, N] one matrix a group, `tile_group`
+    [R / tile_m] and `live` [1] from the layout. Returns [R, N] in
+    `out_dtype` (default: x's), `activation` ("relu2" or None) applied to
+    the f32 accumulator. Rows of dead tiles are NOT written on the chip.
+
+    Dispatches the Pallas kernel `moe_gmm` on TPU (or under interpret
+    mode), else the jnp reference."""
+    if x_rows.ndim != 2 or w.ndim != 3 or x_rows.shape[1] != w.shape[1]:
+        raise ValueError(f"moe_gmm: x_rows {x_rows.shape} does not fit w {w.shape} ([groups, K, N])")
+    if x_rows.shape[0] % tile_m or tile_group.shape[0] != x_rows.shape[0] // tile_m:
+        raise ValueError(f"moe_gmm: {x_rows.shape[0]} rows are not {tile_group.shape[0]} tiles of {tile_m}")
+    out_dtype = jnp.dtype(out_dtype or x_rows.dtype)
+    if _on_tpu():
+        with jax.enable_x64(False):
+            return _moe_gmm_jit(x_rows, w, jnp.asarray(tile_group, jnp.int32),
+                                jnp.asarray(live, jnp.int32), activation=activation,
+                                out_dtype=out_dtype, tile_m=tile_m)
+    return moe_gmm_reference(x_rows, w, tile_group, live, activation, out_dtype, tile_m)

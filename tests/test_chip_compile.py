@@ -132,6 +132,34 @@ def test_paged_attention_compiles_at_the_cells_shapes(one_chip, B, M, q_len):
     assert _paged_kernels(one_chip, BF16, BF16, q_len, B=B, N=4097, M=M) == 1
 
 
+def test_paged_attention_compiles_at_the_hybrid_cells_shapes(one_chip):
+    """The hybrid decoder's one attention layer: 32 query heads over 2 kv
+    heads (16 queries a kv head against the dense decoder's 4), the 128-row
+    bucket over a 64-page table, the pool of 8193 pages."""
+    H, HKV, D, BS, B, M, N = 32, 2, 128, 16, 128, 64, 8193
+    pages = _aval(one_chip, (N, HKV, BS, D), BF16)
+    assert _kernels(pk.flash_decode_paged, _aval(one_chip, (B, H, D), BF16), pages, pages,
+                    _aval(one_chip, (B, M), jnp.int32), _aval(one_chip, (B,), jnp.int32)) == 1
+
+
+@pytest.mark.parametrize("k, n, activation, out_dtype", [
+    (1024, 2688, "relu2", BF16), (2688, 1024, None, jnp.float32)], ids=["up_relu2", "down_f32"])
+def test_moe_gmm_compiles_at_the_cells_shapes(one_chip, k, n, activation, out_dtype):
+    """The expert layer's grouped matmul at the hybrid cell's decode shapes:
+    128 rows x 22 choices (704 of them land on the 128 experts held, on
+    average; the padded layout holds the worst case), latent 1024 x expert
+    width 2688, bf16, under the framework's global x64."""
+    assignments, groups = 128 * 22, 128
+    rows = pk.moe_padded_rows(assignments, groups)
+    tiles = rows // pk.MOE_TILE_M
+
+    def fn(x_rows, w, tile_group, live):
+        return pk.moe_gmm(x_rows, w, tile_group, live, activation=activation, out_dtype=out_dtype)
+
+    assert _kernel_names(fn, _aval(one_chip, (rows, k), BF16), _aval(one_chip, (groups, k, n), BF16),
+                         _aval(one_chip, (tiles,), jnp.int32), _aval(one_chip, (1,), jnp.int32)) == ["moe_gmm"]
+
+
 @pytest.mark.parametrize("m2_dtype", [jnp.float32, BF16], ids=["m2_f32", "m2_bf16"])
 def test_fused_adamw_compiles_under_global_x64(one_chip, m2_dtype):
     """Through the public fused_adamw_apply, traced as its callers trace it
