@@ -294,23 +294,51 @@ class PagedCacheView:
                          jnp.ones((), jnp.int32)])
         self.moe_counts = new if self.moe_counts is None else self.moe_counts + new
 
-    def write(self, idx: int, k_new, v_new, positions) -> None:
-        """Scatter new K/V into layer `idx`'s pages.
+    def write(self, idx: int, k_new, v_new, positions=None) -> None:
+        """Put new K/V into layer `idx`'s pages, in place.
 
         k_new/v_new [B, S, Hkv, D]; positions [B, S] int32 absolute token
-        positions. Position p of row b lands in page block_tables[b, p//bs]
-        slot p % bs (pages are [N, Hkv, bs, D]: the page and slot indices
-        straddle the head axis, so the scattered update is [B, S, Hkv, D] —
-        exactly k_new's layout); positions past a row's real pages hit table padding
-        (the trash page) by construction, and write_mask=False positions
-        are redirected to the trash page explicitly.
+        positions, or None for a prefill, whose tokens sit at 0..S-1.
+        Position p of row b lands in page block_tables[b, p//bs] slot p % bs.
+        Pages are [N, Hkv, bs, D] and neither form below leaves XLA's TPU
+        backend anything to re-lay: it updates the donated pool where it lies
+        (a `[pages, :, slots]` index straddles the head axis, and the
+        compiler then copies the whole pool to another layout before the
+        scatter and back after it, whatever the rows).
+
+        - Positioned rows (decode, extend): ONE scatter that indexes page, kv
+          head and slot together, its window a single [D] vector. An update
+          costs the chip about 70 ns, so this is for steps of few tokens.
+          write_mask=False positions are redirected to the trash page, and
+          pad rows all land on the trash page's slot 0: the indices are NOT
+          unique.
+        - A prefill fills whole pages from each row's first: the tokens are
+          cut into pages ([B, S/bs, Hkv, bs, D], zeros past S) and scattered
+          along the page axis alone, 32 KB contiguous an update. Slots past
+          the prompt in its last page, and the table's padding (the trash
+          page), take what the bucket's padding computed: nobody reads them.
         """
-        positions = jnp.asarray(positions, jnp.int32)
+        b, s, hkv = k_new.shape[:3]
         bs = self.block_size
-        pages = jnp.take_along_axis(self.block_tables, positions // bs, axis=1)
-        if self.write_mask is not None:
-            pages = jnp.where(jnp.asarray(self.write_mask, bool), pages, TRASH_PAGE)
-        slots = positions % bs
+        if positions is None:
+            if self.write_mask is not None:
+                raise ValueError("write_mask narrows positioned writes; a prefill has none")
+            n = -(-s // bs)
+            pages = self.block_tables[:, :n]
+
+            def put(pool, new):  # new [B, S, Hkv, ...]
+                new = jnp.pad(new, [(0, 0), (0, n * bs - s)] + [(0, 0)] * (new.ndim - 2))
+                return pool.at[pages].set(jnp.swapaxes(new.reshape(b, n, bs, *new.shape[2:]), 2, 3))
+        else:
+            positions = jnp.asarray(positions, jnp.int32)
+            pages = jnp.take_along_axis(self.block_tables, positions // bs, axis=1)
+            if self.write_mask is not None:
+                pages = jnp.where(jnp.asarray(self.write_mask, bool), pages, TRASH_PAGE)
+            at = (pages[..., None], jnp.arange(hkv, dtype=jnp.int32), (positions % bs)[..., None])
+
+            def put(pool, new):
+                return pool.at[at].set(new)
+
         if self.k_scales is not None:
             # int8 storage: per-slot-per-kv-head absmax scales — the
             # observer rule (quantization/observers), applied per written
@@ -319,15 +347,12 @@ class PagedCacheView:
 
             k_sc = absmax_scale(k_new, axis=-1)  # [B, S, Hkv] f32
             v_sc = absmax_scale(v_new, axis=-1)
-            k_q = quantize_absmax(k_new, k_sc[..., None])
-            v_q = quantize_absmax(v_new, v_sc[..., None])
-            self.k_pages[idx] = self.k_pages[idx].at[pages, :, slots].set(k_q)
-            self.v_pages[idx] = self.v_pages[idx].at[pages, :, slots].set(v_q)
-            self.k_scales[idx] = self.k_scales[idx].at[pages, :, slots].set(k_sc)
-            self.v_scales[idx] = self.v_scales[idx].at[pages, :, slots].set(v_sc)
-        else:
-            self.k_pages[idx] = self.k_pages[idx].at[pages, :, slots].set(k_new)
-            self.v_pages[idx] = self.v_pages[idx].at[pages, :, slots].set(v_new)
+            k_new = quantize_absmax(k_new, k_sc[..., None])
+            v_new = quantize_absmax(v_new, v_sc[..., None])
+            self.k_scales[idx] = put(self.k_scales[idx], k_sc)
+            self.v_scales[idx] = put(self.v_scales[idx], v_sc)
+        self.k_pages[idx] = put(self.k_pages[idx], k_new)
+        self.v_pages[idx] = put(self.v_pages[idx], v_new)
 
 
 class BlockPool:

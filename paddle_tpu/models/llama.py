@@ -109,9 +109,8 @@ class LlamaAttention(nn.Layer):
         from ..ops.pallas import flash_decode_paged, flash_decode_paged_multi
 
         max_pos = cache.block_tables.shape[1] * cache.block_size
-        if positions is None:
-            pos2d = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-        else:
+        pos2d = None  # a prefill: tokens at 0..S-1, for the rotation and for the write
+        if positions is not None:
             raw_pos = positions.value if isinstance(positions, Tensor) else positions
             pos2d = jnp.asarray(raw_pos, jnp.int32).reshape(b, -1)
         qr, kr = _rope(q.value, k.value, positions=pos2d, max_pos=max_pos)

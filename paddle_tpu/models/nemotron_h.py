@@ -215,8 +215,7 @@ class NemotronHAttention(nn.Layer):
         v = manip.reshape(self.v_proj(x), [b, s, self.num_kv_heads, self.head_dim])
         if cache is None or positions is None:
             if cache is not None:
-                pos2d = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-                cache.write(self.layer_idx, k.value, v.value, pos2d)
+                cache.write(self.layer_idx, k.value, v.value)
             out = F.scaled_dot_product_attention(q, k, v, is_causal=True, training=self.training)
         else:
             raw = positions.value if isinstance(positions, Tensor) else positions

@@ -14,6 +14,7 @@ The dispatch gate `_on_tpu()` still sees the CPU, so each test steers it
 with monkeypatch — in the test, not through an option of the program.
 """
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
 
@@ -140,6 +141,95 @@ def test_paged_attention_compiles_at_the_hybrid_cells_shapes(one_chip):
     pages = _aval(one_chip, (N, HKV, BS, D), BF16)
     assert _kernels(pk.flash_decode_paged, _aval(one_chip, (B, H, D), BF16), pages, pages,
                     _aval(one_chip, (B, M), jnp.int32), _aval(one_chip, (B,), jnp.int32)) == 1
+
+
+def _pool_copies(compiled, pool_aval):
+    """`copy` instructions of the optimized program whose result has the
+    pool's shape: the change of layout XLA puts around a scatter it cannot
+    make in place, a layer's whole pool read and written each."""
+    dt = {"bfloat16": "bf16", "int8": "s8"}[str(pool_aval.dtype)]
+    shape = re.escape(f"{dt}[{','.join(map(str, pool_aval.shape))}]")
+    return re.findall(r"= " + shape + r"\S* copy\(", compiled.as_text())
+
+
+@pytest.mark.parametrize("n, hkv, b, s, m, how, pool_dtype", [
+    (4097, 8, 32, 1, 64, "positions", BF16), (4097, 8, 1, 512, 64, "positions", BF16),
+    (4097, 8, 4, 4, 64, "masked", BF16), (8193, 2, 128, 1, 64, "positions", BF16),
+    (4097, 8, 32, 1, 64, "positions", jnp.int8),
+    (4097, 8, 1, 512, 64, "prefill", BF16), (4097, 8, 1, 4096, 256, "prefill", BF16),
+    (8193, 2, 1, 256, 64, "prefill", BF16), (4097, 8, 1, 512, 64, "prefill", jnp.int8),
+], ids=["decode_b32", "extend_1x512", "extend_4x4_masked", "hybrid_decode_b128", "int8_decode_b32",
+        "prefill_s512", "prefill_s4096", "hybrid_prefill_s256", "int8_prefill_s512"])
+def test_kv_write_is_in_place(one_chip, n, hkv, b, s, m, how, pool_dtype):
+    """`PagedCacheView.write` at the serving cells' shapes (the dense
+    decoder's pool, the hybrid's one attention layer, the int8 pool's pages),
+    the pool donated: positioned rows through the scatter by page, head and
+    slot, a prefill (positions None) by whole pages; either updates the pool
+    where it lies."""
+    from paddle_tpu.inference.kv_cache import PagedCacheView
+
+    bs, d = 16, 128
+    pool = _aval(one_chip, (n, hkv, bs, d), pool_dtype)
+    new = _aval(one_chip, (b, s, hkv, d), BF16)
+    state = {"k": [pool], "v": [pool]}
+    if pool_dtype == jnp.int8:
+        state["k_scale"] = state["v_scale"] = [_aval(one_chip, (n, hkv, bs), jnp.float32)]
+
+    def fn(state, bt, k_new, v_new, positions=None, mask=None):
+        view = PagedCacheView.from_state(state, bt, jnp.zeros((b,), jnp.int32), bs, write_mask=mask)
+        view.write(0, k_new, v_new, positions)
+        return PagedCacheView.state_of(view)
+
+    extra = {"prefill": (), "positions": (_aval(one_chip, (b, s), jnp.int32),),
+             "masked": (_aval(one_chip, (b, s), jnp.int32), _aval(one_chip, (b, s), jnp.bool_))}[how]
+    compiled = jax.jit(fn, donate_argnums=0).lower(
+        state, _aval(one_chip, (b, m), jnp.int32), new, new, *extra).compile()
+    assert _pool_copies(compiled, pool) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 24 << 20   # a copy of the bf16 pool is 134 MB
+
+
+@pytest.mark.parametrize("program, size", [("decode", 32), ("prefill", 512), ("extend", (4, 4))],
+                         ids=["decode_b32", "prefill_s512", "extend_4x4"])
+def test_engine_program_holds_no_copy_of_the_pool(one_chip, monkeypatch, program, size):
+    """The whole program: the engine's 32-row decode, 512-token prefill and
+    (4, 4) extend of a 2-layer decoder at Mistral widths (no weight and no
+    page exists: model and engine are built under `jax.eval_shape`), state
+    donated as on the chip."""
+    from paddle_tpu.inference.engine import InferenceEngine
+    from paddle_tpu.models.llama import LlamaForCausalLM
+
+    box = {}
+
+    def construct():
+        model = LlamaForCausalLM(vocab_size=32000, hidden_size=4096, num_hidden_layers=2,
+                                 num_attention_heads=32, num_key_value_heads=8,
+                                 intermediate_size=14336, rms_norm_eps=1e-5)
+        model.eval()
+        for t in model.state_dict().values():
+            t._value = jax.ShapeDtypeStruct(t.shape, BF16)
+        box["engine"] = InferenceEngine(model, max_seq_len=1024, block_size=16, num_blocks=4097, max_batch=32)
+        return 0
+
+    jax.eval_shape(construct)
+    paddle.seed(0)  # the constructor's draws left a traced key behind
+    engine = box["engine"]
+    jit = engine._jit
+
+    class ForTheChip:
+        """The engine lowers avals that name no device: name the described one."""
+        def __init__(self, jitted):
+            self.jitted = jitted
+
+        def lower(self, *avals):
+            return self.jitted.lower(*jax.tree.map(lambda a: _aval(one_chip, a.shape, a.dtype), avals))
+
+    monkeypatch.setattr(engine, "_donate", True)
+    monkeypatch.setattr(engine, "_jit", lambda fn, n_args: ForTheChip(jit(fn, n_args)))
+    compile_ = getattr(engine, "_compile_" + program)
+    compiled = compile_(*size) if isinstance(size, tuple) else compile_(size)
+    assert compiled.as_text().count(" scatter(") == 4   # K and V written, a layer
+    assert _pool_copies(compiled, engine._state_avals()["k"][0]) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20   # a copy of the pool is 134 MB
 
 
 @pytest.mark.parametrize("k, n, activation, out_dtype", [
