@@ -887,91 +887,6 @@ def _build_serving():
                                            "kv_heads", "ffn", "max_seq",
                                            "block_size", "max_batch", "seed",
                                            "gap_s")}
-
-    # ---- round 18: warm-vs-cold engine start on a persistent compile
-    # cache. Cold = fresh engine against an EMPTY cache dir (prewarm pays
-    # XLA for every bucket, persists each executable); warm = a simulated
-    # relaunch (in-process shared registry cleared, same dir) whose prewarm
-    # restores every bucket from disk. The TTFTs measured here are
-    # engine-construction -> first generated token — the cold-start wall
-    # `python -m paddle_tpu.compile_cache report` decomposes — not the
-    # steady-state request TTFT above. perf_gate gates cold/warm TTFT
-    # (time) and the warm relaunch's cache_hit_rate (throughput). ----
-    def coldstart_sub():
-        import shutil
-        import tempfile
-
-        from paddle_tpu import compile_cache as _cc
-
-        skip = os.environ.get("BENCH_SKIP_COLDSTART", "").lower()
-        if skip in ("1", "true", "yes"):
-            return {"coldstart": {"skipped": "BENCH_SKIP_COLDSTART"}}
-        if _remaining() < float(os.environ.get("BENCH_EST_COLDSTART", 45)):
-            return {"coldstart": {"skipped": "deadline"}}
-        prompt = list(range(1, min(8, max(2, d["max_seq"] // 4)) + 1))
-        gen = int(os.environ.get("BENCH_COLDSTART_TOKENS", 4))
-        cache_dir = tempfile.mkdtemp(prefix="bench-compile-cache-")
-
-        def one_start():
-            # a "process start": no in-process executables, fresh timeline.
-            # hits/misses are DELTAS around this start — the ledger's
-            # counter families are monotonic and already carry the whole
-            # headline replay's per-step hits
-            _cc.clear_shared()
-            _cc.reset()
-            s0 = _cc.summary()
-            t0 = time.monotonic()
-            eng = InferenceEngine(
-                model, max_seq_len=d["max_seq"], block_size=d["block_size"],
-                max_batch=d["max_batch"],
-                decode_batch_buckets=(d["max_batch"],),
-            )
-            eng.prewarm()
-            out = eng.generate([prompt], max_new_tokens=gen)
-            wall = time.monotonic() - t0
-            s1 = _cc.summary()
-            hits = s1.get("hits", 0) - s0.get("hits", 0)
-            misses = s1.get("misses", 0) - s0.get("misses", 0)
-            looked = hits + misses
-            delta = {"hits": hits, "misses": misses,
-                     "hit_rate": round(hits / looked, 4) if looked else None}
-            return wall, out, delta, _cc.cold_start_report()
-
-        prev = _cc.active_store()  # restore any env-configured store after
-        try:
-            _cc.configure(cache_dir)
-            cold_wall, cold_out, cold_sum, cold_rep = one_start()
-            warm_wall, warm_out, warm_sum, _ = one_start()
-        finally:
-            _cc.configure(prev.root if prev is not None else None)
-            shutil.rmtree(cache_dir, ignore_errors=True)
-        if warm_out != cold_out:  # restored executables must be bit-honest
-            return {"coldstart": {"skipped": "warm output diverged from cold"}}
-        return {
-            "cold_start_ttft_ms": round(cold_wall * 1000.0, 3),
-            "warm_start_ttft_ms": round(warm_wall * 1000.0, 3),
-            "cache_hit_rate": warm_sum.get("hit_rate"),
-            "coldstart_dims": {
-                **{k: d[k] for k in ("vocab", "hidden", "layers", "max_seq",
-                                     "block_size", "max_batch")},
-                "gen_tokens": gen,
-            },
-            "coldstart": {
-                "cold": {"wall_s": round(cold_wall, 4),
-                         "misses": cold_sum.get("misses"),
-                         "report": cold_rep},
-                "warm": {"wall_s": round(warm_wall, 4),
-                         "misses": warm_sum.get("misses"),
-                         "hit_rate": warm_sum.get("hit_rate")},
-                "outputs_identical": True,
-                "serialization_available": _cc.serialization_available(),
-            },
-        }
-
-    try:
-        res.update(coldstart_sub())
-    except Exception as e:  # the sub-run must never kill the headline
-        res["coldstart"] = {"skipped": f"error: {str(e)[-200:]}"}
     return res
 
 
@@ -1737,16 +1652,12 @@ def _build_moe_longcontext():
     SpecLayout build_mesh; ep rides the dp axis, sep is the ring axis) and
     the record carries real attribution like the dense configs.
     BENCH_MOE_EAGER=1 is the escape hatch back to the eager step. The
-    compile routes through the round-18 persistent cache (cold vs warm wall
-    recorded) and the static-capture fusion probe records the `fuse_moe`
+    static-capture fusion probe records the `fuse_moe`
     dispatch->expert->combine match count perf_gate gates."""
-    import tempfile
-
     import numpy as np
 
     import paddle_tpu as paddle
     from paddle_tpu import nn
-    from paddle_tpu import compile_cache as _cc
     from paddle_tpu.distributed import fleet
     from paddle_tpu.incubate.distributed.models.moe import ExpertLayer, MoELayer
     from paddle_tpu.ops.ring_attention import ring_attention_op
@@ -1830,88 +1741,44 @@ def _build_moe_longcontext():
         # (record_drop_telemetry(dropped=...)), never inside the trace
         return loss, moe0.last_drop_count(), moe1.last_drop_count()
 
-    # compile through the round-18 persistent cache so the (expensive)
-    # long-context compile is a one-time cost: BENCH_MOE_CACHE_DIR shares a
-    # store across runs; the default ephemeral dir makes cold REALLY cold
-    prev_store = _cc.active_store()
-    cache_dir = os.environ.get("BENCH_MOE_CACHE_DIR") or tempfile.mkdtemp(
-        prefix="bench_moe_cc_"
-    )
-    try:
-        if not eager:
-            _cc.configure(cache_dir)
-        step = (moe_longcontext_step if eager
-                else paddle.jit.to_static(moe_longcontext_step))
-        state = {}
+    step = (moe_longcontext_step if eager
+            else paddle.jit.to_static(moe_longcontext_step))
+    state = {}
 
-        def run(n):
-            t0 = time.perf_counter()
-            for _ in range(n):
-                loss, d0, d1 = step(x)
-            state["drops"] = (d0, d1)
-            val = float(loss.numpy())
-            return time.perf_counter() - t0, val
+    def run(n):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            loss, d0, d1 = step(x)
+        state["drops"] = (d0, d1)
+        val = float(loss.numpy())
+        return time.perf_counter() - t0, val
 
-        dt_step, final_loss = _slope_measure(run, d["steps"], warm=2)
-        if dt_step <= 0:
-            # slope noise at CI-shrunk dims (one-step deltas): fall back to
-            # a plain per-step average so the roofline (mfu/hbm_util) and
-            # tokens_per_sec stay well-defined
-            n_avg = max(2, d["steps"])
-            t_avg, final_loss = run(n_avg)
-            dt_step = t_avg / n_avg
-        attribution = (_attribution(dt_step) if not eager else {
-            "attribution": "unavailable",
-            "why": "BENCH_MOE_EAGER=1 escape hatch (uncompiled eager step; "
-                   "no compiled-program cost record to attribute)",
-        })
+    dt_step, final_loss = _slope_measure(run, d["steps"], warm=2)
+    if dt_step <= 0:
+        # slope noise at CI-shrunk dims (one-step deltas): fall back to
+        # a plain per-step average so the roofline (mfu/hbm_util) and
+        # tokens_per_sec stay well-defined
+        n_avg = max(2, d["steps"])
+        t_avg, final_loss = run(n_avg)
+        dt_step = t_avg / n_avg
+    attribution = (_attribution(dt_step) if not eager else {
+        "attribution": "unavailable",
+        "why": "BENCH_MOE_EAGER=1 escape hatch (uncompiled eager step; "
+               "no compiled-program cost record to attribute)",
+    })
 
-        # capacity-drop counters: ONE blocking read per layer of the LAST
-        # step's returned device scalars, into the guardian telemetry +
-        # the capture record (eager steps return concrete values — the
-        # same read path)
-        drops = {
-            name: m.record_drop_telemetry(name=name, dropped=dv)
-            for (name, m), dv in zip(
-                (("moe0", moe0), ("moe1", moe1)), state["drops"]
-            )
-        }
-        routed = sum(s["routed"] for s in drops.values() if s)
-        dropped = sum(s["dropped"] for s in drops.values() if s)
-
-        # cold vs warm compile wall through the persistent store: drop the
-        # in-process shared entries, re-stage the same step, and let the
-        # fingerprint restore from disk (serialization permitting) — the
-        # warm path a relaunch would pay
-        compile_cache = {"cache_dir_ephemeral": "BENCH_MOE_CACHE_DIR" not in os.environ}
-        if not eager:
-            fname = "moe_longcontext_step"
-            cold = [e for e in _cc.events(origin="to_static")
-                    if e["name"] == fname and e["outcome"] in ("miss", "restore")]
-            if cold:
-                compile_cache["cold"] = {
-                    "outcome": cold[0]["outcome"],
-                    "compile_s": round(cold[0]["seconds"], 3),
-                }
-            serial0 = cold[-1]["serial"] if cold else 0
-            _cc.clear_shared()
-            warm_step = paddle.jit.to_static(moe_longcontext_step)
-            t0 = time.perf_counter()
-            warm_step(x)  # call 1: the eager recording pass (no compile yet)
-            warm_step(x)  # call 2: trace + fingerprint -> disk restore
-            warm_wall = time.perf_counter() - t0
-            warm = [e for e in _cc.events(origin="to_static",
-                                          since_serial=serial0)
-                    if e["name"] == fname and e["outcome"] in ("miss", "restore")]
-            compile_cache["warm"] = {
-                "outcome": warm[-1]["outcome"] if warm else None,
-                "compile_s": round(warm[-1]["seconds"], 3) if warm else None,
-                "wall_s": round(warm_wall, 3),
-            }
-            compile_cache["serialization_available"] = _cc.serialization_available()
-    finally:
-        if not eager:
-            _cc.configure(prev_store.root if prev_store is not None else None)
+    # capacity-drop counters: ONE blocking read per layer of the LAST
+    # step's returned device scalars, into the guardian telemetry +
+    # the capture record (eager steps return concrete values — the
+    # same read path)
+    drops = {
+        name: m.record_drop_telemetry(name=name, dropped=dv)
+        for (name, m), dv in zip(
+            (("moe0", moe0), ("moe1", moe1)), state["drops"]
+        )
+    }
+    routed = sum(s["routed"] for s in drops.values() if s)
+    dropped = sum(s["dropped"] for s in drops.values() if s)
 
     # fusion-coverage probe: the SAME forward, eager-converted to a static
     # Program and run through the default pass pipeline — `fuse_moe` must
@@ -1956,7 +1823,6 @@ def _build_moe_longcontext():
             "drop_fraction": round(dropped / routed, 4) if routed else None,
             "per_layer": drops,
         },
-        "compile_cache": compile_cache,
         "note": (
             "GQA flash attention + exact ring attention (sep axis) + "
             "GShard-capacity MoE EP routing in one to_static step over the "

@@ -1,27 +1,22 @@
-"""Compilation-lifecycle observability + persistent compile cache.
+"""Compilation-lifecycle observability: the compile ledger and the engine's
+bucket key.
 
-One subsystem, two inseparable halves (round 18):
-
-- **Observability** (`ledger`): every `lower()`/`compile()` across the
-  four compile entry points — static `Executor`, `to_static`, the
-  `InferenceEngine` shape buckets, the fused-optimizer engine — emits a
-  structured event (origin, stable program fingerprint, signature, wall
-  seconds, hit|miss|restore|shared|persist outcome) into a bounded store
-  with `paddle_tpu_compile_*` telemetry, compile spans in the request
+- `ledger`: every `lower()`/`compile()` across the four compile entry
+  points (static `Executor`, `to_static`, the `InferenceEngine` shape
+  buckets, the fused-optimizer engine) emits a structured event (origin,
+  name, signature, wall seconds, miss|hit|shared outcome) into a bounded
+  store with `paddle_tpu_compile_*` telemetry, compile spans in the request
   trace's chrome lanes, and a cold-start timeline report
   (`python -m paddle_tpu.compile_cache report`) decomposing the
   engine-load -> first-token wall.
+- `fingerprint`: the key under which same-signature engines of one process
+  share a bucket's executable (`inference/engine.py`).
 
-- **Cache** (`store`): compiled executables persisted keyed by
-  (program fingerprint, topology meta, jax version) in an atomic
-  CRC-verified layout (PR 2's torn-write discipline), restored instead of
-  recompiled on the next process — plus an in-process shared registry so
-  fleet replicas with identical signatures compile once. Point the process
-  at a directory with `configure(path)` or the
-  `PADDLE_TPU_COMPILE_CACHE_DIR` env var (exported ahead by the elastic
-  relaunch path so restarted workers land on a warm cache).
+The cache itself is JAX's: compiled programs persist across processes
+through JAX's persistent compilation cache, placed by
+`paddle_tpu.framework.persistent_cache.enable()`, and through nothing else.
 """
-from . import fingerprint, ledger, report, store  # noqa: F401
+from . import fingerprint, ledger, report  # noqa: F401
 from .fingerprint import (  # noqa: F401
     aval_signature,
     entry_key,
@@ -36,23 +31,11 @@ from .ledger import (  # noqa: F401
     summary,
 )
 from .report import cold_start_report, format_report  # noqa: F401
-from .store import (  # noqa: F401
-    CompileCacheStore,
-    active_store,
-    clear_shared,
-    configure,
-    make_meta,
-    serialization_available,
-    shared_get,
-    shared_put,
-    store_dir,
-)
 
 __all__ = [
     "fingerprint",
     "ledger",
     "report",
-    "store",
     "aval_signature",
     "entry_key",
     "fingerprint_text",
@@ -64,13 +47,4 @@ __all__ = [
     "summary",
     "cold_start_report",
     "format_report",
-    "CompileCacheStore",
-    "active_store",
-    "clear_shared",
-    "configure",
-    "make_meta",
-    "serialization_available",
-    "shared_get",
-    "shared_put",
-    "store_dir",
 ]

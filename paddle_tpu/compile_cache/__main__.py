@@ -3,8 +3,7 @@
 Reads a ledger dump (`compile_cache.ledger.dump_json(path)`, written by
 bench / dryrun / a serving process at shutdown) and prints the
 engine-load -> first-token decomposition; `--json` emits the raw report
-dict. Store maintenance (stats/verify/gc) lives in
-`tools/compile_cache.py`.
+dict.
 """
 from __future__ import annotations
 
@@ -19,7 +18,7 @@ from . import ledger, report
 def main(argv: Optional[Sequence[str]] = None) -> int:
     p = argparse.ArgumentParser(
         prog="python -m paddle_tpu.compile_cache",
-        description="compile-cache cold-start timeline report",
+        description="compile-ledger cold-start timeline report",
     )
     sub = p.add_subparsers(dest="cmd", required=True)
     rp = sub.add_parser("report", help="cold-start timeline from a ledger dump")

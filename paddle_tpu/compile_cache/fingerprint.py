@@ -1,19 +1,17 @@
-"""Stable program fingerprints + topology metadata for the compile cache.
+"""Program fingerprints + topology metadata for the engine's bucket key.
 
-A cache key has two halves:
+Same-signature engines of one process share a bucket's executable
+(`inference/engine.py`) under a key with two halves:
 
-- the **program fingerprint**: a sha256 over a canonical text rendering of
-  the program being compiled. The static Executor and `to_static` hash the
-  PR 12 textual IR (`static.analysis.graph.program_to_text` / the traced
-  jaxpr); the serving engine hashes a canonical description of the bucket
-  program (model dims, pool dtype, bucket kind/size, aval signature, mesh
-  shape, donation) — everything the compiled artifact depends on and
+- the **program fingerprint**: a sha256 over a canonical description of the
+  bucket program (model dims, pool dtype, bucket kind/size, aval signature,
+  shardings, donation) — everything the compiled artifact depends on and
   nothing it doesn't (weight VALUES are runtime arguments, so two replicas
   of the same model share a fingerprint by construction);
-- the **topology meta**: jax version, backend platform, device count and
-  mesh axis sizes. An executable serialized on one topology must never be
-  deserialized onto another, so the meta participates in the disk key and
-  is re-verified against the entry's recorded meta at restore time.
+- the **topology meta**: jax version, backend platform, device count, mesh
+  axis sizes and the mesh's device ids. An executable compiled for one
+  device set must never run another's traffic, so the meta participates in
+  the key.
 """
 from __future__ import annotations
 
@@ -36,8 +34,8 @@ def fingerprint_text(text: str) -> str:
 
 
 def topology_meta(mesh=None) -> dict:
-    """The environment half of a cache key: everything that must match for
-    a serialized executable to load and run correctly."""
+    """The environment half of a share key: everything that must match for
+    one engine's executable to serve another's traffic."""
     meta = {"jax_version": None, "platform": "unknown", "device_count": 0,
             "mesh_shape": None}
     try:
@@ -66,8 +64,8 @@ def topology_meta(mesh=None) -> dict:
 
 
 def topology_key(meta: Optional[dict] = None) -> str:
-    """Short stable digest of a topology meta (participates in entry keys
-    and is what restore compares)."""
+    """Short stable digest of a topology meta (participates in entry
+    keys)."""
     meta = meta if meta is not None else topology_meta()
     return hashlib.sha256(
         json.dumps(meta, sort_keys=True).encode()
@@ -75,8 +73,8 @@ def topology_key(meta: Optional[dict] = None) -> str:
 
 
 def entry_key(fingerprint: str, meta: Optional[dict] = None) -> str:
-    """Disk entry name: (fingerprint, topology meta, jax version) — the
-    jax version rides inside the meta."""
+    """Share key: (fingerprint, topology meta, jax version) — the jax
+    version rides inside the meta."""
     return f"{fingerprint}-{topology_key(meta)}"
 
 

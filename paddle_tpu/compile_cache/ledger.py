@@ -1,17 +1,18 @@
-"""Compile-event ledger: the observability half of the compile cache.
+"""Compile-event ledger.
 
 Every `lower()`/`compile()` across the four compile entry points (static
 Executor, `to_static`, `InferenceEngine` buckets, fused-optimizer engine)
-reports here with a structured event: origin, program name, stable
-fingerprint, signature, wall seconds, and an outcome —
+reports here with a structured event: origin, program name, signature,
+wall seconds (the engine and the fused optimizer add a fingerprint), and an
+outcome —
 
-- ``miss``     a fresh trace+XLA compile ran
-- ``restore``  the executable was deserialized from the persistent store
+- ``miss``     a fresh trace+XLA compile ran (JAX's persistent cache may
+               have served the XLA half: `persistent_cache.stats()` counts
+               that, not this ledger)
 - ``shared``   an identical in-process executable was reused (fleet
                replicas with the same signature)
-- ``persist``  a freshly compiled executable was written to the store
-- ``error``    a cache entry was rejected (corrupt, topology mismatch)
 - ``hit``      the caller's own in-memory cache served the signature
+- ``error``    what `record` files an outcome it does not know under
 
 Hits are counter-only: they happen per dispatch (per decode step on the
 serving path), so appending them to the bounded event store would age out
@@ -20,7 +21,7 @@ bounded deque the cold-start report reads.
 
 Telemetry (all labeled ``{origin, outcome}``):
 ``paddle_tpu_compile_events_total``, ``paddle_tpu_compile_seconds_total``,
-``paddle_tpu_compile_cache_hits_total`` (hit|shared|restore),
+``paddle_tpu_compile_cache_hits_total`` (hit|shared),
 ``paddle_tpu_compile_cache_misses_total`` (miss|error).
 
 When request tracing is on, non-hit events also land as spans in the
@@ -61,8 +62,8 @@ __all__ = [
     "OUTCOMES",
 ]
 
-OUTCOMES = ("hit", "miss", "restore", "shared", "persist", "error")
-_HIT_LIKE = ("hit", "shared", "restore")
+OUTCOMES = ("hit", "miss", "shared", "error")
+_HIT_LIKE = ("hit", "shared")
 _MISS_LIKE = ("miss", "error")
 
 _MAX_EVENTS = 512
@@ -84,20 +85,20 @@ def _counters(origin: str, outcome: str, seconds: float) -> None:
     if seconds > 0:
         _tm.counter(
             "paddle_tpu_compile_seconds_total",
-            "wall seconds spent in compile-lifecycle work (compile, "
-            "restore, persist) by entry point and outcome",
+            "wall seconds spent in compile-lifecycle work by entry point "
+            "and outcome",
             ("origin", "outcome"),
         ).labels(**lbl).inc(float(seconds))
     if outcome in _HIT_LIKE:
         _tm.counter(
             "paddle_tpu_compile_cache_hits_total",
-            "compile-cache hits (in-memory hit, in-process shared, "
-            "disk restore)", ("origin", "outcome"),
+            "compile-cache hits (in-memory hit, in-process shared)",
+            ("origin", "outcome"),
         ).labels(**lbl).inc()
     elif outcome in _MISS_LIKE:
         _tm.counter(
             "paddle_tpu_compile_cache_misses_total",
-            "compile-cache misses (fresh compile) and rejected entries",
+            "compile-cache misses (fresh compile) and unknown outcomes",
             ("origin", "outcome"),
         ).labels(**lbl).inc()
 
@@ -290,8 +291,8 @@ def load_dump(path: str) -> dict:
 
 
 def reset_timeline() -> None:
-    """Clear marks/spans only — bench's warm-vs-cold sub-run re-measures
-    the engine-load window without losing the event history."""
+    """Clear marks/spans only: the engine-load window can be measured
+    again without losing the event history."""
     with _lock:
         _marks.clear()
         _spans.clear()

@@ -3,19 +3,16 @@
 The ledger timeline gives contiguous phase boundaries — an
 ``engine_load_start`` mark + ``engine_init`` span from the engine
 constructor, an optional ``prewarm`` span, and a ``first_token`` mark from
-the first logits the engine produces. Compile events (miss / restore /
-persist, each with wall seconds) land inside those phases. The report
+the first logits the engine produces. Compile events (miss / shared, each
+with wall seconds) land inside those phases. The report
 slices the window into components that sum to the measured wall BY
 CONSTRUCTION (the PR 14 request-trace discipline applied to compilation):
 
     engine_init_s        constructor work (weight placement, pool alloc)
     pre_prewarm_s        gap between constructor exit and prewarm start
     prewarm_compile_s    fresh XLA compiles inside prewarm (outcome=miss)
-    prewarm_restore_s    disk restores inside prewarm (outcome=restore)
-    prewarm_persist_s    disk writes inside prewarm (outcome=persist)
     prewarm_host_s       prewarm wall not covered by compile events
     serve_compile_s      compile events after prewarm, before first token
-    serve_restore_s      restores in the same tail window
     serve_host_s         residual host work up to the first token
 
 `consistency` = sum(components) / wall. Because residuals are clamped at
@@ -30,7 +27,7 @@ from . import ledger as _ledger
 
 __all__ = ["cold_start_report", "format_report"]
 
-_COMPILE_OUTCOMES = ("miss", "restore", "persist", "shared", "error")
+_COMPILE_OUTCOMES = ("miss", "shared", "error")
 
 
 def _last(marks: List[dict], key: str, before: Optional[float] = None):
@@ -104,23 +101,12 @@ def cold_start_report(data: Optional[dict] = None) -> dict:
         p1 = min(first_token, pw["t1"])
         comp["pre_prewarm_s"] = max(0.0, p0 - init_end)
         comp["prewarm_compile_s"] = _bucket_seconds(win_events, p0, p1, "miss")
-        comp["prewarm_restore_s"] = _bucket_seconds(win_events, p0, p1, "restore")
-        comp["prewarm_persist_s"] = _bucket_seconds(win_events, p0, p1, "persist")
-        comp["prewarm_host_s"] = max(
-            0.0, (p1 - p0) - comp["prewarm_compile_s"]
-            - comp["prewarm_restore_s"] - comp["prewarm_persist_s"]
-        )
+        comp["prewarm_host_s"] = max(0.0, (p1 - p0) - comp["prewarm_compile_s"])
         tail0 = p1
     else:
         tail0 = init_end
-    comp["serve_compile_s"] = (
-        _bucket_seconds(win_events, tail0, first_token, "miss")
-        + _bucket_seconds(win_events, tail0, first_token, "persist")
-    )
-    comp["serve_restore_s"] = _bucket_seconds(win_events, tail0, first_token, "restore")
-    comp["serve_host_s"] = max(
-        0.0, (first_token - tail0) - comp["serve_compile_s"] - comp["serve_restore_s"]
-    )
+    comp["serve_compile_s"] = _bucket_seconds(win_events, tail0, first_token, "miss")
+    comp["serve_host_s"] = max(0.0, (first_token - tail0) - comp["serve_compile_s"])
     comp = {k: round(v, 6) for k, v in comp.items()}
     total = sum(comp.values())
     outcomes: dict = {}

@@ -185,18 +185,6 @@ class CollectiveController:
             "PADDLE_ELASTIC_PREV_WORLD": str(prev_world),
             "PADDLE_ELASTIC_PLAN": json.dumps(plan),
         }
-        # compile-cache ship-ahead (round 18): relaunched workers inherit
-        # the controller's persistent executable cache dir, so post-scale
-        # engines restore their shape buckets instead of recompiling —
-        # elastic recovery pays deserialize, not XLA
-        try:
-            from ... import compile_cache as _cc
-
-            cache_dir = _cc.store_dir() or os.environ.get(_cc.store.ENV_DIR)
-            if cache_dir:
-                reshard_env[_cc.store.ENV_DIR] = str(cache_dir)
-        except Exception:
-            pass
         for c in self.pod.containers:
             c.env.update(reshard_env)
         self.pod.deploy()

@@ -11,8 +11,8 @@ The cache's thresholds are lowered so that every program is kept, the
 step and bucket programs included. `stats()` counts this process's
 persistent-cache hits and misses from JAX's own monitoring events.
 
-This is JAX's cache, not the repo's executable store
-(`paddle_tpu.compile_cache.store`, opt-in and off on these paths).
+This is the one cache of compiled programs that outlives a process: the
+repo keeps no executable store of its own.
 """
 from __future__ import annotations
 

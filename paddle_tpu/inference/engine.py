@@ -34,6 +34,7 @@ attention every array and every operand is as it was.
 from __future__ import annotations
 
 import math
+import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -58,6 +59,35 @@ def _bucket_counter():
         "compile = new signature lowered+compiled)",
         label_names=("kind", "event"),
     )
+
+
+# Executables shared between the engines of one process: replicas with one
+# bucket signature on one device set compile each bucket once, the first
+# pays and the rest adopt (outcome "shared"). Keyed by `_bucket_key`'s entry
+# key; FIFO-bounded, since sharing is a hint and a dropped entry only costs a
+# compile.
+_MAX_SHARED = 256
+_shared_lock = threading.Lock()
+_shared: Dict[str, object] = {}
+
+
+def _shared_get(key: str):
+    with _shared_lock:
+        return _shared.get(key)
+
+
+def _shared_put(key: str, compiled) -> None:
+    with _shared_lock:
+        if key not in _shared and len(_shared) >= _MAX_SHARED:
+            _shared.pop(next(iter(_shared)))
+        _shared[key] = compiled
+
+
+def clear_shared_executables() -> None:
+    """Forget every shared executable (tests start from an empty table, or
+    one test's engine would donate its buckets to the next's)."""
+    with _shared_lock:
+        _shared.clear()
 
 
 def _default_prefill_buckets(max_seq_len: int, block_size: int) -> Tuple[int, ...]:
@@ -202,9 +232,8 @@ class InferenceEngine:
         # bumped by every load_weights(); the fleet exports it per replica
         # so a half-finished rollout is visible in telemetry
         self.weights_version = 0
-        # round 18: compile-cache plumbing — per-signature fingerprints
-        # (lazy), the topology meta restore verifies against, and the
-        # cold-start timeline marks the `compile_cache report` decomposes
+        # per-signature fingerprints (lazy), and the cold-start timeline
+        # marks the `compile_cache report` decomposes
         self._fingerprints: Dict[Tuple[str, object], Tuple[str, str]] = {}
         self._fp_base: Optional[str] = None
         self._topo_meta: Optional[dict] = None
@@ -298,7 +327,7 @@ class InferenceEngine:
         raise ValueError(f"{kind} size {n} exceeds the largest bucket {buckets[-1]}")
 
     def _bucket_key(self, kind: str, size) -> Tuple[str, str]:
-        """(program fingerprint, disk/share entry key) for one bucket
+        """(program fingerprint, share key) for one bucket
         signature — a canonical text over everything the compiled artifact
         depends on (dims, bucket, pool/state avals, param avals, donation,
         shardings) and nothing it doesn't: weight VALUES are call
@@ -356,27 +385,16 @@ class InferenceEngine:
 
     def _compile_miss(self, kind: str, size, sz):
         """(executable, outcome) for a bucket this engine has not run yet:
-        shared from a same-signature replica, restored from the store, or
-        compiled (outcome "miss")."""
+        shared from a same-signature engine of this process, or compiled
+        (outcome "miss")."""
         from .. import compile_cache as _cc
 
         key = (kind, size)
         name = f"{kind}_{sz}"
         t0 = time.perf_counter()
         fp, ekey = self._bucket_key(kind, size)
-        outcome = "miss"
-        ex = _cc.shared_get(ekey)
-        if ex is not None:
-            # in-process sharing (round-18 bugfix): a same-signature replica
-            # already compiled this bucket program — reuse its executable
-            outcome = "shared"
-        else:
-            st = _cc.active_store()
-            if st is not None:
-                got = st.get(ekey, expect_meta=self._topo_meta)
-                if got is not None:
-                    ex = got[0]
-                    outcome = "restore"
+        ex = _shared_get(ekey)
+        outcome = "miss" if ex is None else "shared"
         if ex is None:
             if kind == "prefill":
                 ex = self._compile_prefill(size)
@@ -384,17 +402,15 @@ class InferenceEngine:
                 ex = self._compile_decode(size)
             else:  # ("extend", (B, Q))
                 ex = self._compile_extend(*size)
+            _shared_put(ekey, ex)
         dt = time.perf_counter() - t0
         self._compiled[key] = ex
         if outcome == "miss":
             self.bucket_stats["compiles"] += 1
         else:
-            # shared/restored keys appear only when those outcomes happen:
-            # the baseline {hits, compiles} shape is unchanged for engines
-            # that never touch the cache
-            k = "shared" if outcome == "shared" else "restored"
-            self.bucket_stats[k] = self.bucket_stats.get(k, 0) + 1
-        _cc.shared_put(ekey, ex)
+            # the key appears only when sharing happens: the baseline
+            # {hits, compiles} shape is unchanged for a lone engine
+            self.bucket_stats["shared"] = self.bucket_stats.get("shared", 0) + 1
         event = "compile" if outcome == "miss" else outcome
         if _rt.enabled():
             # a compile-miss dispatch IS a tail-latency event: the signature
@@ -415,22 +431,12 @@ class InferenceEngine:
                     )
                 except Exception:
                     pass
-        if outcome == "miss":
-            st = _cc.active_store()
-            if st is not None:
-                tp = time.perf_counter()
-                if st.put(ekey, ex,
-                          _cc.make_meta("serving", name, fp, signature=sz,
-                                        mesh=self._mesh)):
-                    _cc.record("serving", name, "persist",
-                               seconds=time.perf_counter() - tp,
-                               fingerprint=fp, signature=sz)
         return ex, outcome
 
     def prewarm(self, *, include_prefill: bool = True,
                 include_decode: bool = True,
                 extend_q: Sequence[int] = ()) -> dict:
-        """Compile (or restore/share) every bucket program up front, so
+        """Compile (or share) every bucket program up front, so
         steady-state serving — and the first token — never pays a compile.
         `extend_q` adds the (B, Q) extend/verify family for the given
         query lengths (speculative decode uses draft_len + 1);

@@ -261,9 +261,8 @@ class _CompiledEntry:
             self.jitted = None  # grad presence changed -> rebuild
 
         if self.jitted is None:
-            with RecordEvent("to_static.compile") as span:
-                span.args = {"outcome": self._compile(
-                    args, kwargs, treedef, t_idx, leaves, raw_args, rng)}
+            with RecordEvent("to_static.compile", args={"outcome": "compile"}):
+                self._compile(args, kwargs, treedef, t_idx, leaves, raw_args, rng)
 
         with RecordEvent("to_static.gather"):
             state_vals = [t._value for t in self.state]
@@ -307,8 +306,7 @@ class _CompiledEntry:
         return self._rebuild_out(outs)
 
     def _compile(self, args, kwargs, treedef, t_idx, leaves, raw_args, rng):
-        """Discover the step's state, then restore or compile its program;
-        returns which ("restore" or "compile")."""
+        """Discover the step's state, then compile its program."""
         # Fixpoint state discovery: any CONCRETE tensor read during tracing
         # is framework state the eager recording missed (e.g. optimizer
         # accumulators created lazily inside the recorded step) — it must
@@ -390,55 +388,7 @@ class _CompiledEntry:
                             self.jitted = None
                             raise
                 t0 = _time.perf_counter()
-                # round 18: fingerprint the traced jaxpr (the PR 12
-                # textual IR of a to_static step) and try the persistent
-                # cache before paying XLA compile. Fingerprinting is
-                # telemetry-gated like the rest of the attribution path.
-                from .. import compile_cache as _cc
-                from .. import telemetry as _tm
-
                 fname = getattr(self.fn, "__name__", "<fn>")
-                fp = ekey = st = None
-                if _tm.enabled():
-                    try:
-                        fp = _cc.fingerprint_text(
-                            f"to_static-v1|{fname}|"
-                            f"donate={self.donated}|{traced.jaxpr}"
-                        )
-                        ekey = _cc.entry_key(fp)
-                        st = _cc.active_store()
-                    except Exception:
-                        fp = ekey = st = None
-                restored = None
-                if st is not None and ekey is not None:
-                    got = st.get(ekey, expect_meta=_cc.topology_meta())
-                    if got is not None:
-                        restored = got[0]
-                if restored is not None:
-                    self.jitted = restored
-                    # a restored step must not LOSE its attribution
-                    # record: cost/memory analysis comes off the
-                    # deserialized executable, so warm runs report the
-                    # same FLOPs/HBM the cold compile did (perf_gate
-                    # hard-fails configs that regress from measured
-                    # attribution back to unavailable)
-                    from ..profiler import perf_attribution as _pa
-
-                    _pa.record_compiled(
-                        "to_static",
-                        fname,
-                        compiled=restored,
-                        compile_seconds=0.0,
-                        extra={"n_state": len(self.state),
-                               "restored": True},
-                    )
-                    _cc.record(
-                        "to_static", fname, "restore",
-                        seconds=_time.perf_counter() - t0,
-                        fingerprint=fp,
-                        signature=f"n_state={len(self.state)}",
-                    )
-                    return "restore"
                 lowered = traced.lower()
                 self.jitted = lowered.compile()
                 dt = _time.perf_counter() - t0
@@ -446,6 +396,7 @@ class _CompiledEntry:
                 # step exists as a compiled XLA program: FLOPs, HBM
                 # bytes, memory footprint, compile time (telemetry-gated
                 # inside record_compiled; never raises)
+                from .. import compile_cache as _cc
                 from ..profiler import perf_attribution as _pa
 
                 _pa.record_compiled(
@@ -458,19 +409,9 @@ class _CompiledEntry:
                 )
                 _cc.record(
                     "to_static", fname, "miss", seconds=dt,
-                    fingerprint=fp,
                     signature=f"n_state={len(self.state)}",
                 )
-                if st is not None and ekey is not None:
-                    tp = _time.perf_counter()
-                    if st.put(ekey, self.jitted,
-                              _cc.make_meta("to_static", fname, fp)):
-                        _cc.record(
-                            "to_static", fname, "persist",
-                            seconds=_time.perf_counter() - tp,
-                            fingerprint=fp,
-                        )
-                return "compile"
+                return
             self.state.extend(missed)
         raise RuntimeError("to_static: state discovery did not converge")
 

@@ -519,23 +519,15 @@ def _serving_section() -> dict:
 
 
 def _compilation_section() -> dict:
-    """The compile-lifecycle ledger rollup (round 18): event/hit/miss/
-    restore counts and compile seconds by origin, plus the persistent
-    store's size/entry footprint when one is configured. Answers 'what did
-    cold start cost and how much of it did the cache absorb' from the same
-    report that already attributes steady-state FLOPs."""
+    """The compile-lifecycle ledger rollup: event/hit/miss counts and
+    compile seconds by origin. Answers 'what did cold start cost' from the
+    same report that already attributes steady-state FLOPs."""
     try:
         from .. import compile_cache as _cc
 
-        section = _cc.summary()
+        return _cc.summary()
     except Exception as e:  # the report must render without the ledger
         return {"available": False, "reason": f"compile ledger failed: {e}"}
-    try:
-        st = _cc.active_store()
-        section["store"] = st.stats() if st is not None else None
-    except Exception:
-        section["store"] = None
-    return section
 
 
 def perf_report(origin: Optional[str] = None) -> dict:

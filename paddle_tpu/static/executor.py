@@ -423,24 +423,6 @@ class Executor:
 
         cache = {}
         fallback = [False]
-        # PR 12 textual IR = the stable program fingerprint (round 18):
-        # hashed lazily on the first actual compile, never on the hot path
-        fp_base = []
-
-        def _fingerprint(args):
-            from .. import compile_cache as _cc
-
-            if not fp_base:
-                try:
-                    from .analysis.graph import program_to_text
-
-                    text = program_to_text(program)
-                except Exception:
-                    text = f"ops={[op.type for op in program.ops]}"
-                fp_base.append(f"executor-replay-v1|{text}")
-            return _cc.fingerprint_text(
-                f"{fp_base[0]}|{_cc.aval_signature(args)}"
-            )
 
         def wrapper(feed_arrays, param_arrays, accum_arrays, lr_arrays):
             args = (feed_arrays, param_arrays, accum_arrays, lr_arrays)
@@ -466,17 +448,8 @@ class Executor:
                 name = f"replay[{len(program.ops)}ops,{len(feed_arrays)}feeds]"
                 try:
                     t0 = time.perf_counter()
-                    fp = _fingerprint(args)
-                    ekey = _cc.entry_key(fp)
-                    outcome, lowered = "miss", None
-                    st = _cc.active_store()
-                    if st is not None:
-                        got = st.get(ekey, expect_meta=_cc.topology_meta())
-                        if got is not None:
-                            exe, outcome = got[0], "restore"
-                    if exe is None:
-                        lowered = jitted.lower(*args)
-                        exe = lowered.compile()
+                    lowered = jitted.lower(*args)
+                    exe = lowered.compile()
                     dt = time.perf_counter() - t0
                 except Exception:
                     fallback[0] = True
@@ -487,30 +460,20 @@ class Executor:
                     "wall time of a static Executor program's first "
                     "(tracing + XLA compile) run",
                 ).observe(dt)
-                _cc.record("static_executor", name, outcome, seconds=dt,
-                           fingerprint=fp,
+                _cc.record("static_executor", name, "miss", seconds=dt,
                            signature=f"{len(feed_arrays)}feeds")
-                if outcome == "miss":
-                    from ..profiler import perf_attribution as _pa
+                from ..profiler import perf_attribution as _pa
 
-                    _pa.record_compiled(
-                        "static_executor",
-                        name,
-                        lowered=lowered,
-                        compiled=exe,
-                        compile_seconds=dt,
-                        # lets CostModel.profile_measure find THIS program's
-                        # record on a warm cache instead of the global newest
-                        extra={"program_id": id(program)},
-                    )
-                    st = _cc.active_store()
-                    if st is not None:
-                        tp = time.perf_counter()
-                        if st.put(ekey, exe,
-                                  _cc.make_meta("static_executor", name, fp)):
-                            _cc.record("static_executor", name, "persist",
-                                       seconds=time.perf_counter() - tp,
-                                       fingerprint=fp)
+                _pa.record_compiled(
+                    "static_executor",
+                    name,
+                    lowered=lowered,
+                    compiled=exe,
+                    compile_seconds=dt,
+                    # lets CostModel.profile_measure find THIS program's
+                    # record on a warm cache instead of the global newest
+                    extra={"program_id": id(program)},
+                )
             else:
                 from .. import compile_cache as _cc
 

@@ -31,14 +31,13 @@ import pytest  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
-def _isolate_compile_cache():
-    """The in-process shared executable registry (round 18) deliberately
+def _isolate_shared_executables():
+    """The engine's in-process table of shared executables deliberately
     spans engine instances — which would also span TESTS: an engine built in
     an earlier test would donate buckets to a later test's identical-dims
     engine, breaking exact bucket_stats assertions. Start every test with an
-    empty registry (the persistent store is untouched — it is opt-in via
-    env/configure and tests that want it set their own tmp dir)."""
-    from paddle_tpu import compile_cache
+    empty table."""
+    from paddle_tpu.inference.engine import clear_shared_executables
 
-    compile_cache.clear_shared()
+    clear_shared_executables()
     yield

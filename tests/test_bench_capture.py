@@ -208,8 +208,7 @@ def test_moe_longcontext_child_reports_drops():
     """ROADMAP-5, round 20: the MoE + long-context child runs COMPILED
     (to_static over the sep×ep mesh) and its record carries real
     attribution (FLOPs/HBM — never the unavailable marker), the post-step
-    drop counters, the fuse_moe match count, and the persistent-cache
-    cold/warm compile walls."""
+    drop counters and the fuse_moe match count."""
     env = dict(os.environ)
     env.update(
         JAX_PLATFORMS="cpu", BENCH_CHILD="moe_longcontext",
@@ -243,15 +242,6 @@ def test_moe_longcontext_child_reports_drops():
     assert res["device"]["platform"] == "cpu"
     # the fusion probe: both layers' dispatch->expert->combine chains match
     assert res["matches"]["fuse_moe"] == 2
-    # persistent-cache round trip: cold miss, then a warm restore (or an
-    # honest miss when executable serialization is unavailable)
-    cc = res["compile_cache"]
-    assert cc["cold"]["outcome"] == "miss"
-    if cc["serialization_available"]:
-        assert cc["warm"]["outcome"] == "restore"
-        assert cc["warm"]["wall_s"] >= 0
-    else:
-        assert cc["warm"]["outcome"] in ("miss", None)
 
 
 def test_moe_longcontext_eager_escape_hatch():

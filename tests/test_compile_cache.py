@@ -1,12 +1,8 @@
-"""Round 18: compilation-lifecycle observability + persistent compile cache.
-
-Two halves under test: the compile-event LEDGER (every lower()/compile()
-across the four entry points emits origin/fingerprint/outcome events with
-paddle_tpu_compile_* telemetry; hits are counter-only) and the persistent
-STORE (executables serialized under the PR 2 torn-write discipline, keyed
-by (program fingerprint, topology meta, jax version), restored instead of
-recompiled — with every corruption mode falling back to a fresh compile,
-counted, never a crash or a wrong executable).
+"""Compilation-lifecycle observability: the compile-event LEDGER (every
+lower()/compile() across the four entry points emits origin/outcome events
+with paddle_tpu_compile_* telemetry; hits are counter-only), the cold-start
+report over it, and the engine's in-process sharing of bucket executables.
+The cache that outlives a process is JAX's: tests/test_persistent_cache.py.
 """
 import json
 import os
@@ -22,6 +18,7 @@ import jax.numpy as jnp
 import paddle_tpu as paddle
 from paddle_tpu import compile_cache as cc
 from paddle_tpu import telemetry as tm
+from paddle_tpu.inference.engine import clear_shared_executables
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -33,14 +30,6 @@ def telemetry_on():
     yield
     if not was:
         tm.disable()
-
-
-@pytest.fixture
-def store(tmp_path, telemetry_on):
-    """A configured persistent store in a tmp dir, deconfigured after."""
-    st = cc.configure(str(tmp_path / "cache"))
-    yield st
-    cc.configure(None)
 
 
 @pytest.fixture(scope="module")
@@ -61,19 +50,6 @@ def _tiny_engine(model, **kw):
     kw.setdefault("max_batch", 2)
     kw.setdefault("decode_batch_buckets", (2,))
     return InferenceEngine(model, **kw)
-
-
-def _mk_exec(scale=2.0, n=4):
-    f = jax.jit(lambda x: x * scale)
-    return f.lower(jax.ShapeDtypeStruct((n,), jnp.float32)).compile()
-
-
-def _err_count(reason):
-    fam = tm.default_registry().get("paddle_tpu_compile_cache_errors_total")
-    if fam is None:
-        return 0
-    return sum(c.value for c in fam.children()
-               if dict(c.labels).get("reason") == reason)
 
 
 # ---------------------------------------------------------------------------
@@ -117,14 +93,14 @@ def test_ledger_events_and_hit_counter_only(telemetry_on):
     cc.record("serving", "prefill_8", "miss", seconds=0.25, fingerprint="ab")
     cc.record("serving", "prefill_8", "hit")
     cc.record("serving", "prefill_8", "hit")
-    cc.record("serving", "prefill_8", "persist", seconds=0.01)
+    cc.record("serving", "prefill_8", "shared", seconds=0.01)
     evs = cc.events(since_serial=serial0)
     # hits are counter-only: per-dispatch events would flood the bounded
     # store out of its rare compile-path events
-    assert [e["outcome"] for e in evs] == ["miss", "persist"]
+    assert [e["outcome"] for e in evs] == ["miss", "shared"]
     assert evs[0]["seconds"] == 0.25 and evs[0]["fingerprint"] == "ab"
     after = cc.summary()
-    assert after["hits"] - before["hits"] == 2
+    assert after["hits"] - before["hits"] == 3
     assert after["misses"] - before["misses"] == 1
     assert after["available"]
 
@@ -152,126 +128,11 @@ def test_ledger_dump_roundtrip(tmp_path, telemetry_on):
 
 
 # ---------------------------------------------------------------------------
-# store: atomic layout, corruption fallback, chaos site
+# engine: in-process sharing
 # ---------------------------------------------------------------------------
-
-def test_store_roundtrip_and_verify(store):
-    ex = _mk_exec()
-    key = cc.entry_key("a" * 32)
-    assert store.put(key, ex, cc.make_meta("serving", "t", "a" * 32))
-    got = store.get(key, expect_meta=cc.topology_meta())
-    assert got is not None
-    restored, meta = got
-    x = jnp.arange(4, dtype=jnp.float32)
-    np.testing.assert_array_equal(np.asarray(restored(x)),
-                                  np.asarray(ex(x)))
-    assert meta["origin"] == "serving"
-    assert store.verify() == {"entries": 1, "corrupt": 0, "failures": {}}
-
-
-def test_store_topology_mismatch_rejected(store):
-    key = cc.entry_key("b" * 32)
-    assert store.put(key, _mk_exec(), cc.make_meta("serving", "t", "b" * 32))
-    wrong = dict(cc.topology_meta())
-    wrong["jax_version"] = "0.0.0-other"
-    n0 = _err_count("topology_mismatch")
-    assert store.get(key, expect_meta=wrong) is None
-    assert _err_count("topology_mismatch") == n0 + 1
-
-
-@pytest.mark.parametrize("corruption,reason", [
-    ("truncate", "crc_mismatch"),
-    ("flip", "crc_mismatch"),
-    ("unmark", "torn_entry"),
-    ("bad_meta", "bad_meta"),
-])
-def test_store_corruption_falls_back_counted(store, corruption, reason):
-    """Every torn/corrupt shape is a counted miss, never a crash or a
-    wrong executable."""
-    key = cc.entry_key("c" * 32)
-    assert store.put(key, _mk_exec(), cc.make_meta("serving", "t", "c" * 32))
-    d = os.path.join(store.root, key)
-    if corruption == "truncate":
-        with open(os.path.join(d, "payload.bin"), "r+b") as f:
-            f.truncate(10)
-    elif corruption == "flip":
-        with open(os.path.join(d, "payload.bin"), "r+b") as f:
-            b = bytearray(f.read())
-            b[len(b) // 2] ^= 0xFF
-            f.seek(0)
-            f.write(bytes(b))
-    elif corruption == "unmark":
-        os.remove(os.path.join(d, "COMPLETE"))
-    else:
-        with open(os.path.join(d, "meta.json"), "w") as f:
-            f.write("{not json")
-    n0 = _err_count(reason)
-    assert store.get(key, expect_meta=cc.topology_meta()) is None
-    assert _err_count(reason) == n0 + 1
-    if corruption != "unmark":
-        ok, why = store.verify_entry(key)
-        assert not ok and why != "ok"
-
-
-def test_store_read_chaos_site_is_counted_miss(store):
-    """FaultPlan site `compile_cache.read`: an injected read fault surfaces
-    as a counted miss (the caller compiles fresh), never an exception."""
-    from paddle_tpu.distributed.resilience import fault_injection as fi
-
-    key = cc.entry_key("d" * 32)
-    assert store.put(key, _mk_exec(), cc.make_meta("serving", "t", "d" * 32))
-    n0 = _err_count("read_failed")
-    fi.install_plan(fi.FaultPlan().add("compile_cache.read", "fail", times=1))
-    try:
-        assert store.get(key, expect_meta=cc.topology_meta()) is None
-    finally:
-        fi.clear_plan()
-    assert _err_count("read_failed") == n0 + 1
-    # plan exhausted: the same entry restores fine
-    assert store.get(key, expect_meta=cc.topology_meta()) is not None
-
-
-def test_store_gc_corrupt_first_then_lru(store):
-    keys = [cc.entry_key(ch * 32) for ch in "efg"]
-    for k in keys:
-        assert store.put(k, _mk_exec(), cc.make_meta("serving", "t", k[:32]))
-    os.remove(os.path.join(store.root, keys[1], "COMPLETE"))
-    rep = store.gc(max_bytes=store.entry_bytes(keys[0]))
-    reasons = {r["key"]: r["reason"] for r in rep["removed"]}
-    assert reasons[keys[1]] == "missing_complete_marker"  # corrupt goes first
-    assert sum(1 for r in reasons.values() if r == "lru") >= 1
-    assert store.stats()["bytes"] <= store.entry_bytes(keys[0]) * 2
-
-
-# ---------------------------------------------------------------------------
-# engine: persist -> restore, in-process sharing
-# ---------------------------------------------------------------------------
-
-def test_engine_cold_persist_then_warm_restore(tiny_model, store):
-    prompt = list(range(1, 7))
-    cold = _tiny_engine(tiny_model)
-    cold.prewarm()
-    cold_ids = cold.generate([prompt], max_new_tokens=4)
-    n_buckets = cold.bucket_stats["compiles"]
-    assert n_buckets >= 2  # prefill buckets + the decode bucket
-    evs = [e for e in cc.events() if e["origin"] == "serving"]
-    assert {e["outcome"] for e in evs} == {"miss", "persist"}
-    # the relaunch: no in-process executables survive
-    del cold
-    cc.clear_shared()
-    cc.reset()
-    warm = _tiny_engine(tiny_model)
-    warm.prewarm()
-    warm_ids = warm.generate([prompt], max_new_tokens=4)
-    assert warm.bucket_stats.get("compiles", 0) == 0
-    assert warm.bucket_stats.get("restored", 0) == n_buckets
-    evs = [e for e in cc.events() if e["origin"] == "serving"]
-    assert evs and all(e["outcome"] == "restore" for e in evs)
-    assert warm_ids == cold_ids
-
 
 def test_engine_inprocess_sharing_outcome_shared(tiny_model, telemetry_on):
-    cc.clear_shared()
+    clear_shared_executables()
     cc.reset()
     a = _tiny_engine(tiny_model)
     a.prewarm()
@@ -294,7 +155,7 @@ def test_fleet_prewarm_compiles_once(tiny_model, telemetry_on):
     registry."""
     from paddle_tpu.inference.fleet import ReplicaFleet
 
-    cc.clear_shared()
+    clear_shared_executables()
     cc.reset()
     engines = [_tiny_engine(tiny_model) for _ in range(2)]
     fl = ReplicaFleet(engines)
@@ -308,58 +169,53 @@ def test_fleet_prewarm_compiles_once(tiny_model, telemetry_on):
 # the other entry points: to_static, static Executor, fused optimizer
 # ---------------------------------------------------------------------------
 
-def test_to_static_ledger_and_persistent_restore(store):
+def test_to_static_ledger_miss_then_hit(telemetry_on):
     from paddle_tpu import nn
 
-    def build():
-        paddle.seed(11)
-        m = nn.Linear(4, 2)
-        return m, paddle.jit.to_static(lambda x: m(x) * 2)
-
+    paddle.seed(11)
+    m = nn.Linear(4, 2)
+    f = paddle.jit.to_static(lambda x: m(x) * 2)
     x = paddle.ones([2, 4])
     serial0 = cc.ledger.last_serial()
-    _, f1 = build()
-    f1(x)  # first call is the eager recording run; compile is on call 2
-    out1 = f1(x).numpy()
-    evs = [e for e in cc.events(since_serial=serial0)
-           if e["origin"] == "to_static"]
-    assert [e["outcome"] for e in evs] == ["miss", "persist"]
-    assert evs[0]["fingerprint"]
-    # a fresh capture of the same program restores instead of recompiling
-    serial1 = cc.ledger.last_serial()
-    _, f2 = build()
-    f2(x)
-    out2 = f2(x).numpy()
-    evs = [e for e in cc.events(since_serial=serial1)
-           if e["origin"] == "to_static"]
-    assert [e["outcome"] for e in evs] == ["restore"]
+    eager = f(x).numpy()  # first call is the eager recording run
+    out1 = f(x).numpy()  # the compile is on call 2
+    (ev,) = [e for e in cc.events(since_serial=serial0)
+             if e["origin"] == "to_static"]
+    assert ev["outcome"] == "miss" and ev["seconds"] > 0
+    assert ev["signature"].startswith("n_state=")
+    # the entry's own program serves call 3: no second event
+    out2 = f(x).numpy()
+    assert [e for e in cc.events(since_serial=serial0)
+            if e["origin"] == "to_static"] == [ev]
     np.testing.assert_array_equal(out1, out2)
+    np.testing.assert_allclose(eager, out1, rtol=1e-6)
 
 
-def test_static_executor_ledger_and_restore(store):
+def test_static_executor_ledger_miss_then_hit(telemetry_on):
     from paddle_tpu import static
 
-    def run_once():
-        main = static.Program()
-        with static.program_guard(main, static.Program()):
-            x = static.data("x", [2, 4], "float32")
-            y = paddle.matmul(x, paddle.ones([4, 2])) + 1.0
-        exe = static.Executor()
-        feed = np.arange(8, dtype="float32").reshape(2, 4)
-        (out,) = exe.run(main, feed={"x": feed}, fetch_list=[y])
-        return out
+    main = static.Program()
+    with static.program_guard(main, static.Program()):
+        x = static.data("x", [2, 4], "float32")
+        y = paddle.matmul(x, paddle.ones([4, 2])) + 1.0
+    exe = static.Executor()
+    feed = np.arange(8, dtype="float32").reshape(2, 4)
 
     serial0 = cc.ledger.last_serial()
-    out1 = run_once()
-    evs = [e for e in cc.events(since_serial=serial0)
-           if e["origin"] == "static_executor"]
-    assert [e["outcome"] for e in evs] == ["miss", "persist"]
-    serial1 = cc.ledger.last_serial()
-    out2 = run_once()  # same program text + avals -> disk restore
-    evs = [e for e in cc.events(since_serial=serial1)
-           if e["origin"] == "static_executor"]
-    assert [e["outcome"] for e in evs] == ["restore"]
+    hits0 = cc.summary()["hits"]
+    (out1,) = exe.run(main, feed={"x": feed}, fetch_list=[y])
+    (ev,) = [e for e in cc.events(since_serial=serial0)
+             if e["origin"] == "static_executor"]
+    assert ev["outcome"] == "miss" and ev["seconds"] > 0
+    assert ev["signature"] == "1feeds"
+    # same program + feed shapes: the replay's own cache serves it, which
+    # the ledger counts as a hit and files no event for
+    (out2,) = exe.run(main, feed={"x": feed}, fetch_list=[y])
+    assert [e for e in cc.events(since_serial=serial0)
+            if e["origin"] == "static_executor"] == [ev]
+    assert cc.summary()["hits"] == hits0 + 1
     np.testing.assert_array_equal(out1, out2)
+    np.testing.assert_array_equal(out1, feed @ np.ones((4, 2), "float32") + 1.0)
 
 
 def test_fused_optimizer_ledger_event(telemetry_on):
@@ -413,7 +269,7 @@ def test_cold_start_report_decomposition(telemetry_on):
     cc.ledger.span("prewarm", t0 + 0.5, t0 + 3.0)
     cc.record("serving", "prefill_8", "miss", seconds=1.0)
     cc.ledger._events[-1]["t_end"] = t0 + 1.8  # land inside the prewarm span
-    cc.record("serving", "prefill_8", "persist", seconds=0.2)
+    cc.record("serving", "decode_2", "shared", seconds=0.2)
     cc.ledger._events[-1]["t_end"] = t0 + 2.0
     cc.ledger.mark("first_token", t0 + 3.4)
     rep = cc.cold_start_report()
@@ -424,7 +280,8 @@ def test_cold_start_report_decomposition(telemetry_on):
     assert abs(rep["consistency"] - 1.0) <= 0.05
     assert comps["engine_init_s"] == pytest.approx(0.5)
     assert comps["prewarm_compile_s"] == pytest.approx(1.0)
-    assert comps["prewarm_persist_s"] == pytest.approx(0.2)
+    assert comps["prewarm_host_s"] == pytest.approx(1.5)
+    assert rep["outcomes"] == {"miss": 1, "shared": 1}
     # no timeline -> explicitly unavailable, never a crash
     cc.reset_timeline()
     assert not cc.cold_start_report()["available"]
@@ -451,69 +308,3 @@ def test_report_cli_subprocess(tmp_path, telemetry_on):
          "-i", str(tmp_path / "nope.json")],
         capture_output=True, text=True, timeout=120, cwd=REPO, env=env)
     assert r.returncode == 2 and "unreadable" in r.stderr
-
-
-def test_tools_cli_subprocess(tmp_path, store):
-    """tools/compile_cache.py stats/verify/gc over a real store dir."""
-    for ch in "xy":
-        assert store.put(cc.entry_key(ch * 32), _mk_exec(),
-                         cc.make_meta("serving", "t", ch * 32))
-    os.remove(os.path.join(store.root, cc.entry_key("y" * 32), "COMPLETE"))
-    tool = os.path.join(REPO, "tools", "compile_cache.py")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-
-    def run(*args):
-        return subprocess.run([sys.executable, tool, *args],
-                              capture_output=True, text=True, timeout=120,
-                              cwd=REPO, env=env)
-
-    r = run("stats", "--dir", store.root)
-    assert r.returncode == 0, r.stderr[-2000:]
-    st = json.loads(r.stdout)
-    assert st["entries"] == 2 and st["corrupt"] == 1
-    r = run("verify", "--dir", store.root)
-    assert r.returncode == 1  # corrupt entry -> nonzero for cron wrappers
-    assert json.loads(r.stdout)["corrupt"] == 1
-    r = run("gc", "--dir", store.root, "--max-bytes", "0")
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert len(json.loads(r.stdout)["removed"]) == 2
-    r = run("verify", "--dir", store.root)
-    assert r.returncode == 0
-    # env-var default dir (no --dir)
-    env2 = dict(env, PADDLE_TPU_COMPILE_CACHE_DIR=store.root)
-    r = subprocess.run([sys.executable, tool, "stats"], capture_output=True,
-                       text=True, timeout=120, cwd=REPO, env=env2)
-    assert r.returncode == 0 and json.loads(r.stdout)["entries"] == 0
-
-
-def test_elastic_relaunch_ships_cache_dir(tmp_path, store, monkeypatch):
-    """Ship-ahead: the elastic relaunch exports the controller's compile
-    cache dir to every restarted worker, so post-scale engines restore
-    their buckets instead of recompiling."""
-    import paddle_tpu.distributed.launch.controller as ctrl_mod
-    from paddle_tpu.compile_cache.store import ENV_DIR
-    from paddle_tpu.distributed.launch import (
-        CollectiveController,
-        Context,
-        parse_args,
-    )
-    from tests.test_launch import _StubElastic
-
-    assert cc.store_dir() == store.root
-    script = tmp_path / "w.py"
-    script.write_text("import time; time.sleep(0.1)\n")
-    args = parse_args([
-        "--nnodes", "2", "--node_rank", "0", "--nproc_per_node", "1",
-        "--restart_backoff", "0.01", "--max_restart", "2",
-        "--poll_interval", "0.1", str(script),
-    ])
-    controller = CollectiveController(Context(args))
-    controller.elastic = _StubElastic(["hostA"])
-    controller.build_pod()
-    monkeypatch.setattr(ctrl_mod.time, "sleep", lambda d: None)
-    try:
-        assert controller._elastic_restart() is True
-        env = controller.pod.containers[0].env
-        assert env[ENV_DIR] == store.root
-    finally:
-        controller.pod.stop(force=True)

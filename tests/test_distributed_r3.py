@@ -9,8 +9,13 @@ import paddle_tpu.distributed as dist
 
 def test_namespace_parity():
     import ast
+    import os
 
-    tree = ast.parse(open("/root/reference/python/paddle/distributed/__init__.py").read())
+    ref_init = "/root/reference/python/paddle/distributed/__init__.py"
+    if not os.path.exists(ref_init):
+        pytest.skip("/root/reference is not mounted: nothing to compare this tree with")
+    with open(ref_init) as f:
+        tree = ast.parse(f.read())
     ref = None
     for node in ast.walk(tree):
         if isinstance(node, ast.Assign):

@@ -312,7 +312,7 @@ def test_engine_compile_is_a_span_of_the_call_that_paid(tiny_engine):
     (comp,) = [r for r in recs if r[0] == "engine.compile"]
     assert comp[4] == first[3]
     assert comp[6]["kind"] == "decode" and comp[6]["size"] == 1
-    assert comp[6]["outcome"] in ("compile", "restore", "shared")
+    assert comp[6]["outcome"] in ("compile", "shared")
     assert not [r for r in recs if r[4] == second[3] and r[0] == "engine.compile"]
 
 
@@ -339,7 +339,7 @@ def test_to_static_call_numbers_steps_and_names_phases():
                               "to_static.compile", "to_static.gather", "to_static.dispatch",
                               "to_static.writeback"]
     (comp,) = [r for r in recs if r[0] == "to_static.compile"]
-    assert comp[6]["outcome"] in ("compile", "restore")
+    assert comp[6]["outcome"] == "compile"
     # gather and dispatch twice each: the arguments, then the key's split (the
     # call's first work for the device); the state's values, then the program
     steady = ["to_static.guard", "to_static.gather", "to_static.dispatch",

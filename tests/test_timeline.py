@@ -428,10 +428,10 @@ def test_ledger_emits_independent_of_metrics_gate(monkeypatch):
 
     ledger.reset()
     monkeypatch.setattr(tm, "enabled", lambda: False)
-    ledger.record("engine", "b64", "restore", seconds=0.2)
+    ledger.record("engine", "b64", "shared", seconds=0.2)
     ledger.record("engine", "b64", "hit")  # per-dispatch: never an event
     kinds = [r["kind"] for r in tl.recorder().records()]
-    assert kinds == ["compile.restore"]
+    assert kinds == ["compile.shared"]
 
 
 def test_retry_giveup_observes_injected_site():
