@@ -9,7 +9,9 @@ The serving-tier compute core (PAPER.md L3c `jit/serving`). One engine owns:
 - a BlockPool of paged KV (inference/kv_cache.py);
 - a small set of AOT-COMPILED shape buckets: requests are padded into
   (batch=1, seq_bucket) prefill programs and (batch_bucket, 1) decode
-  programs, so steady-state serving never retraces — the same
+  programs, and ONE chunk program (the largest batch bucket's rows and up
+  to `chunk_width` prompt tokens of one more sequence, the weights read
+  once for both), so steady-state serving never retraces — the same
   per-signature `lower().compile()` discipline the static Executor adopted
   in PR 5, with every compile recorded into the perf-attribution store
   (origin "serving") and bucket hits/compiles counted in telemetry.
@@ -88,6 +90,14 @@ def clear_shared_executables() -> None:
     one test's engine would donate its buckets to the next's)."""
     with _shared_lock:
         _shared.clear()
+
+
+# Prompt tokens a chunk step carries beside the decode rows. A v5e's ridge is
+# 197e12 FLOP/s over 819e9 B/s = 240 FLOP a byte, so about 240 tokens ride one
+# read of the bf16 weights for nothing; 128 leaves the decode rows their half
+# and is one lane tile of positions in the paged kernel. Rounded to whole
+# pages by the engine: a chunk starts and ends on a page's edge.
+_CHUNK_TOKENS = 128
 
 
 def _default_prefill_buckets(max_seq_len: int, block_size: int) -> Tuple[int, ...]:
@@ -207,6 +217,13 @@ class InferenceEngine:
             self.params = params
             self._repl = None
             self._page_sharding = None
+
+        # prompt tokens a step may carry beside its decode rows (`decode_with_chunk`):
+        # whole pages, at most the table; 0 for a model with recurrent layers,
+        # whose state cannot take several tokens of a row without snapshots
+        self.chunk_width = 0 if self.num_state_layers else min(
+            max(self.block_size, _CHUNK_TOKENS // self.block_size * self.block_size),
+            self.max_pages * self.block_size)
 
         if num_blocks is None:
             # worst case: every decode slot at full context, plus the trash page
@@ -381,6 +398,13 @@ class InferenceEngine:
         with RecordEvent("engine.compile", args={"kind": kind, "size": sz}) as span:
             ex, outcome = self._compile_miss(kind, size, sz)
             span.args["outcome"] = "compile" if outcome == "miss" else outcome
+        if ((kind, size) == ("decode", self.decode_batch_buckets[-1])
+                and self.chunk_width and self.max_batch > 1):
+            # who readies the largest decode bucket readies what a step beside
+            # it may run: a prompt's chunk rides the decode rows only where a
+            # second slot exists (a lone slot's chunk program compiles on
+            # first use, after a prefix-cache hit)
+            self._get_compiled("chunk", size)
         return ex
 
     def _compile_miss(self, kind: str, size, sz):
@@ -400,6 +424,8 @@ class InferenceEngine:
                 ex = self._compile_prefill(size)
             elif kind == "decode":
                 ex = self._compile_decode(size)
+            elif kind == "chunk":
+                ex = self._compile_chunk(size)
             else:  # ("extend", (B, Q))
                 ex = self._compile_extend(*size)
             _shared_put(ekey, ex)
@@ -437,7 +463,8 @@ class InferenceEngine:
                 include_decode: bool = True,
                 extend_q: Sequence[int] = ()) -> dict:
         """Compile (or share) every bucket program up front, so
-        steady-state serving — and the first token — never pays a compile.
+        steady-state serving — and the first token — never pays a compile
+        (the chunk program comes with the largest decode bucket).
         `extend_q` adds the (B, Q) extend/verify family for the given
         query lengths (speculative decode uses draft_len + 1);
         `include_prefill=False` warms a decode-tier engine (streamed
@@ -530,11 +557,15 @@ class InferenceEngine:
         return logits if view.moe_counts is None else (logits, view.moe_counts)
 
     def _fetch(self, out, take, span):
-        """The step's ONE fetch: the logits at `take` (the real rows) and,
-        for a model with expert layers, its counters onto the span."""
+        """The step's ONE fetch: the logits at `take` (the real rows; None
+        for all of them, which starts no slicing program) and, for a model
+        with expert layers, its counters onto the span."""
+        def rows(a):
+            return a if take is None else a[take]
+
         if not self._has_moe:
-            return np.asarray(out[take])
-        logits, counts = jax.device_get((out[0][take], out[1]))
+            return np.asarray(rows(out))
+        logits, counts = jax.device_get((rows(out[0]), out[1]))
         span.args["moe_assignments"] = int(counts[0])
         span.args["moe_experts_touched"] = int(counts[1])
         span.args["moe_layers"] = int(counts[2])
@@ -626,13 +657,51 @@ class InferenceEngine:
         )
         return self._jit(fn, len(avals)).lower(*avals).compile()
 
+    def _compile_chunk(self, B: int):
+        """The chunk program: B decode rows' one token each and `chunk_width`
+        consecutive prompt tokens of ONE more sequence, all in one row of
+        tokens, so every weight matmul runs once over both and the weights
+        are read once a step. Attention splits by segment (the model's cache
+        branch, on the view's `chunk_table`); the vocabulary head runs on the
+        rows and the chunk's last real token only (`last`), so the logits
+        are [B + 1, V]."""
+        from ..core.tensor import Tensor
+        from ..jit.api import functional_call
+        from ..autograd import no_grad
+
+        model, block_size = self._model, self.block_size
+        with_counters = self._with_counters
+
+        def fn(params, tokens, positions, seq_lens, bt, chunk_bt, last, state):
+            view = PagedCacheView.from_state(state, bt, seq_lens, block_size, chunk_table=chunk_bt)
+            with no_grad():
+                logits = functional_call(
+                    model, params, Tensor(tokens), cache=view,
+                    positions=positions, last_index=last, training=False,
+                )
+            return with_counters(logits.value, view), PagedCacheView.state_of(view)
+
+        i32 = jnp.int32
+        avals = (
+            self._param_avals(),
+            jax.ShapeDtypeStruct((1, B + self.chunk_width), i32),
+            jax.ShapeDtypeStruct((1, B + self.chunk_width), i32),
+            jax.ShapeDtypeStruct((B,), i32),
+            jax.ShapeDtypeStruct((B, self.max_pages), i32),
+            jax.ShapeDtypeStruct((1, self.max_pages), i32),
+            jax.ShapeDtypeStruct((B + 1,), i32),
+            self._state_avals(),
+        )
+        return self._jit(fn, len(avals)).lower(*avals).compile()
+
     def _compile_extend(self, B: int, Q: int):
         """The extend/verify program (round 17): Q tokens per row written +
         read through the paged cache in ONE call — speculative-decode
-        verify (1 committed token + k drafts) and chunked suffix prefill
-        (Q prompt tokens per step after a prefix-cache hit) both run here.
-        `valid` masks pad slots: their K/V writes are redirected to the
-        trash page and their logits are discarded host-side."""
+        verify (1 committed token + k drafts), and with it the prompts of a
+        scheduler that runs `spec_decode` (Q tokens a row a step; without it
+        a prompt enters through `_compile_chunk`'s program). `valid` masks
+        pad slots: their K/V writes are redirected to the trash page and
+        their logits are discarded host-side."""
         from ..core.tensor import Tensor
         from ..jit.api import functional_call
         from ..autograd import no_grad
@@ -708,14 +777,56 @@ class InferenceEngine:
         """One decode step for `n` in-flight sequences (token i at absolute
         position positions[i], context length seq_lens[i] AFTER this token);
         returns logits [n, V]."""
-        n = len(tokens)
-        if n < 1:
+        if len(tokens) < 1:
             raise ValueError("decode needs at least one sequence")
-        B = self.bucket_for("decode", n)
-        with RecordEvent("engine.decode", args={"rows": n, "bucket": B}) as span:
+        return self._decode_step(tokens, positions, seq_lens, page_rows)[0]
+
+    def decode_with_chunk(
+        self,
+        tokens: Sequence[int],
+        positions: Sequence[int],
+        seq_lens: Sequence[int],
+        page_rows: Sequence[Sequence[int]],
+        chunk_ids: Sequence[int],
+        chunk_start: int,
+        chunk_pages: Sequence[int],
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """A decode step (as `decode`; `n` may be 0) that also carries the
+        next 1..`chunk_width` prompt tokens `chunk_ids` of ONE more sequence,
+        at positions chunk_start.. of `chunk_pages` (chunk_start on a page's
+        edge; the pages cover the chunk's last token). One program, the
+        weights read once: the chunk's K/V is written by whole pages and its
+        tokens see the sequence's cached context and themselves causally.
+        Returns (logits [n, V], the chunk's LAST token's logits [V]). The
+        span is an `engine.decode` like any step's, with `chunk_tokens`."""
+        take = len(chunk_ids)
+        if not self.chunk_width:
+            raise NotImplementedError(
+                "decode_with_chunk: the model has recurrent layers, and several tokens of a row "
+                "over a live recurrent state need state snapshots")
+        if take < 1 or take > self.chunk_width:
+            raise ValueError(f"a chunk holds 1..{self.chunk_width} tokens, not {take}")
+        if chunk_start % self.block_size or chunk_start + take > self.max_seq_len:
+            raise ValueError(
+                f"a chunk starts on a page's edge and ends inside the table: start {chunk_start}, "
+                f"{take} tokens, pages of {self.block_size}, max_seq_len {self.max_seq_len}")
+        return self._decode_step(tokens, positions, seq_lens, page_rows,
+                                 (chunk_ids, int(chunk_start), chunk_pages))
+
+    def _decode_step(self, tokens, positions, seq_lens, page_rows, chunk=None):
+        """`decode` and `decode_with_chunk`: the rows into their bucket (the
+        largest, the chunk program's, where a chunk rides), the chunk's
+        tokens behind them; (logits [n, V], the chunk's logits or None)."""
+        n = len(tokens)
+        ids, start, pages = chunk or ((), 0, ())
+        take = len(ids)
+        B = self.bucket_for("decode", n) if chunk is None else self.decode_batch_buckets[-1]
+        C = self.chunk_width if chunk is not None else 0
+        args = {"rows": n, "bucket": B, "chunk_tokens": take, "chunk_width": self.chunk_width}
+        with RecordEvent("engine.decode", args=args) as span:
             with RecordEvent("engine.decode.inputs"):
-                tok = np.zeros((B,), np.int32)
-                pos = np.zeros((B,), np.int32)
+                tok = np.zeros((B + C,), np.int32)
+                pos = np.zeros((B + C,), np.int32)
                 lens = np.ones((B,), np.int32)  # inactive rows read 1 trash slot
                 bt = np.zeros((B, self.max_pages), np.int32)
                 tok[:n] = np.asarray(tokens, np.int32)
@@ -723,20 +834,36 @@ class InferenceEngine:
                 lens[:n] = np.asarray(seq_lens, np.int32)
                 for i, row in enumerate(page_rows):
                     bt[i] = self.pool.padded_table(row, self.max_pages)
+                frontiers = lens - 1
                 span.args["context"] = int(lens[:n].sum())
-                self._count_page_blocks(span, lens - 1)
+                if chunk is not None:
+                    tok[B:B + take] = np.asarray(ids, np.int32)
+                    pos[B:B + take] = start + np.arange(take, dtype=np.int32)  # pad slots stay at 0
+                    chunk_bt = np.asarray([self.pool.padded_table(pages, self.max_pages)], np.int32)
+                    last = np.append(np.arange(B, dtype=np.int32), np.int32(B + take - 1))
+                    frontiers = np.append(frontiers, np.int32(start + take - 1))
+                    span.args["context"] += start + take
+                self._count_page_blocks(span, frontiers)
                 slots = self._slots_of(page_rows, B)
                 if slots:
                     span.args["state_slots"] = self.pool.state_slots_used()
-            ex = self._get_compiled("decode", B)
+            if chunk is None:
+                ex = self._get_compiled("decode", B)
+                operands = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(lens), jnp.asarray(bt), *slots)
+            else:
+                ex = self._get_compiled("chunk", B)
+                operands = (jnp.asarray(tok[None]), jnp.asarray(pos[None]), jnp.asarray(lens),
+                            jnp.asarray(bt), jnp.asarray(chunk_bt), jnp.asarray(last))
             with RecordEvent("engine.decode.dispatch"):
-                logits, state = ex(
-                    self.params, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(lens),
-                    jnp.asarray(bt), *slots, self.pool.device_state(),
-                )
+                logits, state = ex(self.params, *operands, self.pool.device_state())
                 self.pool.adopt_state(state)
             with RecordEvent("engine.decode.fetch"):
-                out = self._fetch(logits, slice(0, n), span)
+                if chunk is None:
+                    out = self._fetch(logits, slice(0, n), span), None
+                else:
+                    # all B + 1 rows: a slice a row count is a program a count
+                    rows = self._fetch(logits, None, span)
+                    out = rows[:n], rows[B]
         self._mark_first_token()
         return out
 
@@ -752,7 +879,9 @@ class InferenceEngine:
         returning next-token logits for EVERY consumed position —
         [n, q_len, V] (pad slots hold garbage; callers read only their real
         prefix). Speculative verify reads the whole greedy chain from one
-        call; chunked suffix prefill streams q_len prompt tokens per step."""
+        call; a scheduler that speculates also streams its prompts here,
+        q_len tokens a row a step (without `spec_decode` a prompt enters
+        through `decode_with_chunk`)."""
         n = len(token_rows)
         if n < 1:
             raise ValueError("extend needs at least one sequence")

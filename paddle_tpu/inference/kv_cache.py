@@ -63,6 +63,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import hashlib
 
 import numpy as np
+from jax import lax
 from jax import numpy as jnp
 
 from .. import telemetry
@@ -160,6 +161,12 @@ class PagedCacheView:
     (optional) redirects masked positions' writes to the trash page — the
     engine's extend/verify program uses it to neutralize pad queries.
 
+    `chunk_table` [1, M] (optional) is the block table of ONE more sequence
+    whose next prompt tokens ride this step behind the decode rows' one token
+    each (the engine's chunk program): `write` serves the rows,
+    `write_chunk` the chunk, and the model reads each segment through the
+    paged kernel with its own table.
+
     The second kind of state: `ssm` / `conv` hold one array a RECURRENT
     layer (`[slots + 1, ...]`, see StateSpec) and `slots` [B] each row's slot
     (0, the trash slot, for a pad row). `read_state` gives the rows' state (a
@@ -172,7 +179,8 @@ class PagedCacheView:
     def __init__(self, k_pages: Sequence, v_pages: Sequence, block_tables,
                  seq_lens, block_size: int, k_scales: Optional[Sequence] = None,
                  v_scales: Optional[Sequence] = None, write_mask=None,
-                 ssm: Optional[Sequence] = None, conv: Optional[Sequence] = None, slots=None):
+                 ssm: Optional[Sequence] = None, conv: Optional[Sequence] = None, slots=None,
+                 chunk_table=None):
         self.k_pages = list(k_pages)
         self.v_pages = list(v_pages)
         self.k_scales = list(k_scales) if k_scales is not None else None
@@ -181,17 +189,20 @@ class PagedCacheView:
         self.seq_lens = jnp.asarray(seq_lens, jnp.int32)
         self.block_size = int(block_size)
         self.write_mask = write_mask
+        self.chunk_table = None if chunk_table is None else jnp.asarray(chunk_table, jnp.int32)
         self.ssm = list(ssm) if ssm is not None else None
         self.conv = list(conv) if conv is not None else None
         self.slots = None if slots is None else jnp.asarray(slots, jnp.int32)
         self.moe_counts = None  # [assignments, experts touched, layers] once an expert layer ran
 
     @classmethod
-    def from_state(cls, state, block_tables, seq_lens, block_size, write_mask=None, slots=None):
+    def from_state(cls, state, block_tables, seq_lens, block_size, write_mask=None, slots=None,
+                   chunk_table=None):
         """A view over a pool's state pytree (`BlockPool.device_state()`)."""
         return cls(state["k"], state["v"], block_tables, seq_lens, block_size,
                    k_scales=state.get("k_scale"), v_scales=state.get("v_scale"),
-                   write_mask=write_mask, ssm=state.get("ssm"), conv=state.get("conv"), slots=slots)
+                   write_mask=write_mask, ssm=state.get("ssm"), conv=state.get("conv"), slots=slots,
+                   chunk_table=chunk_table)
 
     @staticmethod
     def state_of(view) -> Dict[str, List]:
@@ -323,12 +334,7 @@ class PagedCacheView:
         if positions is None:
             if self.write_mask is not None:
                 raise ValueError("write_mask narrows positioned writes; a prefill has none")
-            n = -(-s // bs)
-            pages = self.block_tables[:, :n]
-
-            def put(pool, new):  # new [B, S, Hkv, ...]
-                new = jnp.pad(new, [(0, 0), (0, n * bs - s)] + [(0, 0)] * (new.ndim - 2))
-                return pool.at[pages].set(jnp.swapaxes(new.reshape(b, n, bs, *new.shape[2:]), 2, 3))
+            put = self._whole_pages(self.block_tables[:, :-(-s // bs)], b, s)
         else:
             positions = jnp.asarray(positions, jnp.int32)
             pages = jnp.take_along_axis(self.block_tables, positions // bs, axis=1)
@@ -339,6 +345,39 @@ class PagedCacheView:
             def put(pool, new):
                 return pool.at[at].set(new)
 
+        self._put(idx, k_new, v_new, put)
+
+    def write_chunk(self, idx: int, k_new, v_new, first_position) -> None:
+        """Put the chunk's K/V [1, C, Hkv, D] into layer `idx`'s pages: the
+        prefill's whole-page write, from the page of `first_position` (a
+        traced scalar, a multiple of the page size: a chunk starts where a
+        shared prefix or an earlier chunk ended, on a page's edge) of
+        `chunk_table` on. A last page the tokens fill in part is written
+        whole: its tail holds what the chunk's padding computed, past the
+        sequence's frontier, and the sequence's later writes replace it.
+        Columns past the table's end, like its padding, are the trash page."""
+        c = k_new.shape[1]
+        n = -(-c // self.block_size)
+        table = jnp.pad(self.chunk_table, ((0, 0), (0, n)), constant_values=TRASH_PAGE)
+        first = jnp.asarray(first_position, jnp.int32) // self.block_size
+        self._put(idx, k_new, v_new, self._whole_pages(
+            lax.dynamic_slice_in_dim(table, first, n, axis=1), 1, c))
+
+    def _whole_pages(self, pages, b: int, s: int):
+        """put(pool, new) that cuts `new` [B, S, Hkv, ...] into the pages
+        `pages` [B, ceil(S / bs)] names (zeros past S) and scatters them along
+        the page axis alone."""
+        bs, n = self.block_size, pages.shape[1]
+
+        def put(pool, new):
+            new = jnp.pad(new, [(0, 0), (0, n * bs - s)] + [(0, 0)] * (new.ndim - 2))
+            return pool.at[pages].set(jnp.swapaxes(new.reshape(b, n, bs, *new.shape[2:]), 2, 3))
+
+        return put
+
+    def _put(self, idx: int, k_new, v_new, put) -> None:
+        """K/V into layer `idx` through `put`, quantized first on an int8
+        pool (the scale planes through the same `put`)."""
         if self.k_scales is not None:
             # int8 storage: per-slot-per-kv-head absmax scales — the
             # observer rule (quantization/observers), applied per written

@@ -2,9 +2,13 @@
 
 The serving tier's control loop: requests are admitted into the in-flight
 decode batch at TOKEN granularity — between any two decode steps a waiting
-request can be prefilled into a free slot (vLLM/Orca-style continuous
-batching), instead of waiting for the whole batch to drain (static
-batching, kept here as the measured baseline). When the paged KV pool runs
+request can take a free slot (vLLM/Orca-style continuous batching), instead
+of waiting for the whole batch to drain (static batching, kept here as the
+measured baseline). With nothing in flight its prompt runs through one
+bucketed prefill; beside rows that decode it enters in CHUNKS that ride the
+decode step: one engine program a step for the decode rows and up to
+`engine.chunk_width` tokens of ONE prompt, the oldest with prompt left, the
+weights read once for both (`engine.decode_with_chunk`). When the paged KV pool runs
 dry, the scheduler PREEMPTS: the youngest running request is evicted, its
 pages freed, and it re-queues at the FRONT of the waiting line with its
 generated prefix folded into the prompt (recompute-on-resume — the pages
@@ -31,7 +35,7 @@ Round 17 — prefix sharing + speculative decoding:
 - Admission consults the pool's prefix index (`prefix_cache=True`,
   default): a prompt whose leading FULL pages match a resident chain
   shares those pages ref-counted (the last prompt token is always
-  recomputed — its logits emit the first generated token) and streams only
+  recomputed — its logits emit the first generated token) and chunks only
   the suffix, so prefill work drops to O(new suffix) and shared system
   prompts occupy the pool once. Every running request publishes its
   committed full pages back into the index; completion retains them
@@ -46,11 +50,13 @@ Round 17 — prefix sharing + speculative decoding:
   stale K/V writes sit past seq_len, masked and overwritten, and surplus
   tail pages are rolled back to the pool). Greedy verify emits EXACTLY
   the tokens plain decode would — byte-identical outputs, fewer steps.
-  Prompt streaming rides the same program `draft_len + 1` tokens per
-  step (chunked prefill at chunk granularity).
+  With `spec_decode` on, prompts stream through the same program
+  `draft_len + 1` tokens a row a step, and no chunk is planned.
 - A model with recurrent layers (the pool then holds a state slot a
-  sequence, `pool.has_recurrent_state`) gets neither: prefix lookup and
-  registration are skipped and `spec_decode` is refused, because both need
+  sequence, `pool.has_recurrent_state`) gets none of the three: prefix
+  lookup and registration are skipped, `spec_decode` is refused and a
+  prompt beside decode rows streams ONE token a step through its own decode
+  row (the engine's `chunk_width` is 0), because each needs
   snapshots of the state. Its slot is bound with the request's first page
   and released with it: finish, expiry, shed and preemption free the pages,
   and a preempted request streams again from position 0, from the zero state.
@@ -231,9 +237,11 @@ class Request:
     # and its `request.prompt` span starts
     slot: Optional[tuple] = None
     token_times: List[float] = field(default_factory=list)
-    # token-streamed admission: prompt tokens already written to the cache
-    # (cursor == len(prompt) once the request is generating)
+    # chunked or streamed admission: prompt tokens already written to the
+    # cache (cursor == len(prompt) once the request is generating), and the
+    # steps that carried a chunk of this prompt
     cursor: int = 0
+    chunks: int = 0
     # recompute-on-resume: prompt tokens re-prefilled after a preemption
     # include the already-generated prefix; `_prompt_len` keeps the original
     _prompt_len: Optional[int] = None
@@ -286,7 +294,8 @@ class ContinuousBatchingScheduler:
 
     step() = [complete finished] -> [admit waiting while slots + pages
     allow] -> [grow running sequences' page allocation, preempting when the
-    pool is dry] -> [one decode step for everyone running].
+    pool is dry] -> [one engine step: a token for everyone who decodes and,
+    beside them, a chunk of the oldest prompt still to come].
     """
 
     def __init__(self, engine, *, max_running: Optional[int] = None,
@@ -315,10 +324,11 @@ class ContinuousBatchingScheduler:
         self.prefix_cache = bool(prefix_cache) and not recurrent
         self.spec = spec_decode
         # "auto" (default): idle-scheduler admissions run a bucketed prefill
-        # program, busy ones stream. "streamed": NEVER bucketed — the
-        # disaggregated fleet's decode tier runs this, so it serves streamed
-        # prefill (tier-degradation intake) without ever compiling a prefill
-        # bucket, keeping its compile family decode-only
+        # program, busy ones enter in chunks beside the decode rows (or
+        # stream, where no chunk can be planned). "streamed": NEVER bucketed —
+        # the disaggregated fleet's decode tier runs this, so it takes prompts
+        # in (tier-degradation intake) without ever compiling a prefill
+        # bucket, keeping its compile family to the decode steps' programs
         if admission_mode not in ("auto", "streamed"):
             raise ValueError(
                 f"admission_mode {admission_mode!r} is not 'auto' or 'streamed'")
@@ -339,6 +349,7 @@ class ContinuousBatchingScheduler:
         # caller is expected to route elsewhere; anything queued here just
         # waits out the drain)
         self.draining = False
+        self._entered = {"prompt_tokens": 0, "chunk_tokens": 0}  # of the step that runs
 
     # ---- queue surface ----
     def drain(self) -> None:
@@ -672,7 +683,7 @@ class ContinuousBatchingScheduler:
                 t_slot, mode, cached = req.slot
                 record_span("request.prompt", t_slot, now, ident=req.rid,
                             args={"mode": mode, "prompt_len": req.prompt_len,
-                                  "cached": cached})
+                                  "cached": cached, "chunks": req.chunks})
             if telemetry.enabled() and req.submitted_time is not None:
                 # both timestamps from the scheduler clock: queue wait
                 # inside the scheduler is included, replay-offset arrival
@@ -686,34 +697,54 @@ class ContinuousBatchingScheduler:
 
     @staticmethod
     def _tokens_needed(req: Request) -> int:
-        """Cache slots this step's write for `req` must be covered for:
-        streaming writes prompt[cursor] at position cursor; generation
+        """Cache slots the next one-token write for `req` must be covered
+        for: streaming writes prompt[cursor] at position cursor; generation
         writes generated[-1] at position context_len - 1."""
         if req.cursor < len(req.prompt):
             return req.cursor + 1
         return req.context_len
 
+    def _chunk_width(self) -> int:
+        """Prompt tokens a step may carry beside its decode rows; 0 where a
+        prompt streams instead: the pool holds recurrent state (the engine's
+        width is 0: one token a step), or speculative decoding is on (its
+        plans stream the prompt through `engine.extend`)."""
+        return 0 if self.spec is not None else getattr(self.engine, "chunk_width", 0)
+
+    def _chunkable(self, req: Request) -> bool:
+        """Whether `req`'s next prompt tokens can enter as a chunk: it has
+        prompt left, on a page's edge (admission starts it on one and a chunk
+        is whole pages; only a step under `spec_decode` leaves it elsewhere,
+        and then it streams a token a step up to the next edge)."""
+        return (self._chunk_width() > 0 and req.cursor < len(req.prompt)
+                and req.cursor % self.engine.pool.block_size == 0)
+
     def _try_admit(self) -> Optional[int]:
         """Admit the oldest waiting request into a free decode slot;
         returns the number of tokens emitted by the admission (1 for a
-        bucketed prefill, 0 for a streamed one), or None when blocked.
+        bucketed prefill, 0 otherwise), or None when blocked.
 
         Two admission paths (the continuous-batching TPOT trade): with
         NOTHING in flight there is no one to stall, so the prompt runs
         through a bucketed prefill program in one shot (TTFT-optimal).
         With decode in flight, a monolithic prefill between two decode
         steps would stretch every in-flight request's inter-token interval
-        — instead the prompt is STREAMED through the request's own decode
-        slot one token per step (chunked prefill at token granularity), so
-        admission never stalls anyone else's decode cadence.
+        — instead the request takes a slot with its prompt still to come
+        ("chunked"), and the steps that follow carry it in beside the decode
+        rows, up to `engine.chunk_width` tokens of ONE prompt a step, oldest
+        first (`_step_inner`); meanwhile it holds no decode row, and it
+        emits its first token in the step that carries its last chunk.
+        Where no chunk can be planned (`_chunk_width()` 0: recurrent state,
+        or `spec_decode`) the prompt is "streamed" through the request's own
+        decode row, one token a step (`draft_len + 1` under `spec_decode`).
 
         Round 17: admission consults the prefix index first. A hit shares
-        the resident pages (refcounted) and ALWAYS streams — only the
-        un-cached suffix flows through decode slots, and the bucketed
-        prefill (which writes every prompt position) never touches shared
-        pages. The last prompt token is never served from cache: its
-        logits emit the first generated token, so at least one position
-        always recomputes.
+        the resident pages (refcounted) and NEVER takes the bucketed prefill
+        (which writes every prompt position, and must not touch shared
+        pages) — only the un-cached suffix enters, from the page's edge
+        where the shared pages end. The last prompt token is never served
+        from cache: its logits emit the first generated token, so at least
+        one position always recomputes.
         """
         if self.draining or not self.waiting or len(self.running) >= self.max_running:
             return None
@@ -740,6 +771,7 @@ class ContinuousBatchingScheduler:
                     self._trace_admit(req, mode="bucketed")
                 logits = self.engine.prefill(req.prompt, req.pages)
                 req.cursor = len(req.prompt)
+                self._entered["prompt_tokens"] += len(req.prompt)
                 if telemetry.enabled():
                     _req_counter().labels(event="admitted", reason="").inc()
                 self._emit_token(req, logits, self.clock())
@@ -747,9 +779,10 @@ class ContinuousBatchingScheduler:
                     self.running.append(req)
                 self._register_committed(req)
                 return 1
-            # bucketed allocation doesn't fit: fall through and stream the
-            # prompt page-by-page instead (the pool-constrained path)
-        # streamed admission: one fresh page holds the first uncached write
+            # bucketed allocation doesn't fit: fall through and let the
+            # prompt enter page by page instead (the pool-constrained path)
+        # chunked or streamed admission: one fresh page holds the first
+        # uncached write, the steps grow the rest
         if pool.available() < 1:
             if shared:
                 # admission blocked after the lookup took refs — hand them
@@ -767,9 +800,10 @@ class ContinuousBatchingScheduler:
         req._registered_pages = len(shared)
         req._chain_digest = keys[len(shared) - 1] if shared else b""
         self.running.append(req)
-        self._note_slot(req, "streamed", cached)
+        mode = "chunked" if self._chunk_width() else "streamed"
+        self._note_slot(req, mode, cached)
         if req.trace is not None:
-            self._trace_admit(req, mode="streamed", cached=cached)
+            self._trace_admit(req, mode=mode, cached=cached)
         if telemetry.enabled():
             _req_counter().labels(event="admitted", reason="").inc()
         return 0
@@ -925,6 +959,7 @@ class ContinuousBatchingScheduler:
             kind, toks, _poss = plans[r.rid]
             if kind == "stream":
                 r.cursor += len(toks)
+                self._entered["prompt_tokens"] += len(toks)
                 if r.cursor == len(r.prompt):
                     # the last prompt token's logits ARE the first
                     # generated token
@@ -975,6 +1010,8 @@ class ContinuousBatchingScheduler:
             if (self.spec is not None and self.qos is not None
                     and not self.qos.brownout.spec_allowed()):
                 self.spec = None
+            # prompt tokens that enter in this step: by any path, and in a chunk
+            self._entered = {"prompt_tokens": 0, "chunk_tokens": 0}
             try:
                 produced = self._step_inner()
             finally:
@@ -984,7 +1021,7 @@ class ContinuousBatchingScheduler:
                 self.ewma_step_s = (dt if self.ewma_step_s is None
                                     else 0.8 * self.ewma_step_s + 0.2 * dt)
             span.args = {"produced": produced, "running": len(self.running),
-                         "waiting": len(self.waiting)}
+                         "waiting": len(self.waiting), **self._entered}
             if self.engine.pool.has_recurrent_state:
                 span.args["state_slots"] = self.engine.pool.state_slots_used()
         return produced
@@ -1051,6 +1088,12 @@ class ContinuousBatchingScheduler:
             if self.spec is not None:
                 for req in self.running:
                     plans[req.rid] = self._plan_row(req)
+            # the step's ONE chunk: the oldest request with prompt left (the
+            # order of admission, which is `_try_admit`'s); the others with
+            # prompt left keep their slot, wait their turn and write nothing
+            chunk_req = next((r for r in self.running if self._chunkable(r)), None)
+            take = 0 if chunk_req is None else min(
+                self._chunk_width(), len(chunk_req.prompt) - chunk_req.cursor)
 
             # growth: every running sequence needs pages covering the K/V slots
             # this step writes; allocate at block boundaries, preempting until
@@ -1061,15 +1104,21 @@ class ContinuousBatchingScheduler:
                     # evicted by an earlier iteration's preemption — allocating
                     # into it now would leak the page at re-admission
                     continue
+                # lo..hi: the positions this step writes for the request
                 if self.spec is not None:
-                    need_tokens = plans[req.rid][2][-1] + 1
+                    _, _, poss = plans[req.rid]
+                    lo, hi = poss[0], poss[-1]
+                elif req is chunk_req:
+                    lo, hi = req.cursor, req.cursor + take - 1
+                elif self._chunkable(req):
+                    continue  # waits its turn
                 else:
-                    need_tokens = self._tokens_needed(req)
-                if need_tokens > self.engine.max_seq_len:
+                    lo = hi = self._tokens_needed(req) - 1
+                if hi + 1 > self.engine.max_seq_len:
                     # capacity guard (submit() bounds this; belt-and-braces)
                     self._finish(req, self.clock())
                     continue
-                while pool.blocks_for_tokens(need_tokens) > len(req.pages):
+                while pool.blocks_for_tokens(hi + 1) > len(req.pages):
                     try:
                         req.pages.extend(pool.alloc(1, owner=req.rid))
                     except PoolExhausted:
@@ -1085,17 +1134,13 @@ class ContinuousBatchingScheduler:
                 # evacuate/resume and rollback races are exactly where a silent
                 # scribble would corrupt a neighbor — clone instead.
                 if req in self.running and req.pages:
-                    if self.spec is not None:
-                        _, _, poss = plans[req.rid]
-                        lo, hi = poss[0], poss[-1]
-                    else:
-                        hi = self._tokens_needed(req) - 1
-                        lo = hi
                     for pi in range(lo // pool.block_size,
                                     min(hi // pool.block_size, len(req.pages) - 1) + 1):
                         if pool.refcount(req.pages[pi]) > 1:
                             req.pages[pi] = pool.make_private(req.pages[pi], owner=req.rid)
             alive = [r for r in self.running if r.pages]
+            if chunk_req not in alive:
+                chunk_req = None  # finished by the guard, or a later row's growth evicted it
 
         if alive and self.spec is not None:
             with RecordEvent("sched.spec"):
@@ -1105,6 +1150,8 @@ class ContinuousBatchingScheduler:
             with RecordEvent("sched.rows"):
                 rows = []
                 for r in alive:
+                    if r is chunk_req or self._chunkable(r):
+                        continue  # the chunk, or a prompt that waits its turn: no decode row
                     if r.cursor < len(r.prompt):  # streaming its prompt in
                         rows.append((r, r.prompt[r.cursor], r.cursor))
                     else:
@@ -1113,15 +1160,23 @@ class ContinuousBatchingScheduler:
                 positions = [p for _, _, p in rows]
                 seq_lens = [p + 1 for _, _, p in rows]
                 page_rows = [r.pages for r, _, _ in rows]
-            logits = self.engine.decode(
-                tokens=tokens, positions=positions, seq_lens=seq_lens,
-                page_rows=page_rows,
-            )
+            logits, chunk_logits = (), None
+            if chunk_req is not None:
+                logits, chunk_logits = self.engine.decode_with_chunk(
+                    tokens, positions, seq_lens, page_rows,
+                    chunk_req.prompt[chunk_req.cursor:chunk_req.cursor + take],
+                    chunk_req.cursor, chunk_req.pages)
+            elif rows:
+                logits = self.engine.decode(
+                    tokens=tokens, positions=positions, seq_lens=seq_lens,
+                    page_rows=page_rows,
+                )
             with RecordEvent("sched.emit"):
                 now = self.clock()
                 for (r, _, _), lg in zip(rows, logits):
                     if r.cursor < len(r.prompt):
                         r.cursor += 1
+                        self._entered["prompt_tokens"] += 1
                         if r.cursor == len(r.prompt):
                             # the last prompt token's logits ARE the first
                             # generated token
@@ -1129,6 +1184,15 @@ class ContinuousBatchingScheduler:
                             produced += 1
                     else:
                         self._emit_token(r, lg, now)
+                        produced += 1
+                if chunk_logits is not None:
+                    chunk_req.cursor += take
+                    chunk_req.chunks += 1
+                    self._entered["prompt_tokens"] += take
+                    self._entered["chunk_tokens"] += take
+                    if chunk_req.cursor == len(chunk_req.prompt):
+                        # the step that carries a prompt's last chunk emits its first token
+                        self._emit_token(chunk_req, chunk_logits, now)
                         produced += 1
                 self.running = [r for r in self.running if not r.done]
         with RecordEvent("sched.publish"):

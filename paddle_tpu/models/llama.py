@@ -11,7 +11,9 @@ threads a paged KV cache (inference/kv_cache.PagedCacheView) through the
 attention layers — prefill writes the prompt's K/V into the cache pages and
 runs the normal causal attention; single-token decode writes the new K/V at
 `positions` and reads the whole context back through the Pallas paged
-flash-decode kernel (jnp reference off-TPU). The cache path is
+flash-decode kernel (jnp reference off-TPU); a view with a `chunk_table`
+is the engine's chunk step, decode rows and a chunk of one prompt in one
+row of tokens (`_rows_and_chunk_attention`). The cache path is
 inference-only (no grad is taped through it).
 """
 from __future__ import annotations
@@ -114,6 +116,9 @@ class LlamaAttention(nn.Layer):
             raw_pos = positions.value if isinstance(positions, Tensor) else positions
             pos2d = jnp.asarray(raw_pos, jnp.int32).reshape(b, -1)
         qr, kr = _rope(q.value, k.value, positions=pos2d, max_pos=max_pos)
+        if cache.chunk_table is not None:
+            out_t = Tensor(_rows_and_chunk_attention(cache, self.layer_idx, qr, kr, v.value, pos2d))
+            return self.o_proj(manip.reshape(out_t, [b, s, self.num_heads * self.head_dim]))
         cache.write(self.layer_idx, kr, v.value, pos2d)
         if positions is None:
             # prefill: the context IS this call's k/v — normal causal
@@ -142,6 +147,28 @@ class LlamaAttention(nn.Layer):
             out_t = Tensor(out)
         out_t = manip.reshape(out_t, [b, s, self.num_heads * self.head_dim])
         return self.o_proj(out_t)
+
+
+def _rows_and_chunk_attention(cache, idx, q, k, v, pos):
+    """Attention of the engine's chunk step: ONE row of tokens [1, n + C, ...],
+    the n decode rows' one token each and then C consecutive prompt tokens of
+    one more sequence (`cache.chunk_table` its pages). The projections around
+    this ran over all of them together; here each segment writes its K/V and
+    reads its own context through the paged kernel: the rows positioned and
+    one query each, the chunk by whole pages and as one row of C queries,
+    which sees what its sequence cached before it and itself causally."""
+    from ..ops.pallas import flash_decode_paged, flash_decode_paged_multi
+
+    n = cache.block_tables.shape[0]
+    cache.write(idx, k[0, :n, None], v[0, :n, None], pos[0, :n, None])
+    cache.write_chunk(idx, k[:, n:], v[:, n:], pos[0, n])
+    kp, vp = cache.layer(idx)
+    ks, vs = cache.scales(idx)
+    rows = flash_decode_paged(q[0, :n], kp, vp, cache.block_tables, cache.seq_lens,
+                              k_scales=ks, v_scales=vs)
+    chunk = flash_decode_paged_multi(q[:, n:], kp, vp, cache.chunk_table, pos[:, n:],
+                                     k_scales=ks, v_scales=vs)
+    return jnp.concatenate([rows[None], chunk], axis=1)  # [1, n + C, H, D]
 
 
 class LlamaMLP(nn.Layer):
@@ -239,7 +266,8 @@ class LlamaForCausalLM(nn.Layer):
             return loss, None
         if last_index is not None:
             # gather ONE position per row before the LM head (prefill takes
-            # the prompt's true last token; skips the [B, S, V] logits)
+            # the prompt's true last token; skips the [B, S, V] logits), or
+            # several positions of the one row of a chunk step
             idx = last_index.value if isinstance(last_index, Tensor) else last_index
             idx = jnp.asarray(idx, jnp.int32).reshape(-1)
             hv = h.value

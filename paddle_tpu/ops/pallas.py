@@ -971,7 +971,9 @@ def _paged_attn_kernel(bs, pages, group, q_count, rows, scale, quantized):
     kv heads. Q >= 1 query tokens per sequence ride as [hkv, rows, d]
     (query-major, so row r is query r // group of kv-head-group slot
     r % group; rows past Q * group are sublane padding), each masked to its
-    own causal frontier q_positions[b, r // group]. `quantized` adds
+    own causal frontier: qpos[b] is (the one position) at Q == 1, and (first
+    position, queries that count) at Q > 1, query j of the count at first + j
+    and the rest at position 0. `quantized` adds
     per-page scale-plane operands; the per-slot scales are [hkv, bs] lane
     rows, so they apply on the logits / probability side of the two matmuls
     (algebraically the dequantized K/V, without a lane->sublane relayout of
@@ -1012,12 +1014,15 @@ def _paged_attn_kernel(bs, pages, group, q_count, rows, scale, quantized):
                 logits = logits * (stacked(ksc_refs)[:, None, :] * (1.0 / 127.0))
             row = lax.broadcasted_iota(jnp.int32, (rows, width), 0)
             pos = i * width + lax.broadcasted_iota(jnp.int32, (rows, width), 1)
-            # per-query frontier: Q is static and small, so it unrolls as Q
-            # scalar-prefetch reads selected by row range (SMEM scalars never
-            # vector-gather)
-            frontier = jnp.full((rows, width), qpos_ref[b, 0], jnp.int32)
-            for qi in range(1, q_count):
-                frontier = jnp.where(row >= qi * group, qpos_ref[b, qi], frontier)
+            if q_count == 1:
+                frontier = jnp.full((rows, width), qpos_ref[b, 0], jnp.int32)
+            else:
+                # a row's queries stand at CONSECUTIVE positions, so query
+                # row // group stands at the first position plus that; the
+                # queries past the row's count (pad slots, sublane padding)
+                # stand at position 0, as the callers' pad slots do
+                qi = lax.div(row, jnp.int32(group))
+                frontier = jnp.where(qi < qpos_ref[b, 1], qpos_ref[b, 0] + qi, 0)
             logits = jnp.where((pos <= frontier)[None], logits, -1e30)
             m_prev = m_scr[...]
             m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
@@ -1053,7 +1058,11 @@ def _paged_extend_impl(q, k_pages, v_pages, block_tables, q_positions,
     block_tables = jnp.pad(block_tables, ((0, 0), (0, blocks * pages - m)))
     # the row's frontier is the max over Q, because pad slots of an extend
     # row carry position 0
-    live = paged_live_blocks(jnp.max(q_positions, axis=1), bs, m)
+    last = jnp.max(q_positions, axis=1)
+    live = paged_live_blocks(last, bs, m)
+    if qn > 1:
+        # consecutive positions a row: the kernel takes (first, count)
+        q_positions = jnp.stack([q_positions[:, 0], last - q_positions[:, 0] + 1], axis=1)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     quantized = k_scales is not None
     # pack queries query-major per kv head: row qi*group + g is query qi of
@@ -1198,7 +1207,9 @@ def flash_decode_paged_multi(q, k_pages, v_pages, block_tables, q_positions,
     q_positions  [B, Q] int32  — absolute cache position of each query;
                                  query j attends to positions <= its own
                                  (the K/V for all Q tokens must already be
-                                 written — write-then-read like decode)
+                                 written — write-then-read like decode).
+                                 A row's positions are CONSECUTIVE from its
+                                 first; slots past its last token carry 0
 
     Same dispatch contract as flash_decode_paged."""
     if q.ndim != 4:

@@ -133,6 +133,34 @@ def test_paged_attention_compiles_at_the_cells_shapes(one_chip, B, M, q_len):
     assert _paged_kernels(one_chip, BF16, BF16, q_len, B=B, N=4097, M=M) == 1
 
 
+@pytest.mark.parametrize("pool_dtype", [BF16, jnp.int8], ids=["bf16", "int8"])
+def test_paged_attention_compiles_for_a_prompts_chunk(one_chip, pool_dtype):
+    """The chunk row of the engine's chunk program: ONE row of 128 queries
+    (512 query rows a kv head's tile, its frontier from an iota) over the
+    chat cell's table."""
+    assert _paged_kernels(one_chip, pool_dtype, BF16, 128, B=1, N=4097, M=64) == 1
+
+
+def test_decode_kernel_body_at_one_query_is_what_it_was():
+    """PR 30 gave the kernel's mask a second form for rows of many
+    consecutive queries. Every decode step of every serving cell runs the
+    kernel at ONE query a row, and what it traces to there is, letter for
+    letter, what PR 29's tree traced to (the digest below is of that tree's
+    text; a jaxpr carries no source locations). A PR that changes the
+    one-query kernel on purpose replaces the digest, and measures decode."""
+    import hashlib
+
+    B, H, HKV, D, BS, N, M = 32, 32, 8, 128, 16, 4097, 64
+    pages = jax.ShapeDtypeStruct((N, HKV, BS, D), BF16)
+    with jax.enable_x64(False):
+        text = str(jax.make_jaxpr(lambda *a: pk._paged_decode_impl(*a, None))(
+            jax.ShapeDtypeStruct((B, H, D), BF16), pages, pages,
+            jax.ShapeDtypeStruct((B, M), jnp.int32), jax.ShapeDtypeStruct((B,), jnp.int32)))
+    assert "paged_attn" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "475a45a666ca3cf3bdbb192b375084271425f70a55e7d36f6c61ed0e2a412752")
+
+
 def test_paged_attention_compiles_at_the_hybrid_cells_shapes(one_chip):
     """The hybrid decoder's one attention layer: 32 query heads over 2 kv
     heads (16 queries a kv head against the dense decoder's 4), the 128-row
@@ -188,13 +216,16 @@ def test_kv_write_is_in_place(one_chip, n, hkv, b, s, m, how, pool_dtype):
     assert compiled.memory_analysis().temp_size_in_bytes < 24 << 20   # a copy of the bf16 pool is 134 MB
 
 
-@pytest.mark.parametrize("program, size", [("decode", 32), ("prefill", 512), ("extend", (4, 4))],
-                         ids=["decode_b32", "prefill_s512", "extend_4x4"])
-def test_engine_program_holds_no_copy_of_the_pool(one_chip, monkeypatch, program, size):
-    """The whole program: the engine's 32-row decode, 512-token prefill and
-    (4, 4) extend of a 2-layer decoder at Mistral widths (no weight and no
-    page exists: model and engine are built under `jax.eval_shape`), state
-    donated as on the chip."""
+@pytest.mark.parametrize("program, size, scatters", [
+    ("decode", 32, 4), ("prefill", 512, 4), ("extend", (4, 4), 4),
+    ("chunk", 32, 8),   # the rows' positioned write and the chunk's whole pages, K and V, a layer
+], ids=["decode_b32", "prefill_s512", "extend_4x4", "chunk_b32_c128"])
+def test_engine_program_holds_no_copy_of_the_pool(one_chip, monkeypatch, program, size, scatters):
+    """The whole program: the engine's 32-row decode, 512-token prefill,
+    (4, 4) extend and chunk step (32 rows and 128 prompt tokens) of a 2-layer
+    decoder at Mistral widths (no weight and no page exists: model and engine
+    are built under `jax.eval_shape`), state donated as on the chip. The chunk
+    step's two paged-attention calls a layer keep the kernel's name."""
     from paddle_tpu.inference.engine import InferenceEngine
     from paddle_tpu.models.llama import LlamaForCausalLM
 
@@ -227,7 +258,12 @@ def test_engine_program_holds_no_copy_of_the_pool(one_chip, monkeypatch, program
     monkeypatch.setattr(engine, "_jit", lambda fn, n_args: ForTheChip(jit(fn, n_args)))
     compile_ = getattr(engine, "_compile_" + program)
     compiled = compile_(*size) if isinstance(size, tuple) else compile_(size)
-    assert compiled.as_text().count(" scatter(") == 4   # K and V written, a layer
+    text = compiled.as_text()
+    assert text.count(" scatter(") == scatters   # K and V written, a layer
+    if program == "chunk":
+        assert engine.chunk_width == 128
+        kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"" + KERNEL + "\"", text)
+        assert len(kernels) == 4 and all(k.startswith("paged_attn") for k in kernels)
     assert _pool_copies(compiled, engine._state_avals()["k"][0]) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20   # a copy of the pool is 134 MB
 

@@ -180,12 +180,12 @@ def test_scheduler_step_yields_named_phases_that_add_up(tiny_engine):
     sched.step()          # admission by a bucketed prefill, then a decode
     sched.submit(_request(1))
     spans.clear()
-    produced = sched.step()  # a busy step: request 1 streams in beside request 0
+    produced = sched.step()  # a busy step: request 1's prompt rides request 0's decode, one chunk
     recs = spans.records()
     by = _by_name(recs)
     (step,) = by["sched.step"]
     assert step[6] == {"produced": produced, "running": len(sched.running),
-                       "waiting": len(sched.waiting)}
+                       "waiting": len(sched.waiting), "prompt_tokens": 5, "chunk_tokens": 5}
     # nothing expires here: a sweep that finds nothing is no phase of the step
     phases = ["sched.admit", "sched.grow", "sched.rows", "engine.decode",
               "sched.emit", "sched.publish"]
@@ -252,9 +252,10 @@ def test_request_queue_plus_prompt_is_ttft(tiny_engine):
         assert q[1] == r.submitted_time and q[2] == p[1] and p[2] == r.first_token_time
         assert (q[2] - q[1]) + (p[2] - p[1]) == pytest.approx(r.ttft(), abs=1e-9)
         assert p[6]["prompt_len"] == r.prompt_len and p[6]["cached"] == 0
-    # the first found an idle scheduler: one bucketed prefill; the others streamed
-    assert prompt[0][6]["mode"] == "bucketed"
-    assert {prompt[1][6]["mode"], prompt[2][6]["mode"]} == {"streamed"}
+    # the first found an idle scheduler: one bucketed prefill; the others took a
+    # slot beside it and entered in chunks, one chunk each (5 and 6 tokens)
+    assert (prompt[0][6]["mode"], prompt[0][6]["chunks"]) == ("bucketed", 0)
+    assert [(prompt[i][6]["mode"], prompt[i][6]["chunks"]) for i in (1, 2)] == [("chunked", 1)] * 2
 
 
 def test_engine_decode_carries_rows_bucket_context(tiny_engine):
@@ -268,12 +269,79 @@ def test_engine_decode_carries_rows_bucket_context(tiny_engine):
     recs = spans.records()
     (dec,) = [r for r in recs if r[0] == "engine.decode"]
     # a 64-position table is one page block of the paged kernel: 4 rows, 4 live
-    assert dec[6] == {"rows": 3, "bucket": 4, "context": 6,
+    assert dec[6] == {"rows": 3, "bucket": 4, "context": 6, "chunk_tokens": 0, "chunk_width": 64,
                       "page_blocks_live": 4, "page_blocks_grid": 4}
     kids = [r for r in recs if r[4] == dec[3] and r[0].startswith("engine.decode.")]
     assert [r[0] for r in kids] == ["engine.decode.inputs", "engine.decode.dispatch",
                                     "engine.decode.fetch"]
     assert sum(r[2] - r[1] for r in kids) <= dec[2] - dec[1]
+
+
+@pytest.mark.parametrize("n_prompt, chunks", [(9, [9]), (128, [128]), (150, [128, 22])],
+                         ids=["one_short_chunk", "one_full_chunk", "two_chunks"])
+def test_a_chunk_step_is_an_engine_decode_span_that_counts_its_chunk(tiny_engine, n_prompt, chunks):
+    """A prompt that arrives beside a decode row rides the steps in chunks of
+    128 (512 positions at block 8: 4 page blocks a row). Each such step is
+    ONE `engine.decode` span, named and shaped as a plain step's, in the
+    largest bucket, with the chunk's tokens and frontier counted; the step's
+    `sched.step` counts them too, and the request's `request.prompt` says how
+    it entered and in how many steps."""
+    from paddle_tpu.inference.engine import InferenceEngine
+
+    wide = InferenceEngine(tiny_engine._model, max_seq_len=512, block_size=8, max_batch=4)
+    sched = _scheduler(wide, prefix_cache=False)
+    sched.submit(_request(0, n_prompt=3, max_new=8))
+    sched.step()                  # bucketed, then one decode: context 4 after it
+    late = _request(1, n_prompt=n_prompt, max_new=2)
+    sched.submit(late)
+    start = 0
+    for i, take in enumerate(chunks):
+        spans.clear()
+        sched.step()
+        recs = spans.records()
+        (dec,) = [r for r in recs if r[0] == "engine.decode"]
+        row_context = 5 + i       # the one decode row, a token a step
+        frontier_blocks = (start + take - 1) // 128 + 1
+        assert dec[6] == {"rows": 1, "bucket": 4, "chunk_tokens": take, "chunk_width": 128,
+                          "context": row_context + start + take,
+                          "page_blocks_live": 4 + frontier_blocks, "page_blocks_grid": 5 * 4}
+        kids = [r[0] for r in recs if r[4] == dec[3] and r[0].startswith("engine.decode.")]
+        assert kids == ["engine.decode.inputs", "engine.decode.dispatch", "engine.decode.fetch"]
+        (step,) = [r for r in recs if r[0] == "sched.step"]
+        assert (step[6]["chunk_tokens"], step[6]["prompt_tokens"]) == (take, take)
+        assert dec[4] == step[3]  # a child of the step, where `engine.decode` always was
+        start += take
+    (prompt,) = [r for r in spans.records() if r[0] == "request.prompt"]
+    assert prompt[5] == 1 and prompt[6] == {"mode": "chunked", "prompt_len": n_prompt, "cached": 0,
+                                            "chunks": len(chunks)}
+    spans.clear()
+    sched.step()                  # both decode: a plain step says so
+    (dec,) = [r for r in spans.records() if r[0] == "engine.decode"]
+    (step,) = [r for r in spans.records() if r[0] == "sched.step"]
+    assert (dec[6]["rows"], dec[6]["chunk_tokens"], dec[6]["chunk_width"]) == (2, 0, 128)
+    assert (step[6]["chunk_tokens"], step[6]["prompt_tokens"]) == (0, 0)
+    while not sched.idle():
+        sched.step()
+    assert wide.pool.used() == 0
+
+
+def test_a_bucketed_prefill_and_a_streamed_row_count_as_prompt_tokens_not_chunks(tiny_engine, monkeypatch):
+    sched = _scheduler(tiny_engine, prefix_cache=False)
+    sched.submit(_request(0, n_prompt=6, max_new=8))
+    sched.step()
+    (step,) = [r for r in spans.records() if r[0] == "sched.step"]
+    assert (step[6]["prompt_tokens"], step[6]["chunk_tokens"]) == (6, 0)
+    monkeypatch.setattr(tiny_engine, "chunk_width", 0)   # as a pool with recurrent state reads
+    sched.submit(_request(1, n_prompt=3, max_new=2))
+    for _ in range(3):
+        spans.clear()
+        sched.step()
+        (step,) = [r for r in spans.records() if r[0] == "sched.step"]
+        assert (step[6]["prompt_tokens"], step[6]["chunk_tokens"]) == (1, 0)
+    (prompt,) = [r for r in spans.records() if r[0] == "request.prompt"]
+    assert (prompt[6]["mode"], prompt[6]["chunks"]) == ("streamed", 0)
+    while not sched.idle():
+        sched.step()
 
 
 def test_engine_counts_the_page_blocks_the_paged_kernel_reads(tiny_engine):

@@ -600,16 +600,16 @@ def test_overload_replay_zero_loss_fair_sheds_bounded_p99(tiny_model):
 
 def test_cancel_mid_prefill_stream_frees_pages_and_closes_trace(
         tiny_model, traced):
-    eng = _engine(tiny_model)
+    eng = _engine(tiny_model, max_seq_len=256)
     sched = ContinuousBatchingScheduler(eng)
     anchor = Request(rid=0, prompt=[1, 2, 3, 4], max_new_tokens=12)
     sched.submit(anchor)
-    sched.step()                           # anchor running: B must STREAM
-    streamer = Request(rid=1, prompt=list(range(10, 50)), max_new_tokens=4)
+    sched.step()                           # anchor running: B enters in chunks of 128
+    streamer = Request(rid=1, prompt=list(range(10, 160)), max_new_tokens=4)
     sched.submit(streamer)
     sched.step()
     assert streamer in sched.running
-    assert streamer.cursor < len(streamer.prompt)   # genuinely mid-stream
+    assert 0 < streamer.cursor < len(streamer.prompt)   # genuinely mid-prompt
     used_before = eng.pool.used()
     assert sched.cancel(1)
     # pages freed the SAME step, not at the next harvest
@@ -626,14 +626,14 @@ def test_cancel_mid_prefill_stream_frees_pages_and_closes_trace(
 
 
 def test_ttl_expiry_mid_prefill_stream(tiny_model, traced):
-    eng = _engine(tiny_model)
+    eng = _engine(tiny_model, max_seq_len=256)
     t = [0.0]
     sched = ContinuousBatchingScheduler(eng, clock=lambda: t[0])
     anchor = Request(rid=0, prompt=[1, 2, 3], max_new_tokens=8)
     sched.submit(anchor)
     sched.step()
-    doomed = Request(rid=1, prompt=list(range(100, 140)), max_new_tokens=4,
-                     deadline_s=0.5)
+    doomed = Request(rid=1, prompt=list(range(100, 250)), max_new_tokens=4,
+                     deadline_s=0.5)       # two chunks of 128: one step leaves it mid-prompt
     sched.submit(doomed)
     sched.step()
     assert doomed in sched.running and doomed.cursor < len(doomed.prompt)

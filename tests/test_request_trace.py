@@ -234,7 +234,9 @@ def test_fleet_swap_and_kill_trace_completeness(tiny_model, traced):
     weights = {k: v.numpy() for k, v in tiny_model.state_dict().items()}
     events = [
         (3, lambda: fleet.request_swap(weights)),
-        (6, lambda: fi.install_plan(
+        # after 4 of 12: replica 1 still holds work (a prompt enters in one
+        # chunk now, and by 6 it has none left to evacuate)
+        (4, lambda: fi.install_plan(
             fi.FaultPlan().add("fleet.replica_step.1", "fail", times=2))),
     ]
     stats = fleet_replay(fleet, _mk_requests(12, seed=13), events=events)

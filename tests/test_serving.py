@@ -258,9 +258,10 @@ def test_engine_bucket_hit_counters(tiny_model):
     pages = eng.pool.alloc(2)
     eng.prefill([1, 2, 3], pages)        # compiles prefill_16
     eng.prefill([4, 5, 6, 7], pages)     # hit
-    eng.decode([1], [3], [4], [pages])   # compiles decode_2 (bucket rounds up)
-    eng.decode([2], [4], [5], [pages])   # hit
-    assert eng.bucket_stats == {"hits": 2, "compiles": 2}
+    eng.decode([1], [3], [4], [pages])   # compiles decode_2 (bucket rounds up) and,
+    eng.decode([2], [4], [5], [pages])   # hit    it being the largest, the chunk program
+    assert eng.bucket_stats == {"hits": 2, "compiles": 3}
+    assert ("chunk", 2) in eng._compiled
     assert eng.bucket_for("prefill", 17) == 32
     with pytest.raises(ValueError, match="exceeds the largest bucket"):
         eng.bucket_for("prefill", 33)
@@ -280,10 +281,11 @@ def test_engine_bucket_hit_counters(tiny_model):
 
 def test_scheduler_token_level_admission_seeded_trace(tiny_model, shared_engine):
     """Under a seeded arrival trace: FCFS admission, the first admission
-    (idle system) runs the bucketed prefill, later admissions stream their
-    prompts through decode slots without a prefill call, and a request
-    arriving mid-flight joins the running batch before earlier requests
-    finish (token-level admission, not batch-level)."""
+    (idle system) runs the bucketed prefill, later admissions take a slot
+    and their prompts ride the decode steps in chunks, one prompt a step,
+    without a prefill call, and a request arriving mid-flight joins the
+    running batch before earlier requests finish (token-level admission,
+    not batch-level)."""
     from paddle_tpu.inference.scheduler import ContinuousBatchingScheduler, Request
 
     eng = shared_engine
@@ -313,20 +315,24 @@ def test_scheduler_token_level_admission_seeded_trace(tiny_model, shared_engine)
         sched.submit(r2)
         sched.submit(r3)
         sched.step()
-        # token-level admission: r1/r2 joined the in-flight batch, streamed
-        # (no further prefill calls); r3 waits for a slot (max_running=3)
+        # token-level admission: r1/r2 joined the in-flight batch, chunked
+        # (no further prefill calls); r3 waits for a slot (max_running=3).
+        # The step carried r1's whole prompt as its one chunk, so r1 has its
+        # first token; r2 holds its slot and waits its turn
         assert prefills == [r0.prompt]
         assert {r.rid for r in sched.running} == {0, 1, 2}
         assert [r.rid for r in sched.waiting] == [3]
-        assert r1.cursor >= 1 and r1.generated == []
+        assert r1.cursor == 6 and len(r1.generated) == 1 and r1.chunks == 1
+        assert r2.cursor == 0 and r2.generated == []
 
         while not sched.idle():
             sched.step()
         # everyone finished with its full budget, FCFS preserved via slots
         for r in (r0, r1, r2, r3):
             assert len(r.generated) == 6 and r.done
-        # streamed admissions produced oracle-identical tokens
-        assert r1.generated == _greedy_oracle(tiny_model, r1.prompt, 6)
+        # chunked admissions produced oracle-identical tokens
+        for r in (r1, r2, r3):
+            assert r.generated == _greedy_oracle(tiny_model, r.prompt, 6)
     finally:
         eng.prefill = orig_prefill
     assert eng.pool.used() == 0
@@ -741,6 +747,13 @@ def _ragged_tables(rng, frontiers, m, bs, n):
     return bt
 
 
+def _consecutive(first, count, q):
+    """A row of `q` query slots: `count` consecutive positions from `first`,
+    the slots past them at position 0 (what the engine's extend and chunk
+    rows carry)."""
+    return [first + i if i < count else 0 for i in range(q)]
+
+
 # (id, block size, table width, per-row query positions [B, Q], pool dtype):
 # what the (batch, page block) grid has to tell apart and the old
 # (batch, kv head, page) grid never did — at block 16 a grid step holds 8
@@ -758,6 +771,18 @@ _RAGGED = [
     ("int8_pool_ragged", 16, 20, [[299], [128], [0], [40]], jnp.int8),
     ("int8_pool_extend_q4", 16, 12, [[100, 101, 102, 0], [0, 0, 0, 0], [13, 14, 15, 16]],
      jnp.int8),
+    # the frontier from an iota (first position + row // group, the slots past
+    # the count at 0) against the reference's frontier a query
+    ("extend_q2_across_a_block_edge", 16, 20, [[200, 201], [0, 1], [127, 128], [5, 0]], jnp.float32),
+    ("extend_q5_pad_slots_and_edges", 16, 24,
+     [_consecutive(126, 5, 5), _consecutive(0, 1, 5), _consecutive(250, 3, 5), _consecutive(7, 5, 5)],
+     jnp.float32),
+    # a prompt's chunk: one row of 128 queries (512 query rows a kv head's tile)
+    ("chunk_q128_two_live_blocks", 16, 64, [_consecutive(100, 128, 128), _consecutive(128, 40, 128)],
+     jnp.float32),
+    ("chunk_q128_eight_live_blocks", 16, 64, [_consecutive(896, 128, 128), _consecutive(900, 50, 128)],
+     jnp.float32),
+    ("int8_pool_chunk_q128", 16, 64, [_consecutive(128, 128, 128), _consecutive(0, 17, 128)], jnp.int8),
 ]
 
 
