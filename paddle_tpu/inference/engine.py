@@ -24,9 +24,13 @@ the batch with inactive rows whose block table is all trash-page and whose
 seq_len is 1 — they compute garbage that is discarded.
 
 Layer kinds: the model's `.config` may name each layer's kind
-(`layer_kinds`: "attention", "mamba", "moe"; all attention when absent). The
-pool holds K/V pages for the attention layers only and one fixed-size state
-slot a sequence for the recurrent ("mamba") ones; the programs of such a
+(`layer_kinds`: "attention", "mamba", "moe", or several joined by "+" where
+one layer is both, as "attention+moe"; all attention when absent). The
+pool holds pages for the attention layers only and one fixed-size state
+slot a sequence for the recurrent ("mamba") ones; WHAT an attention layer
+keeps a token is the model's `cache_entry` (absent: K and V, kv heads x
+head_dim; `{"layout": "latent", "width": W}`: one latent vector, a pool of
+`[N, bs, W]` pages); the programs of such a
 model take each row's slot as one more operand (resolved here from the
 row's first page: `decode` and `prefill` keep their signatures), thread the
 state arrays through with the pages, and return the expert layers' counters
@@ -45,7 +49,7 @@ import jax
 from jax import numpy as jnp
 
 from .. import telemetry
-from ..ops.pallas import paged_live_blocks, paged_page_blocks
+from ..ops.pallas import mla_live_blocks, mla_page_blocks, paged_live_blocks, paged_page_blocks
 from ..profiler.utils import RecordEvent
 from ..telemetry import metrics as _metrics
 from ..telemetry import request_trace as _rt
@@ -125,9 +129,10 @@ class InferenceEngine:
     last_index=)` decode mode (LlamaForCausalLM, NemotronHForCausalLM) and a
     `.config` dict that holds `num_hidden_layers`, `num_attention_heads`,
     `hidden_size`, `vocab_size`, optionally `num_key_value_heads`,
-    `head_dim` (default hidden_size // num_attention_heads) and
-    `layer_kinds` (one of "attention", "mamba", "moe" a layer; default all
-    attention); with a "mamba" layer also `mamba_num_heads`,
+    `head_dim` (default hidden_size // num_attention_heads),
+    `layer_kinds` (one of "attention", "mamba", "moe" a layer, or several
+    joined by "+"; default all attention) and `cache_entry` (what an
+    attention layer caches a token; default K and V); with a "mamba" layer also `mamba_num_heads`,
     `mamba_head_dim`, `ssm_state_size`, `n_groups`, `conv_kernel`. `mesh` +
     `layout_table` place the weights for TP-sharded decode (PR 7
     SpecLayout); single-device when omitted.
@@ -163,13 +168,21 @@ class InferenceEngine:
         if len(self.layer_kinds) != self.num_layers:
             raise ValueError(
                 f"model.config names {len(self.layer_kinds)} layer kinds for {self.num_layers} layers")
-        # layers that keep K/V pages, layers that keep a recurrent state
-        self.num_kv_layers = self.layer_kinds.count("attention")
-        self.num_state_layers = self.layer_kinds.count("mamba")
-        self._has_moe = "moe" in self.layer_kinds
-        heads = int(cfg["num_attention_heads"])
-        self.num_kv_heads = int(cfg.get("num_key_value_heads") or heads)
-        self.head_dim = int(cfg.get("head_dim") or int(cfg["hidden_size"]) // heads)
+        # layers that keep pages, layers that keep a recurrent state
+        parts = [k.split("+") for k in self.layer_kinds]
+        self.num_kv_layers = sum("attention" in p for p in parts)
+        self.num_state_layers = sum("mamba" in p for p in parts)
+        self._has_moe = any("moe" in p for p in parts)
+        self.num_heads = int(cfg["num_attention_heads"])
+        # what an attention layer caches a token: K and V a kv head, or the
+        # model's own entry (one latent vector: a pool without a head axis)
+        entry = cfg.get("cache_entry") or {"layout": "kv"}
+        self.cache_layout = str(entry["layout"])
+        if self.cache_layout == "latent":
+            self.num_kv_heads, self.head_dim = 1, int(entry["width"])
+        else:
+            self.num_kv_heads = int(cfg.get("num_key_value_heads") or self.num_heads)
+            self.head_dim = int(cfg.get("head_dim") or int(cfg["hidden_size"]) // self.num_heads)
         self.vocab_size = int(cfg["vocab_size"])
         self.max_seq_len = int(max_seq_len)
         self.block_size = int(block_size)
@@ -208,7 +221,7 @@ class InferenceEngine:
             # the head count doesn't divide
             tp_axis = layout_table.layout.tp_axis
             tp_deg = int(mesh.shape.get(tp_axis, 1))
-            if tp_deg > 1 and self.num_kv_heads % tp_deg == 0:
+            if tp_deg > 1 and self.cache_layout == "kv" and self.num_kv_heads % tp_deg == 0:
                 self._page_sharding = NamedSharding(mesh, P(None, tp_axis, None, None))
             else:
                 self._page_sharding = self._repl
@@ -239,7 +252,7 @@ class InferenceEngine:
             num_blocks, self.block_size, self.num_kv_layers,
             self.num_kv_heads, self.head_dim, dtype=w_dtype,
             kv_dtype=kv_dtype, state_layers=self.num_state_layers,
-            state_spec=state_spec, state_slots=self.max_batch,
+            state_spec=state_spec, state_slots=self.max_batch, layout=self.cache_layout,
         )
         # donation keeps exactly one pool copy live on TPU; CPU's donation
         # path only warns, so gate it on the platform
@@ -501,9 +514,9 @@ class InferenceEngine:
         """Avals mirroring pool.device_state(): per-layer page arrays plus
         scale planes on a quantized pool — the ONE pytree every compiled
         step threads through (and donates)."""
-        shape = (self.pool.num_blocks, self.num_kv_heads, self.block_size, self.head_dim)
+        shape = self.pool.page_shape
         one = jax.ShapeDtypeStruct(shape, self.pool.dtype)
-        avals = {"k": [one] * self.num_kv_layers, "v": [one] * self.num_kv_layers}
+        avals = {"k": [one] * self.num_kv_layers, "v": [one] * len(self.pool.v_pages)}
         if self.pool.quantized:
             sc = jax.ShapeDtypeStruct(shape[:3], jnp.float32)
             avals["k_scale"] = [sc] * self.num_kv_layers
@@ -517,7 +530,7 @@ class InferenceEngine:
         """NamedShardings matching _state_avals: pages follow the kv-head
         TP split; scale planes share it (their head axis is axis 1 too)."""
         pages = [self._page_sharding] * self.num_kv_layers
-        sh = {"k": pages, "v": list(pages)}
+        sh = {"k": pages, "v": pages[:len(self.pool.v_pages)]}
         if self.pool.quantized:
             if self._page_sharding is not self._repl:
                 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -757,15 +770,21 @@ class InferenceEngine:
         self._mark_first_token()
         return out
 
-    def _count_page_blocks(self, span, frontiers):
-        """What the paged kernel's grid does with this call, on the span:
-        `page_blocks_grid` steps a layer (bucket rows x page blocks of the
-        table), of which `page_blocks_live` reach a page someone wrote (up
-        to each row's frontier, pad rows one) and the rest start no copy."""
-        live = paged_live_blocks(frontiers, self.block_size, self.max_pages)
-        _, blocks = paged_page_blocks(self.block_size, self.max_pages)
-        span.args["page_blocks_live"] = int(live.sum())
-        span.args["page_blocks_grid"] = int(live.size * blocks)
+    def _count_page_blocks(self, span, first, count, q_len: int = 1):
+        """What the paged kernel's grid does with ONE of its calls, added up
+        on the span: `page_blocks_grid` steps a layer (bucket rows x page
+        blocks of the table; over latent pages, x the row's query tiles), of
+        which `page_blocks_live` reach a page someone wrote (up to each row's
+        frontier, pad rows one) and the rest start no copy. Row i's queries
+        stand at `first[i]` .. `first[i] + count[i] - 1`, `q_len` slots a row."""
+        if self.cache_layout == "latent":
+            live = mla_live_blocks(first, count, q_len, self.num_heads, self.block_size, self.max_pages)
+            _, blocks = mla_page_blocks(self.block_size, self.max_pages)
+        else:
+            live = paged_live_blocks(first + count - 1, self.block_size, self.max_pages)
+            _, blocks = paged_page_blocks(self.block_size, self.max_pages)
+        span.args["page_blocks_live"] = span.args.get("page_blocks_live", 0) + int(live.sum())
+        span.args["page_blocks_grid"] = span.args.get("page_blocks_grid", 0) + int(live.size * blocks)
 
     def decode(
         self,
@@ -834,16 +853,16 @@ class InferenceEngine:
                 lens[:n] = np.asarray(seq_lens, np.int32)
                 for i, row in enumerate(page_rows):
                     bt[i] = self.pool.padded_table(row, self.max_pages)
-                frontiers = lens - 1
                 span.args["context"] = int(lens[:n].sum())
+                self._count_page_blocks(span, lens - 1, np.ones_like(lens))
                 if chunk is not None:
                     tok[B:B + take] = np.asarray(ids, np.int32)
                     pos[B:B + take] = start + np.arange(take, dtype=np.int32)  # pad slots stay at 0
                     chunk_bt = np.asarray([self.pool.padded_table(pages, self.max_pages)], np.int32)
                     last = np.append(np.arange(B, dtype=np.int32), np.int32(B + take - 1))
-                    frontiers = np.append(frontiers, np.int32(start + take - 1))
                     span.args["context"] += start + take
-                self._count_page_blocks(span, frontiers)
+                    span.args["chunk_context"] = start
+                    self._count_page_blocks(span, np.asarray([start]), np.asarray([take]), C)
                 slots = self._slots_of(page_rows, B)
                 if slots:
                     span.args["state_slots"] = self.pool.state_slots_used()
@@ -909,7 +928,7 @@ class InferenceEngine:
                 for i, row in enumerate(page_rows):
                     bt[i] = self.pool.padded_table(row, self.max_pages)
                 span.args["context"] = int(pos.max(axis=1)[:n].sum()) + n
-                self._count_page_blocks(span, pos.max(axis=1))
+                self._count_page_blocks(span, pos[:, 0], pos.max(axis=1) - pos[:, 0] + 1, q_len)
             ex = self._get_compiled("extend", (B, q_len))
             with RecordEvent("engine.extend.dispatch"):
                 logits, state = ex(

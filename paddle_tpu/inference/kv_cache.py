@@ -25,6 +25,17 @@ sequence through its first page (`state_slot`) and released when that page
 goes back to the free list. A recurrent state holds its whole prefix in one
 value, so such a pool has no prefix reuse and no page migration.
 
+A pool may instead keep LATENT pages (`layout="latent"`): ONE array a layer,
+`[num_blocks, block_size, width]`, one vector a token (multi-head latent
+attention caches the compressed key/value and the shared rotary key, and
+every head reads that one vector: there is no kv-head axis and no V array).
+A slot is the entry's width rounded up to whole lane tiles of 128 (576 -> 640,
+the tail zeros): at 576 XLA's TPU backend lays the array out with the PAGE
+axis minor, and copies a layer's whole pool to the other layout and back
+around every write and every kernel call.
+The allocator, the ref counts and the prefix index are page-granular and
+the same; int8 storage and page migration are refused for such a pool.
+
 Round 17 — prefix sharing + int8 storage:
 
 - Pages are REF-COUNTED. A page's KV depends on its whole token prefix, so
@@ -86,6 +97,7 @@ __all__ = [
 ]
 
 TRASH_PAGE = 0  # reserved: block-table padding + padded-position writes
+_LANES = 128  # a latent slot is whole lane tiles: XLA's TPU layouts keep a minor axis of those minor
 
 
 class PoolExhausted(RuntimeError):
@@ -174,6 +186,11 @@ class PagedCacheView:
     row, or over the whole array in slot order when the step holds a third of
     the slots or more (`slot_major`, with `to_slots` / `from_slots`).
     `moe_counts` adds up what the expert layers report of one step.
+
+    A LATENT pool's view has its one array a layer in `k_pages` (`[N, bs, W]`,
+    no head axis) and no `v_pages`: `latent` is True, `write` / `write_chunk`
+    take the entries `[B, S, width]` alone (zeros fill a slot's tail up to W),
+    and the model reads `k_pages[idx]`.
     """
 
     def __init__(self, k_pages: Sequence, v_pages: Sequence, block_tables,
@@ -183,6 +200,7 @@ class PagedCacheView:
                  chunk_table=None):
         self.k_pages = list(k_pages)
         self.v_pages = list(v_pages)
+        self.latent = bool(self.k_pages) and self.k_pages[0].ndim == 3
         self.k_scales = list(k_scales) if k_scales is not None else None
         self.v_scales = list(v_scales) if v_scales is not None else None
         self.block_tables = jnp.asarray(block_tables, jnp.int32)
@@ -290,9 +308,18 @@ class PagedCacheView:
     def token_mask(self, b: int, s: int, positions):
         """[B, S] bool: the tokens of this step that are real. A prefill
         (positions None) pads its row past `seq_lens`; a decode or extend row
-        is real when it holds a page (`write_mask` narrows it further)."""
+        is real when it holds a page (`write_mask` narrows it further); a
+        chunk step's one row holds the decode rows and then the chunk."""
         if positions is None:
             return jnp.arange(s, dtype=jnp.int32)[None, :] < self.seq_lens.reshape(b, 1)
+        if self.chunk_table is not None:
+            # one row of tokens: the n decode rows, then the chunk, whose pad
+            # slots stand at position 0 behind its first token
+            n = self.block_tables.shape[0]
+            raw = getattr(positions, "value", positions)
+            chunk_pos = jnp.asarray(raw, jnp.int32).reshape(-1)[n:]
+            chunk = (jnp.arange(s - n) == 0) | (chunk_pos != 0)
+            return jnp.concatenate([self.block_tables[:, 0] != TRASH_PAGE, chunk])[None]
         real = jnp.broadcast_to((self.block_tables[:, 0] != TRASH_PAGE)[:, None], (b, s))
         if self.write_mask is not None:
             real = real & jnp.asarray(self.write_mask, bool)
@@ -305,10 +332,11 @@ class PagedCacheView:
                          jnp.ones((), jnp.int32)])
         self.moe_counts = new if self.moe_counts is None else self.moe_counts + new
 
-    def write(self, idx: int, k_new, v_new, positions=None) -> None:
+    def write(self, idx: int, k_new, v_new=None, positions=None) -> None:
         """Put new K/V into layer `idx`'s pages, in place.
 
-        k_new/v_new [B, S, Hkv, D]; positions [B, S] int32 absolute token
+        k_new/v_new [B, S, Hkv, D] (a latent pool: the entries [B, S, W] as
+        `k_new`, no `v_new`); positions [B, S] int32 absolute token
         positions, or None for a prefill, whose tokens sit at 0..S-1.
         Position p of row b lands in page block_tables[b, p//bs] slot p % bs.
         Pages are [N, Hkv, bs, D] and neither form below leaves XLA's TPU
@@ -322,14 +350,15 @@ class PagedCacheView:
           costs the chip about 70 ns, so this is for steps of few tokens.
           write_mask=False positions are redirected to the trash page, and
           pad rows all land on the trash page's slot 0: the indices are NOT
-          unique.
+          unique. A latent pool's pages [N, bs, W] index page and slot, the
+          window the whole [W] entry.
         - A prefill fills whole pages from each row's first: the tokens are
           cut into pages ([B, S/bs, Hkv, bs, D], zeros past S) and scattered
           along the page axis alone, 32 KB contiguous an update. Slots past
           the prompt in its last page, and the table's padding (the trash
           page), take what the bucket's padding computed: nobody reads them.
         """
-        b, s, hkv = k_new.shape[:3]
+        b, s = k_new.shape[:2]
         bs = self.block_size
         if positions is None:
             if self.write_mask is not None:
@@ -340,15 +369,19 @@ class PagedCacheView:
             pages = jnp.take_along_axis(self.block_tables, positions // bs, axis=1)
             if self.write_mask is not None:
                 pages = jnp.where(jnp.asarray(self.write_mask, bool), pages, TRASH_PAGE)
-            at = (pages[..., None], jnp.arange(hkv, dtype=jnp.int32), (positions % bs)[..., None])
+            if self.latent:
+                at = (pages, positions % bs)
+            else:
+                at = (pages[..., None], jnp.arange(k_new.shape[2], dtype=jnp.int32), (positions % bs)[..., None])
 
             def put(pool, new):
                 return pool.at[at].set(new)
 
         self._put(idx, k_new, v_new, put)
 
-    def write_chunk(self, idx: int, k_new, v_new, first_position) -> None:
-        """Put the chunk's K/V [1, C, Hkv, D] into layer `idx`'s pages: the
+    def write_chunk(self, idx: int, k_new, v_new=None, first_position=None) -> None:
+        """Put the chunk's K/V [1, C, Hkv, D] (a latent pool: its entries
+        [1, C, W] as `k_new`) into layer `idx`'s pages: the
         prefill's whole-page write, from the page of `first_position` (a
         traced scalar, a multiple of the page size: a chunk starts where a
         shared prefix or an earlier chunk ended, on a page's edge) of
@@ -364,14 +397,15 @@ class PagedCacheView:
             lax.dynamic_slice_in_dim(table, first, n, axis=1), 1, c))
 
     def _whole_pages(self, pages, b: int, s: int):
-        """put(pool, new) that cuts `new` [B, S, Hkv, ...] into the pages
-        `pages` [B, ceil(S / bs)] names (zeros past S) and scatters them along
-        the page axis alone."""
-        bs, n = self.block_size, pages.shape[1]
+        """put(pool, new) that cuts `new` [B, S, Hkv, ...] (latent: [B, S, W])
+        into the pages `pages` [B, ceil(S / bs)] names (zeros past S) and
+        scatters them along the page axis alone."""
+        bs, n, latent = self.block_size, pages.shape[1], self.latent
 
         def put(pool, new):
             new = jnp.pad(new, [(0, 0), (0, n * bs - s)] + [(0, 0)] * (new.ndim - 2))
-            return pool.at[pages].set(jnp.swapaxes(new.reshape(b, n, bs, *new.shape[2:]), 2, 3))
+            cut = new.reshape(b, n, bs, *new.shape[2:])
+            return pool.at[pages].set(cut if latent else jnp.swapaxes(cut, 2, 3))
 
         return put
 
@@ -390,20 +424,29 @@ class PagedCacheView:
             v_new = quantize_absmax(v_new, v_sc[..., None])
             self.k_scales[idx] = put(self.k_scales[idx], k_sc)
             self.v_scales[idx] = put(self.v_scales[idx], v_sc)
+        if self.latent:  # the slot's tail past the entry: zeros
+            tail = self.k_pages[idx].shape[-1] - k_new.shape[-1]
+            k_new = jnp.pad(k_new, [(0, 0)] * (k_new.ndim - 1) + [(0, tail)])
         self.k_pages[idx] = put(self.k_pages[idx], k_new)
-        self.v_pages[idx] = put(self.v_pages[idx], v_new)
+        if not self.latent:
+            self.v_pages[idx] = put(self.v_pages[idx], v_new)
 
 
 class BlockPool:
     """Preallocated paged KV pool + host free-list allocator.
 
-    Device layout: per layer, k/v pages of shape
+    Device layout, `layout="kv"` (the default): per layer, k/v pages of shape
     [num_blocks, num_kv_heads, block_size, head_dim] (kv-head major: the
     paged kernel fetches a page's whole (num_kv_heads, block_size,
     head_dim) slab, one contiguous read, eight pages of 16 a grid step).
+    `layout="latent"`: per layer ONE array [num_blocks, block_size, W]
+    (`head_dim` the entry's width, W that in whole lane tiles of 128;
+    `num_kv_heads` must be 1: every query head reads the one vector a
+    token), kept in `k_pages`; `v_pages` is empty.
     `num_blocks` INCLUDES the reserved trash page 0; usable capacity is
     num_blocks - 1 pages.
-    `kv_dtype="int8"` stores int8 pages with f32 scale planes alongside.
+    `kv_dtype="int8"` stores int8 pages with f32 scale planes alongside (kv
+    layout only: a latent entry has no per-head absmax to scale by).
 
     `num_layers` counts the layers that keep K/V (a model's attention
     layers). `state_layers` recurrent layers, each a `state_spec` a sequence,
@@ -416,11 +459,21 @@ class BlockPool:
     def __init__(self, num_blocks: int, block_size: int, num_layers: int,
                  num_kv_heads: int, head_dim: int, dtype=jnp.float32,
                  kv_dtype: Optional[str] = None, state_layers: int = 0,
-                 state_spec: Optional[StateSpec] = None, state_slots: int = 0):
+                 state_spec: Optional[StateSpec] = None, state_slots: int = 0,
+                 layout: str = "kv"):
         if num_blocks < 2:
             raise ValueError("BlockPool needs >= 2 blocks (page 0 is reserved)")
         if kv_dtype not in (None, "int8"):
             raise ValueError(f"unsupported kv_dtype {kv_dtype!r} (int8 or None)")
+        if layout not in ("kv", "latent"):
+            raise ValueError(f"unsupported page layout {layout!r} (kv or latent)")
+        if layout == "latent" and kv_dtype is not None:
+            raise ValueError(
+                "a latent pool cannot store int8 pages: the quantizer scales each kv head's vector "
+                "of a slot by its absmax, and a latent entry (a normed latent and a rotary key in "
+                "one vector, read by every head) has no such axis")
+        if layout == "latent" and int(num_kv_heads) != 1:
+            raise ValueError("a latent pool keeps one vector a token: num_kv_heads must be 1")
         if state_layers and (state_spec is None or state_slots < 1):
             raise ValueError("a pool with recurrent layers needs their StateSpec and >= 1 slot")
         self.num_blocks = int(num_blocks)
@@ -429,11 +482,13 @@ class BlockPool:
         self.num_kv_heads = int(num_kv_heads)
         self.head_dim = int(head_dim)
         self.kv_dtype = kv_dtype
+        self.layout = layout
         self.compute_dtype = dtype
         self.dtype = jnp.int8 if kv_dtype == "int8" else dtype
-        shape = (self.num_blocks, self.num_kv_heads, self.block_size, self.head_dim)
+        shape = self.page_shape
         self.k_pages: List = [jnp.zeros(shape, self.dtype) for _ in range(self.num_layers)]
-        self.v_pages: List = [jnp.zeros(shape, self.dtype) for _ in range(self.num_layers)]
+        self.v_pages: List = [] if self.latent else [
+            jnp.zeros(shape, self.dtype) for _ in range(self.num_layers)]
         if kv_dtype == "int8":
             sshape = shape[:3]
             self.k_scales: Optional[List] = [
@@ -475,6 +530,22 @@ class BlockPool:
     def quantized(self) -> bool:
         return self.kv_dtype == "int8"
 
+    @property
+    def latent(self) -> bool:
+        return self.layout == "latent"
+
+    @property
+    def page_shape(self) -> Tuple[int, ...]:
+        """Shape of one layer's page array (every array of a layer has it)."""
+        if self.latent:
+            return (self.num_blocks, self.block_size, -(-self.head_dim // _LANES) * _LANES)
+        return (self.num_blocks, self.num_kv_heads, self.block_size, self.head_dim)
+
+    @property
+    def arrays_per_layer(self) -> int:
+        """Page arrays a layer keeps: K and V, or the one latent array."""
+        return 1 if self.latent else 2
+
     # ---- accounting ----
     def blocks_for_tokens(self, n_tokens: int) -> int:
         return max(1, math.ceil(n_tokens / self.block_size))
@@ -508,9 +579,11 @@ class BlockPool:
 
     def page_bytes(self) -> int:
         """Device bytes ONE page costs across all layers (K + V + scale
-        planes) — the bench's same-pool-bytes comparisons use this."""
+        planes, or the one latent array) — the bench's same-pool-bytes
+        comparisons use this."""
         slot = self.block_size * self.num_kv_heads
-        data = 2 * self.num_layers * slot * self.head_dim * jnp.dtype(self.dtype).itemsize
+        data = (self.arrays_per_layer * self.num_layers * slot * self.page_shape[-1]
+                * jnp.dtype(self.dtype).itemsize)
         scales = 0
         if self.quantized:
             scales = 2 * self.num_layers * slot * 4
@@ -777,7 +850,8 @@ class BlockPool:
         (new,) = self.alloc(1, owner=owner)
         for layer in range(self.num_layers):
             self.k_pages[layer] = self.k_pages[layer].at[new].set(self.k_pages[layer][page])
-            self.v_pages[layer] = self.v_pages[layer].at[new].set(self.v_pages[layer][page])
+            if not self.latent:
+                self.v_pages[layer] = self.v_pages[layer].at[new].set(self.v_pages[layer][page])
             if self.k_scales is not None:
                 self.k_scales[layer] = self.k_scales[layer].at[new].set(self.k_scales[layer][page])
                 self.v_scales[layer] = self.v_scales[layer].at[new].set(self.v_scales[layer][page])
@@ -832,7 +906,7 @@ class BlockPool:
 
     def adopt(self, k_pages: Sequence, v_pages: Sequence) -> None:
         """Install a step's updated page arrays back into the pool."""
-        if len(k_pages) != self.num_layers or len(v_pages) != self.num_layers:
+        if len(k_pages) != self.num_layers or len(v_pages) != len(self.v_pages):
             raise ValueError("page-array layer count does not match the pool")
         self.k_pages = list(k_pages)
         self.v_pages = list(v_pages)
@@ -862,6 +936,11 @@ def _refuse_recurrent(pool: BlockPool, what: str) -> None:
         raise ValueError(
             f"{what}: the pool holds recurrent-layer state, which pages do not carry — "
             "a sequence of such a model cannot migrate by its pages (recompute on the destination)")
+    if pool.latent:
+        raise ValueError(
+            f"{what}: the pool keeps latent pages, and the migration payload (K and V planes a kv "
+            "head, re-encoded by per-head absmax for an int8 destination) has no form for them — "
+            "recompute on the destination")
 
 
 def export_pages(pool: BlockPool, pages: Sequence[int]) -> Dict:

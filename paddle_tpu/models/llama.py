@@ -13,8 +13,9 @@ runs the normal causal attention; single-token decode writes the new K/V at
 `positions` and reads the whole context back through the Pallas paged
 flash-decode kernel (jnp reference off-TPU); a view with a `chunk_table`
 is the engine's chunk step, decode rows and a chunk of one prompt in one
-row of tokens (`_rows_and_chunk_attention`). The cache path is
-inference-only (no grad is taped through it).
+row of tokens. Which segment writes and reads what, in which order, is
+`cache_segments.attend_through_cache`'s (shared with the other decoders).
+The cache path is inference-only (no grad is taped through it).
 """
 from __future__ import annotations
 
@@ -108,67 +109,21 @@ class LlamaAttention(nn.Layer):
             return self.o_proj(out)
 
         # ---- serving cache mode (inference-only) ----
-        from ..ops.pallas import flash_decode_paged, flash_decode_paged_multi
+        from .cache_segments import attend_through_cache, kv_readers, positions_2d
 
+        idx = self.layer_idx
         max_pos = cache.block_tables.shape[1] * cache.block_size
-        pos2d = None  # a prefill: tokens at 0..S-1, for the rotation and for the write
-        if positions is not None:
-            raw_pos = positions.value if isinstance(positions, Tensor) else positions
-            pos2d = jnp.asarray(raw_pos, jnp.int32).reshape(b, -1)
+        pos2d = positions_2d(positions, b)  # None for a prefill: tokens at 0..S-1
         qr, kr = _rope(q.value, k.value, positions=pos2d, max_pos=max_pos)
-        if cache.chunk_table is not None:
-            out_t = Tensor(_rows_and_chunk_attention(cache, self.layer_idx, qr, kr, v.value, pos2d))
-            return self.o_proj(manip.reshape(out_t, [b, s, self.num_heads * self.head_dim]))
-        cache.write(self.layer_idx, kr, v.value, pos2d)
-        if positions is None:
-            # prefill: the context IS this call's k/v — normal causal
-            # attention; padded tail positions produce discarded rows (their
-            # queries only ever see real keys at or before themselves)
-            out_t = F.scaled_dot_product_attention(
+
+        def prefill():
+            return F.scaled_dot_product_attention(
                 Tensor(qr), Tensor(kr), v, is_causal=True, training=False
-            )
-        else:
-            kp, vp = cache.layer(self.layer_idx)
-            ks, vs = cache.scales(self.layer_idx)
-            if s == 1:
-                out = flash_decode_paged(
-                    qr[:, 0], kp, vp, cache.block_tables, cache.seq_lens,
-                    k_scales=ks, v_scales=vs,
-                )[:, None]  # [B, 1, H, D]
-            else:
-                # extend/verify: s > 1 explicit positions — every query
-                # reads the PAGED context up through its own position (the
-                # K/V for all s tokens was just written above), the
-                # speculative-verify / chunked-suffix-prefill layout
-                out = flash_decode_paged_multi(
-                    qr, kp, vp, cache.block_tables, pos2d,
-                    k_scales=ks, v_scales=vs,
-                )
-            out_t = Tensor(out)
-        out_t = manip.reshape(out_t, [b, s, self.num_heads * self.head_dim])
-        return self.o_proj(out_t)
+            ).value
 
-
-def _rows_and_chunk_attention(cache, idx, q, k, v, pos):
-    """Attention of the engine's chunk step: ONE row of tokens [1, n + C, ...],
-    the n decode rows' one token each and then C consecutive prompt tokens of
-    one more sequence (`cache.chunk_table` its pages). The projections around
-    this ran over all of them together; here each segment writes its K/V and
-    reads its own context through the paged kernel: the rows positioned and
-    one query each, the chunk by whole pages and as one row of C queries,
-    which sees what its sequence cached before it and itself causally."""
-    from ..ops.pallas import flash_decode_paged, flash_decode_paged_multi
-
-    n = cache.block_tables.shape[0]
-    cache.write(idx, k[0, :n, None], v[0, :n, None], pos[0, :n, None])
-    cache.write_chunk(idx, k[:, n:], v[:, n:], pos[0, n])
-    kp, vp = cache.layer(idx)
-    ks, vs = cache.scales(idx)
-    rows = flash_decode_paged(q[0, :n], kp, vp, cache.block_tables, cache.seq_lens,
-                              k_scales=ks, v_scales=vs)
-    chunk = flash_decode_paged_multi(q[:, n:], kp, vp, cache.chunk_table, pos[:, n:],
-                                     k_scales=ks, v_scales=vs)
-    return jnp.concatenate([rows[None], chunk], axis=1)  # [1, n + C, H, D]
+        out_t = Tensor(attend_through_cache(cache, idx, qr, (kr, v.value), pos2d, prefill=prefill,
+                                            **kv_readers(cache, idx)))
+        return self.o_proj(manip.reshape(out_t, [b, s, self.num_heads * self.head_dim]))
 
 
 class LlamaMLP(nn.Layer):
@@ -265,15 +220,9 @@ class LlamaForCausalLM(nn.Layer):
             )
             return loss, None
         if last_index is not None:
-            # gather ONE position per row before the LM head (prefill takes
-            # the prompt's true last token; skips the [B, S, V] logits), or
-            # several positions of the one row of a chunk step
-            idx = last_index.value if isinstance(last_index, Tensor) else last_index
-            idx = jnp.asarray(idx, jnp.int32).reshape(-1)
-            hv = h.value
-            if idx.shape[0] == 1 and hv.shape[0] != 1:
-                idx = jnp.broadcast_to(idx, (hv.shape[0],))
-            h = Tensor(jnp.take_along_axis(hv, idx[:, None, None], axis=1)[:, 0])
+            from .cache_segments import take_positions
+
+            h = Tensor(take_positions(h.value, last_index))
         return self.lm_head(h)
 
 

@@ -43,7 +43,8 @@ from ..core.apply import apply
 from ..core.tensor import Tensor
 from ..nn import functional as F
 from ..ops import manipulation as manip
-from ..ops import pallas as pk
+from .cache_segments import attend_through_cache, kv_readers, positions_2d, take_positions
+from .expert_share import route_topk, routed_experts
 
 __all__ = ["NemotronHForCausalLM", "NemotronHModel", "layer_kinds"]
 
@@ -213,67 +214,23 @@ class NemotronHAttention(nn.Layer):
         q = manip.reshape(self.q_proj(x), [b, s, self.num_heads, self.head_dim])
         k = manip.reshape(self.k_proj(x), [b, s, self.num_kv_heads, self.head_dim])
         v = manip.reshape(self.v_proj(x), [b, s, self.num_kv_heads, self.head_dim])
-        if cache is None or positions is None:
-            if cache is not None:
-                cache.write(self.layer_idx, k.value, v.value)
+        if cache is None:
             out = F.scaled_dot_product_attention(q, k, v, is_causal=True, training=self.training)
         else:
-            raw = positions.value if isinstance(positions, Tensor) else positions
-            pos2d = jnp.asarray(raw, jnp.int32).reshape(b, -1)
-            cache.write(self.layer_idx, k.value, v.value, pos2d)
-            kp, vp = cache.layer(self.layer_idx)
-            ks, vs = cache.scales(self.layer_idx)
-            if s == 1:
-                out = pk.flash_decode_paged(q.value[:, 0], kp, vp, cache.block_tables, cache.seq_lens,
-                                            k_scales=ks, v_scales=vs)[:, None]
-            else:
-                out = pk.flash_decode_paged_multi(q.value, kp, vp, cache.block_tables, pos2d,
-                                                  k_scales=ks, v_scales=vs)
-            out = Tensor(out)
+            idx = self.layer_idx
+
+            def prefill():
+                return F.scaled_dot_product_attention(q, k, v, is_causal=True, training=self.training).value
+
+            out = Tensor(attend_through_cache(cache, idx, q.value, (k.value, v.value),
+                                              positions_2d(positions, b), prefill=prefill,
+                                              **kv_readers(cache, idx)))
         return self.o_proj(manip.reshape(out, [b, s, self.num_heads * self.head_dim]))
 
 
 # ---------------------------------------------------------------------------
 # LatentMoE over the experts held
 # ---------------------------------------------------------------------------
-
-def route_topk(x, w_router, b_corr, top_k, scale):
-    """(chosen [T, k] int32, weights [T, k] float32): sigmoid scores in
-    float32; the correction bias steers the choice only; the weights are
-    normalised over all the chosen and scaled."""
-    s = jax.nn.sigmoid(jnp.dot(_f32(x), _f32(w_router), precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(s + _f32(b_corr), top_k)
-    picked = jnp.take_along_axis(s, chosen, axis=-1)
-    return chosen.astype(jnp.int32), scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
-
-
-def routed_experts(u, chosen, weights, w_up, w_down, first, valid=None):
-    """The held experts' part of the routed sum: u [T, latent], chosen and
-    weights [T, k] over ALL experts, w_up [count, latent, f] and w_down
-    [count, f, latent] the experts `first .. first + count - 1`. Pairs whose
-    expert is absent, or whose token is padding (`valid` [T] false), are
-    computed nowhere. Returns ([T, latent] float32, assignments computed,
-    held experts with at least one token)."""
-    t, k = chosen.shape
-    count = w_up.shape[0]
-    local = chosen - first
-    held = (local >= 0) & (local < count)
-    if valid is not None:
-        held &= jnp.asarray(valid, bool).reshape(t, 1)
-    dest, tile_group, live, sizes = pk.moe_group_layout(jnp.where(held, local, count).reshape(-1), count)
-    rows = pk.moe_padded_rows(t * k, count)
-    token = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)
-    # each padded row's token (the zero row T for padding), then one gather
-    row_token = jnp.full((rows,), t, jnp.int32).at[dest].set(token, mode="drop")
-    x_rows = jnp.concatenate([u, jnp.zeros((1, u.shape[1]), u.dtype)])[row_token]
-    mid = pk.moe_gmm(x_rows, w_up, tile_group, live, activation="relu2")
-    y_rows = pk.moe_gmm(mid, w_down, tile_group, live, out_dtype=jnp.float32)
-    # dead tiles are unwritten: read only rows an assignment owns
-    picked = jnp.take(y_rows, jnp.minimum(dest, rows - 1), axis=0).reshape(t, k, -1)
-    wts = jnp.where(held, weights, 0.0)
-    out = jnp.sum(jnp.where(held[..., None], picked, 0.0) * wts[..., None], axis=1)
-    return out, sizes.sum(), (sizes > 0).sum()
-
 
 def latent_moe(x, w_router, b_corr, w_fc1, w_fc2, w_up, w_down, w_sup, w_sdown, *,
                top_k, scale, first, valid=None):
@@ -411,11 +368,6 @@ class NemotronHForCausalLM(nn.Layer):
     def forward(self, input_ids, cache=None, positions=None, last_index=None):
         h = self.backbone(input_ids, cache=cache, positions=positions)
         if last_index is not None:
-            idx = last_index.value if isinstance(last_index, Tensor) else last_index
-            idx = jnp.asarray(idx, jnp.int32).reshape(-1)
-            hv = h.value
-            if idx.shape[0] == 1 and hv.shape[0] != 1:
-                idx = jnp.broadcast_to(idx, (hv.shape[0],))
-            h = Tensor(jnp.take_along_axis(hv, idx[:, None, None], axis=1)[:, 0])
+            h = Tensor(take_positions(h.value, last_index))
         return self.lm_head(h)
 

@@ -864,8 +864,10 @@ _DECODE_LANES = 128  # positions a grid step scores: one lane tile of logits
 def paged_decode_usable(q, k_pages) -> bool:
     """Kernel constraints: TPU platform (or interpret mode), head_dim <= 256
     and lane-aligned, page slots a multiple of the sublane. q [B, H, D];
-    k_pages [N, Hkv, bs, D]. Off-gate callers fall back to the jnp
-    reference — bitwise-equivalent masking/GQA semantics, XLA-gathered."""
+    k_pages [N, Hkv, bs, D], the K/V layout of a pool (a pool of latent
+    pages `[N, bs, W]` is read by `mla_paged_attention`, not here). Off-gate
+    callers fall back to the jnp reference — bitwise-equivalent masking/GQA
+    semantics, XLA-gathered."""
     if not _on_tpu():
         return False
     if q.ndim != 3 or k_pages.ndim != 4:
@@ -879,11 +881,11 @@ def paged_decode_usable(q, k_pages) -> bool:
     return hkv <= h and h % hkv == 0
 
 
-def paged_page_blocks(block_size, table_width):
+def paged_page_blocks(block_size, table_width, positions=_DECODE_LANES):
     """(pages a grid step of the paged kernel reads, page blocks a table of
-    `table_width` columns makes): a step scores one lane tile of positions
-    (8 pages of 16), or the whole of a narrower table."""
-    pages = min(max(1, _DECODE_LANES // block_size), table_width)
+    `table_width` columns makes): a step scores `positions` positions (one
+    lane tile: 8 pages of 16), or the whole of a narrower table."""
+    pages = min(max(1, positions // block_size), table_width)
     return pages, -(-table_width // pages)
 
 
@@ -1231,6 +1233,239 @@ def flash_decode_paged_multi(q, k_pages, v_pages, block_tables, q_positions,
 
 
 # ---------------------------------------------------------------------------
+# paged attention over LATENT pages (mla_paged_attn): multi-head latent
+# attention in its absorbed form
+# ---------------------------------------------------------------------------
+#
+# A latent (MLA) cache keeps ONE vector a token a layer: the normed
+# compressed key/value `c_kv` (value_width wide) followed by the rotated
+# shared key `k_r`. With the key up-projection absorbed into the query
+# (`q_nope W_UK`), every one of the H query heads scores against that one
+# vector, and the context is a weighted sum of its first `value_width`
+# columns (the value up-projection `W_UV` comes after, outside). So a page
+# [bs, W] is read ONCE and serves as key and as value for all heads.
+#
+# Pool layout: pages are [N, bs, W] (no kv-head axis; W whole lane tiles, the
+# entry in its first columns and zeros behind, the query padded alike so that
+# the tail adds nothing to a score). The grid is (rows,
+# query tiles, page blocks): a query tile is TQ consecutive queries of a row,
+# all heads, as [TQ * H, W] query rows (query-major: row r is query r // H,
+# head r % H); a page block is P pages (P * bs = `_MLA_POSITIONS` positions). One query a
+# row (decode), a chunk of a prompt and `extend` are the same call: a row's
+# queries stand at CONSECUTIVE positions from its first, `count` of them
+# real, the rest (pad slots, sublane padding) at position 0. Each query tile
+# has its own frontier (its last real query's position): page blocks past it
+# compute nothing and start no copy, exactly as `paged_attn`'s do, so a
+# chunk's early tiles do not walk the blocks only its late tiles see.
+
+# A grid step scores `_MLA_TILE_ROWS` query rows (queries x heads) against
+# `_MLA_POSITIONS` cached positions (32 pages of 16). On a v5e at 128 heads,
+# a 128-query chunk over 2,048 / 6,144 cached tokens took 1.51 / 3.46 ms a
+# call at 1024 rows x 128 positions, 1.01 / 1.87 at 1024 x 512 and 0.78 /
+# 1.62 at 2048 x 512 (48% and 70% of the MXU's peak): the accumulator's
+# rescale is paid once a step whatever the step's width, and a page is read
+# once a query tile. Sixteen rows of one query (6 of them live, 24k cached
+# tokens) took 0.64 -> 0.54 ms: there a page's copy, 20 KB at about 0.25 us,
+# sets the time. 25 MB of VMEM at the published widths.
+_MLA_TILE_ROWS = 2048
+_MLA_POSITIONS = 512
+
+
+def mla_page_blocks(block_size, table_width):
+    """`paged_page_blocks` of the latent kernel: (pages a grid step reads,
+    page blocks a table makes)."""
+    return paged_page_blocks(block_size, table_width, _MLA_POSITIONS)
+
+
+def mla_query_tile(heads: int, q_len: int) -> int:
+    """Queries of a row a grid step of the latent kernel holds: whole
+    sublane tiles of query rows (16 for bf16), about `_MLA_TILE_ROWS` rows,
+    never more queries than the call has (rounded up to the sublane unit)."""
+    unit = 16 // math.gcd(heads, 16)
+    return unit * max(1, min(-(-q_len // unit), _MLA_TILE_ROWS // (unit * heads)))
+
+
+def mla_live_blocks(first, count, q_len, heads, block_size, table_width):
+    """[B, tiles] page blocks each query tile of the latent kernel reads: up
+    to the position of the tile's last real query (`first + count - 1` at
+    most), one for a tile of padding. numpy or jax `first` / `count` [B]
+    alike (the serving engine counts with this too)."""
+    tq = mla_query_tile(heads, q_len)
+    tiles = -(-q_len // tq)
+    pages, blocks = mla_page_blocks(block_size, table_width)
+    start = np.arange(tiles, dtype=np.int32)[None, :] * tq  # each tile's first query
+    count = count[:, None]
+    frontier = first[:, None] + count.clip(None, start + tq) - 1
+    frontier = frontier * (count > start)  # a tile of padding stands at position 0
+    return (frontier // (pages * block_size) + 1).clip(1, blocks)
+
+
+def mla_paged_reference(q, pages, block_tables, q_positions, value_width, sm_scale=None):
+    """jnp oracle for the latent paged kernel (and the off-TPU path). q
+    [B, Q, H, W] absorbed queries; pages [N, bs, W]; query j of row b attends
+    to every cached position <= q_positions[b, j]. f32 logits, probabilities
+    cast to the storage dtype before the value product. Returns
+    [B, Q, H, value_width]."""
+    w = q.shape[-1]
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(w)
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    q_positions = jnp.asarray(q_positions, jnp.int32)
+
+    def one(qb, bt, qp):
+        kg = pages[bt][..., :w].reshape(-1, w)  # [M * bs, entry]
+        logits = jnp.einsum("qhw,sw->qhs", qb, kg, preferred_element_type=jnp.float32) * scale
+        pos = jnp.arange(kg.shape[0], dtype=jnp.int32)
+        logits = jnp.where(pos[None, None, :] <= qp[:, None, None], logits, -1e30)
+        p = jax.nn.softmax(logits, axis=-1).astype(kg.dtype)
+        return jnp.einsum("qhs,sv->qhv", p, kg[:, :value_width],
+                          preferred_element_type=jnp.float32).astype(qb.dtype)
+
+    return jax.vmap(one)(q, block_tables, q_positions)
+
+
+def _mla_paged_kernel(pages, width, heads, tq, tiles, value_width, scale):
+    rows = tq * heads
+
+    def kernel(bt_ref, qpos_ref, live_ref, q_ref, *rest):
+        page_refs, (o_ref, m_scr, l_scr, acc_scr) = rest[:pages], rest[pages:]
+        b = pl.program_id(0)
+        t = pl.program_id(1)
+        i = pl.program_id(2)
+
+        @pl.when(i == 0)
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, -1e30)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        @pl.when(i < live_ref[b * tiles + t])
+        def _block():
+            qb = q_ref[...]  # [rows, W], storage dtype
+            kb = jnp.concatenate([r[...] for r in page_refs], axis=0)  # [width, W]
+            logits = _dot_nt(qb, kb) * scale  # [rows, width] f32
+            row = lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+            pos = i * width + lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+            qi = t * tq + lax.div(row, jnp.int32(heads))
+            frontier = jnp.where(qi < qpos_ref[b, 1], qpos_ref[b, 0] + qi, 0)
+            logits = jnp.where(pos <= frontier, logits, -1e30)
+            m_prev = m_scr[...]
+            m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+            p = jnp.exp(logits - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            # the page's first columns are the value: no second read
+            acc_scr[...] = acc_scr[...] * alpha + _dot_nn(p.astype(kb.dtype), kb[:, :value_width])
+            m_scr[...] = m_new
+
+        @pl.when(i == pl.num_programs(2) - 1)
+        def _emit():
+            o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+    return kernel
+
+
+def _mla_paged_impl(q, pages_arr, block_tables, q_positions, value_width, sm_scale):
+    n, bs, w = pages_arr.shape
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    q = jnp.pad(q, ((0, 0),) * 3 + ((0, w - q.shape[-1]),))  # zeros against the slot's tail
+    b, qn, h, _ = q.shape
+    m = block_tables.shape[1]
+    pages, blocks = mla_page_blocks(bs, m)
+    block_tables = jnp.pad(block_tables, ((0, 0), (0, blocks * pages - m)))
+    tq = mla_query_tile(h, qn)
+    tiles = -(-qn // tq)
+    rows = tq * h
+    # consecutive positions a row: (first, count); pad slots carry position 0
+    first = q_positions[:, 0]
+    count = jnp.max(q_positions, axis=1) - first + 1
+    live = mla_live_blocks(first, count, qn, h, bs, m).astype(jnp.int32).reshape(-1)
+    qr = jnp.pad(q, ((0, 0), (0, tiles * tq - qn), (0, 0), (0, 0))).reshape(b, tiles * rows, w)
+
+    def page_spec(j):
+        def index(bi, ti, pi, bt, qp, lv):
+            col = jnp.minimum(pi, lv[bi * tiles + ti] - 1) * pages + j
+            return (bt[bi, col], 0, 0)
+
+        return pl.BlockSpec((None, bs, w), index)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # block table, (first, count) a row, live blocks a tile
+        grid=(b, tiles, blocks),
+        in_specs=[pl.BlockSpec((None, rows, w), lambda bi, ti, pi, *_: (bi, ti, 0))]
+        + [page_spec(j) for j in range(pages)],
+        out_specs=pl.BlockSpec((None, rows, value_width), lambda bi, ti, pi, *_: (bi, ti, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, value_width), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        _mla_paged_kernel(pages, pages * bs, h, tq, tiles, value_width, scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, tiles * rows, value_width), q.dtype),
+        # the page axis revisits the tile's accumulator and out block: sequential
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=_INTERPRET,
+        name="mla_paged_attn",
+    )(block_tables, jnp.stack([first, count], axis=1), live, qr, *(pages * [pages_arr]))
+    return out.reshape(b, tiles * tq, h, value_width)[:, :qn]
+
+
+@functools.partial(jax.jit, static_argnames=("value_width", "sm_scale"))
+def _mla_paged_jit(q, pages, block_tables, q_positions, value_width, sm_scale=None):
+    return _mla_paged_impl(q, pages, block_tables, q_positions, value_width, sm_scale)
+
+
+def mla_paged_usable(q, pages, value_width) -> bool:
+    """Kernel constraints: TPU platform (or interpret mode), page slots a
+    multiple of the sublane, the value a lane-aligned prefix of the entry.
+    q [B, Q, H, entry]; pages [N, bs, W], W >= entry."""
+    if not _on_tpu() or q.ndim != 4 or pages.ndim != 3:
+        return False
+    return (pages.shape[1] % _DECODE_SUBLANE == 0 and 0 < value_width <= q.shape[-1]
+            and (value_width % _DECODE_LANES == 0 or value_width == pages.shape[2]))
+
+
+def mla_paged_attention(q, pages, block_tables, q_positions, value_width, sm_scale=None):
+    """Absorbed multi-head latent attention over a paged latent cache.
+
+    q            [B, Q, H, E]  — Q absorbed queries a row (`q_nope W_UK` then
+                                 the rotated `q_rope`), every head against
+                                 the ONE cached vector a position
+    pages        [N, bs, W]    — the pool's latent pages (one model layer):
+                                 `c_kv` then `k_r` in a slot's first E columns
+                                 (W >= E: whole lane tiles, zeros behind); the
+                                 first `value_width` columns are also the value
+    block_tables [B, M] int32  — page indices, padded with the reserved page 0
+    q_positions  [B, Q] int32  — each query's cache position, CONSECUTIVE
+                                 from the row's first, pad slots 0; query j
+                                 attends to positions <= its own (the entries
+                                 of all Q tokens already written)
+
+    Returns the context in the latent, [B, Q, H, value_width] (the value
+    up-projection comes after). One query a row (decode: q_positions =
+    seq_lens - 1), a prompt's chunk and `extend` are this one call. Dispatches
+    the Pallas kernel `mla_paged_attn` on TPU (or under interpret mode), else
+    the jnp reference."""
+    if q.ndim != 4 or pages.ndim != 3 or q.shape[-1] > pages.shape[2]:
+        raise ValueError(f"mla_paged_attention: q {q.shape} does not fit latent pages {pages.shape} "
+                         "([B, Q, H, E] against [N, bs, W >= E])")
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    q_positions = jnp.asarray(q_positions, jnp.int32)
+    if q_positions.shape != q.shape[:2]:
+        raise ValueError(f"mla_paged_attention: q_positions {q_positions.shape} must match q's "
+                         f"[B, Q] {q.shape[:2]}")
+    if mla_paged_usable(q, pages, value_width):
+        with jax.enable_x64(False):
+            return _mla_paged_jit(q, pages, block_tables, q_positions, value_width, sm_scale)
+    return mla_paged_reference(q, pages, block_tables, q_positions, value_width, sm_scale)
+
+
+# ---------------------------------------------------------------------------
 # grouped matmul over the experts a mixture-of-experts layer HOLDS (moe_gmm)
 # ---------------------------------------------------------------------------
 #
@@ -1296,16 +1531,22 @@ def _moe_tile_n(k: int, n: int, itemsize: int) -> int:
     return max(fits) if fits else _DECODE_LANES
 
 
+_MOE_ACTIVATIONS = {
+    None: lambda acc: acc,
+    "relu2": lambda acc: jnp.square(jnp.maximum(acc, 0.0)),
+    "silu": jax.nn.silu,
+}
+
+
 def _apply_moe_activation(acc, activation):
-    if activation == "relu2":
-        return jnp.square(jnp.maximum(acc, 0.0))
-    if activation is None:
-        return acc
-    raise ValueError(f"moe_gmm: unknown activation {activation!r}")
+    if activation not in _MOE_ACTIVATIONS:
+        raise ValueError(f"moe_gmm: unknown activation {activation!r} "
+                         f"(known: {sorted(a for a in _MOE_ACTIVATIONS if a)} or None)")
+    return _MOE_ACTIVATIONS[activation](acc)
 
 
 def moe_gmm_reference(x_rows, w, tile_group, live, activation=None, out_dtype=None,
-                      tile_m: int = MOE_TILE_M):
+                      tile_m: int = MOE_TILE_M, w_gate=None):
     """jnp oracle for the grouped matmul (and the off-TPU path): each tile
     of rows against its group's matrix, f32 accumulation; dead tiles give
     zeros (the kernel leaves them unwritten: nothing may read them)."""
@@ -1313,14 +1554,19 @@ def moe_gmm_reference(x_rows, w, tile_group, live, activation=None, out_dtype=No
     tiles = x_rows.shape[0] // tile_m
     xt = x_rows.reshape(tiles, tile_m, -1)
     acc = jnp.einsum("tmk,tkn->tmn", xt, w[tile_group], preferred_element_type=jnp.float32)
-    acc = _apply_moe_activation(acc, activation)
+    if w_gate is None:
+        acc = _apply_moe_activation(acc, activation)
+    else:
+        gate = jnp.einsum("tmk,tkn->tmn", xt, w_gate[tile_group], preferred_element_type=jnp.float32)
+        acc = _apply_moe_activation(gate, activation) * acc
     alive = jnp.arange(tiles)[:, None, None] < live[0]
     return jnp.where(alive, acc, 0.0).astype(out_dtype).reshape(tiles * tile_m, -1)
 
 
-def _moe_gmm_impl(x_rows, w, tile_group, live, activation, out_dtype, tile_m):
+def _moe_gmm_impl(x_rows, w, tile_group, live, activation, out_dtype, tile_m, w_gate=None):
     rows, k = x_rows.shape
     _, _, n = w.shape
+    gated = w_gate is not None  # two weight blocks a step then, each of the one budget
     tn = _moe_tile_n(k, n, jnp.dtype(w.dtype).itemsize)
 
     def kernel(tg_ref, live_ref, x_ref, w_ref, o_ref):
@@ -1329,21 +1575,28 @@ def _moe_gmm_impl(x_rows, w, tile_group, live, activation, out_dtype, tile_m):
             acc = _dot_nn(x_ref[...], w_ref[...])
             o_ref[...] = _apply_moe_activation(acc, activation).astype(o_ref.dtype)
 
+    def gated_kernel(tg_ref, live_ref, x_ref, g_ref, w_ref, o_ref):
+        @pl.when(pl.program_id(1) < live_ref[0])
+        def _tile():
+            x = x_ref[...]
+            gate = _apply_moe_activation(_dot_nn(x, g_ref[...]), activation)
+            o_ref[...] = (gate * _dot_nn(x, w_ref[...])).astype(o_ref.dtype)
+
     def tile(i, lv):
         # a dead step names the last live tile again: no copy starts
         return jnp.maximum(jnp.minimum(i, lv[0] - 1), 0)
 
+    w_spec = pl.BlockSpec((None, k, tn), lambda j, i, tg, lv: (tg[tile(i, lv)], 0, j))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # the group of each tile, the live tile count
         grid=(n // tn, rows // tile_m),
         in_specs=[
             pl.BlockSpec((tile_m, k), lambda j, i, tg, lv: (tile(i, lv), 0)),
-            pl.BlockSpec((None, k, tn), lambda j, i, tg, lv: (tg[tile(i, lv)], 0, j)),
-        ],
+        ] + (2 if gated else 1) * [w_spec],
         out_specs=pl.BlockSpec((tile_m, tn), lambda j, i, tg, lv: (tile(i, lv), j)),
     )
     return pl.pallas_call(
-        kernel,
+        gated_kernel if gated else kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, n), out_dtype),
         # a dead step REVISITS the last live tile's out block: sequential
@@ -1353,34 +1606,39 @@ def _moe_gmm_impl(x_rows, w, tile_group, live, activation, out_dtype, tile_m):
         ),
         interpret=_INTERPRET,
         name="moe_gmm",
-    )(tile_group, live, x_rows, w)
+    )(tile_group, live, x_rows, *((w_gate, w) if gated else (w,)))
 
 
 @functools.partial(jax.jit, static_argnames=("activation", "out_dtype", "tile_m"))
 def _moe_gmm_jit(x_rows, w, tile_group, live, activation=None, out_dtype=None,
-                 tile_m=MOE_TILE_M):
-    return _moe_gmm_impl(x_rows, w, tile_group, live, activation, out_dtype, tile_m)
+                 tile_m=MOE_TILE_M, w_gate=None):
+    return _moe_gmm_impl(x_rows, w, tile_group, live, activation, out_dtype, tile_m, w_gate)
 
 
 def moe_gmm(x_rows, w, tile_group, live, activation=None, out_dtype=None,
-            tile_m: int = MOE_TILE_M):
+            tile_m: int = MOE_TILE_M, w_gate=None):
     """Grouped matmul over the experts held: rows `x_rows` [R, K] in the
     layout of `moe_group_layout` (R a multiple of `tile_m`, every tile one
     group's rows), `w` [groups, K, N] one matrix a group, `tile_group`
     [R / tile_m] and `live` [1] from the layout. Returns [R, N] in
-    `out_dtype` (default: x's), `activation` ("relu2" or None) applied to
-    the f32 accumulator. Rows of dead tiles are NOT written on the chip.
+    `out_dtype` (default: x's), `activation` ("relu2", "silu" or None)
+    applied to the f32 accumulator. With `w_gate` [groups, K, N] the product
+    is GATED: `activation(x W_gate) * (x W)`, both matrices of a group read in
+    the one step. Rows of dead tiles are NOT written on the chip.
 
     Dispatches the Pallas kernel `moe_gmm` on TPU (or under interpret
     mode), else the jnp reference."""
     if x_rows.ndim != 2 or w.ndim != 3 or x_rows.shape[1] != w.shape[1]:
         raise ValueError(f"moe_gmm: x_rows {x_rows.shape} does not fit w {w.shape} ([groups, K, N])")
+    if w_gate is not None and w_gate.shape != w.shape:
+        raise ValueError(f"moe_gmm: w_gate {w_gate.shape} must have w's shape {w.shape}")
     if x_rows.shape[0] % tile_m or tile_group.shape[0] != x_rows.shape[0] // tile_m:
         raise ValueError(f"moe_gmm: {x_rows.shape[0]} rows are not {tile_group.shape[0]} tiles of {tile_m}")
     out_dtype = jnp.dtype(out_dtype or x_rows.dtype)
     if _on_tpu():
+        gate = {} if w_gate is None else {"w_gate": w_gate}
         with jax.enable_x64(False):
             return _moe_gmm_jit(x_rows, w, jnp.asarray(tile_group, jnp.int32),
                                 jnp.asarray(live, jnp.int32), activation=activation,
-                                out_dtype=out_dtype, tile_m=tile_m)
-    return moe_gmm_reference(x_rows, w, tile_group, live, activation, out_dtype, tile_m)
+                                out_dtype=out_dtype, tile_m=tile_m, **gate)
+    return moe_gmm_reference(x_rows, w, tile_group, live, activation, out_dtype, tile_m, w_gate)

@@ -216,29 +216,20 @@ def test_kv_write_is_in_place(one_chip, n, hkv, b, s, m, how, pool_dtype):
     assert compiled.memory_analysis().temp_size_in_bytes < 24 << 20   # a copy of the bf16 pool is 134 MB
 
 
-@pytest.mark.parametrize("program, size, scatters", [
-    ("decode", 32, 4), ("prefill", 512, 4), ("extend", (4, 4), 4),
-    ("chunk", 32, 8),   # the rows' positioned write and the chunk's whole pages, K and V, a layer
-], ids=["decode_b32", "prefill_s512", "extend_4x4", "chunk_b32_c128"])
-def test_engine_program_holds_no_copy_of_the_pool(one_chip, monkeypatch, program, size, scatters):
-    """The whole program: the engine's 32-row decode, 512-token prefill,
-    (4, 4) extend and chunk step (32 rows and 128 prompt tokens) of a 2-layer
-    decoder at Mistral widths (no weight and no page exists: model and engine
-    are built under `jax.eval_shape`), state donated as on the chip. The chunk
-    step's two paged-attention calls a layer keep the kernel's name."""
+def _described_engine(one_chip, monkeypatch, make_model, **engine_kw):
+    """An InferenceEngine over a model whose leaves are bf16 shapes (no weight
+    and no page exists: model and engine are built under `jax.eval_shape`),
+    its programs lowered for the described chip with the state donated."""
     from paddle_tpu.inference.engine import InferenceEngine
-    from paddle_tpu.models.llama import LlamaForCausalLM
 
     box = {}
 
     def construct():
-        model = LlamaForCausalLM(vocab_size=32000, hidden_size=4096, num_hidden_layers=2,
-                                 num_attention_heads=32, num_key_value_heads=8,
-                                 intermediate_size=14336, rms_norm_eps=1e-5)
+        model = make_model()
         model.eval()
         for t in model.state_dict().values():
             t._value = jax.ShapeDtypeStruct(t.shape, BF16)
-        box["engine"] = InferenceEngine(model, max_seq_len=1024, block_size=16, num_blocks=4097, max_batch=32)
+        box["engine"] = InferenceEngine(model, **engine_kw)
         return 0
 
     jax.eval_shape(construct)
@@ -256,16 +247,111 @@ def test_engine_program_holds_no_copy_of_the_pool(one_chip, monkeypatch, program
 
     monkeypatch.setattr(engine, "_donate", True)
     monkeypatch.setattr(engine, "_jit", lambda fn, n_args: ForTheChip(jit(fn, n_args)))
+    return engine
+
+
+def _kernels_of(text):
+    """Instruction names of the program's Pallas kernels, version suffix dropped."""
+    return [re.sub(r"\.\d+$", "", k) for k in
+            re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"" + KERNEL + "\"", text)]
+
+
+@pytest.mark.parametrize("program, size, scatters", [
+    ("decode", 32, 4), ("prefill", 512, 4), ("extend", (4, 4), 4),
+    ("chunk", 32, 8),   # the rows' positioned write and the chunk's whole pages, K and V, a layer
+], ids=["decode_b32", "prefill_s512", "extend_4x4", "chunk_b32_c128"])
+def test_engine_program_holds_no_copy_of_the_pool(one_chip, monkeypatch, program, size, scatters):
+    """The whole program: the engine's 32-row decode, 512-token prefill,
+    (4, 4) extend and chunk step (32 rows and 128 prompt tokens) of a 2-layer
+    decoder at Mistral widths (no weight and no page exists: model and engine
+    are built under `jax.eval_shape`), state donated as on the chip. The chunk
+    step's two paged-attention calls a layer keep the kernel's name."""
+    from paddle_tpu.models.llama import LlamaForCausalLM
+
+    engine = _described_engine(
+        one_chip, monkeypatch,
+        lambda: LlamaForCausalLM(vocab_size=32000, hidden_size=4096, num_hidden_layers=2,
+                                 num_attention_heads=32, num_key_value_heads=8,
+                                 intermediate_size=14336, rms_norm_eps=1e-5),
+        max_seq_len=1024, block_size=16, num_blocks=4097, max_batch=32)
     compile_ = getattr(engine, "_compile_" + program)
     compiled = compile_(*size) if isinstance(size, tuple) else compile_(size)
     text = compiled.as_text()
     assert text.count(" scatter(") == scatters   # K and V written, a layer
     if program == "chunk":
         assert engine.chunk_width == 128
-        kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"" + KERNEL + "\"", text)
-        assert len(kernels) == 4 and all(k.startswith("paged_attn") for k in kernels)
+        assert _kernels_of(text) == ["paged_attn"] * 4
     assert _pool_copies(compiled, engine._state_avals()["k"][0]) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20   # a copy of the pool is 134 MB
+
+
+@pytest.mark.parametrize("program, size, kernels", [
+    ("decode", 16, {"mla_paged_attn": 2, "moe_gmm": 2}),
+    ("chunk", 16, {"mla_paged_attn": 4, "moe_gmm": 2}),   # rows and chunk, a layer
+    ("prefill", 1024, {"flash_fwd": 2, "moe_gmm": 2}),     # the expanded path: no paged kernel
+], ids=["decode_b16", "chunk_b16_c128", "prefill_s1024"])
+def test_latent_engine_programs_compile_at_published_widths(one_chip, monkeypatch, program, size, kernels):
+    """The long-document cell's engine over a decoder of openPangu-Ultra-MoE's
+    published widths, one leading dense and one sparse layer (16 of 256
+    experts held): the 16-row decode, the chunk step (16 rows and 128 prompt
+    tokens) and the 1024 prefill bucket. The latent pool is `[8705, 16, 640]`
+    (576 in whole lane tiles) and no program holds a `copy` of that shape:
+    the write lands in place and the kernel reads the pool where it lies."""
+    from paddle_tpu.models.pangu_ultra_moe import PanguUltraMoEForCausalLM
+
+    engine = _described_engine(
+        one_chip, monkeypatch,
+        lambda: PanguUltraMoEForCausalLM(
+            vocab_size=19200, hidden_size=7680, num_hidden_layers=2, first_k_dense_replace=1,
+            num_attention_heads=128, q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128, intermediate_size=18432, moe_intermediate_size=2048,
+            n_routed_experts=256, experts_held=[0, 16], num_experts_per_tok=8),
+        max_seq_len=8704, block_size=16, num_blocks=8705, max_batch=16,
+        prefill_buckets=(1024, 2048, 4096, 8192), decode_batch_buckets=(1, 2, 4, 8, 16))
+    pool = engine._state_avals()
+    assert pool["k"][0].shape == (8705, 16, 640) and pool["v"] == [] and engine.chunk_width == 128
+    compiled = getattr(engine, "_compile_" + program)(size)
+    text = compiled.as_text()
+    names = _kernels_of(text)
+    assert {k: names.count(k) for k in set(names)} == kernels
+    assert _pool_copies(compiled, pool["k"][0]) == []
+    if program != "prefill":  # a prefill's temporaries are its activations' (0.3 GB at 1024 tokens)
+        assert compiled.memory_analysis().temp_size_in_bytes < 96 << 20   # a copy of the pool is 178 MB
+
+
+@pytest.mark.parametrize("k, n, gated, out_dtype", [
+    (7680, 2048, True, BF16), (2048, 7680, False, jnp.float32)], ids=["gate_up_silu", "down_f32"])
+def test_gated_moe_gmm_compiles_at_the_latent_cells_shapes(one_chip, k, n, gated, out_dtype):
+    """The gated expert product at the long-document cell's chunk step: 144
+    tokens x 8 choices over 16 experts held, hidden 7680 x expert width 2048,
+    an expert's matrix 31.5 MB (tiled by columns: two blocks of 3.9 MB a step
+    for the gated product), bf16, under the framework's global x64."""
+    assignments, groups = 144 * 8, 16
+    rows = pk.moe_padded_rows(assignments, groups)
+    tiles = rows // pk.MOE_TILE_M
+    w = _aval(one_chip, (groups, k, n), BF16)
+
+    def fn(x_rows, w, tile_group, live, *gate):
+        return pk.moe_gmm(x_rows, w, tile_group, live, activation="silu" if gated else None,
+                          out_dtype=out_dtype, **({"w_gate": gate[0]} if gated else {}))
+
+    assert _kernel_names(fn, _aval(one_chip, (rows, k), BF16), w, _aval(one_chip, (tiles,), jnp.int32),
+                         _aval(one_chip, (1,), jnp.int32), *([w] if gated else [])) == ["moe_gmm"]
+
+
+def test_mla_paged_attention_compiles_for_rows_chunk_and_extend(one_chip):
+    """The latent kernel alone at the cell's shapes: 16 rows of one query,
+    one row of a 128-query chunk (8 query tiles of 16 x 128 heads), and a
+    (4, 4) extend, over the `[8705, 16, 640]` pool and a 544-page table."""
+    pages = _aval(one_chip, (8705, 16, 640), BF16)
+
+    def fn(q, pages, bt, pos):
+        return pk.mla_paged_attention(q, pages, bt, pos, 512, 192 ** -0.5)
+
+    for b, q_len in ((16, 1), (1, 128), (4, 4)):
+        assert _kernel_names(fn, _aval(one_chip, (b, q_len, 128, 576), BF16), pages,
+                             _aval(one_chip, (b, 544), jnp.int32),
+                             _aval(one_chip, (b, q_len), jnp.int32)) == ["mla_paged_attn"]
 
 
 @pytest.mark.parametrize("k, n, activation, out_dtype", [

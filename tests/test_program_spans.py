@@ -283,7 +283,8 @@ def test_a_chunk_step_is_an_engine_decode_span_that_counts_its_chunk(tiny_engine
     """A prompt that arrives beside a decode row rides the steps in chunks of
     128 (512 positions at block 8: 4 page blocks a row). Each such step is
     ONE `engine.decode` span, named and shaped as a plain step's, in the
-    largest bucket, with the chunk's tokens and frontier counted; the step's
+    largest bucket, with the chunk's tokens, its first position
+    (`chunk_context`: what its sequence cached before it) and frontier counted; the step's
     `sched.step` counts them too, and the request's `request.prompt` says how
     it entered and in how many steps."""
     from paddle_tpu.inference.engine import InferenceEngine
@@ -303,7 +304,7 @@ def test_a_chunk_step_is_an_engine_decode_span_that_counts_its_chunk(tiny_engine
         row_context = 5 + i       # the one decode row, a token a step
         frontier_blocks = (start + take - 1) // 128 + 1
         assert dec[6] == {"rows": 1, "bucket": 4, "chunk_tokens": take, "chunk_width": 128,
-                          "context": row_context + start + take,
+                          "context": row_context + start + take, "chunk_context": start,
                           "page_blocks_live": 4 + frontier_blocks, "page_blocks_grid": 5 * 4}
         kids = [r[0] for r in recs if r[4] == dec[3] and r[0].startswith("engine.decode.")]
         assert kids == ["engine.decode.inputs", "engine.decode.dispatch", "engine.decode.fetch"]
