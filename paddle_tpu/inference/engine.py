@@ -30,12 +30,16 @@ pool holds pages for the attention layers only and one fixed-size state
 slot a sequence for the recurrent ("mamba") ones; WHAT an attention layer
 keeps a token is the model's `cache_entry` (absent: K and V, kv heads x
 head_dim; `{"layout": "latent", "width": W}`: one latent vector, a pool of
-`[N, bs, W]` pages); the programs of such a
-model take each row's slot as one more operand (resolved here from the
-row's first page: `decode` and `prefill` keep their signatures), thread the
-state arrays through with the pages, and return the expert layers' counters
-beside the logits in the same fetch. For a model whose layers are all
-attention every array and every operand is as it was.
+`[N, bs, W]` pages); the programs of a model with recurrent layers take each
+row's slot as one more operand, and the chunk program the slot of the
+chunk's sequence (resolved here from a sequence's first page: `decode`,
+`prefill` and `decode_with_chunk` keep their signatures), and thread the
+state arrays through with the pages; a model with expert layers returns
+their counters beside the logits in the same fetch. A chunk moves its
+sequence's recurrent state forward only, from what its slot holds; going
+back (`extend`) would need snapshots and is refused for such a model. For a
+model whose layers are all attention every array and every operand is as it
+was.
 """
 from __future__ import annotations
 
@@ -232,9 +236,8 @@ class InferenceEngine:
             self._page_sharding = None
 
         # prompt tokens a step may carry beside its decode rows (`decode_with_chunk`):
-        # whole pages, at most the table; 0 for a model with recurrent layers,
-        # whose state cannot take several tokens of a row without snapshots
-        self.chunk_width = 0 if self.num_state_layers else min(
+        # whole pages, at most the table
+        self.chunk_width = min(
             max(self.block_size, _CHUNK_TOKENS // self.block_size * self.block_size),
             self.max_pages * self.block_size)
 
@@ -549,7 +552,8 @@ class InferenceEngine:
 
     def _slot_avals(self, rows: int):
         """The operand a model with recurrent layers adds to each program,
-        between the block tables and the state: every row's state slot."""
+        before the state: every row's state slot (the chunk program takes
+        two, its rows' and, one row, the chunk's sequence's)."""
         return (jax.ShapeDtypeStruct((rows,), jnp.int32),) if self.num_state_layers else ()
 
     def _slots_of(self, page_rows, rows: int):
@@ -685,8 +689,11 @@ class InferenceEngine:
         model, block_size = self._model, self.block_size
         with_counters = self._with_counters
 
-        def fn(params, tokens, positions, seq_lens, bt, chunk_bt, last, state):
-            view = PagedCacheView.from_state(state, bt, seq_lens, block_size, chunk_table=chunk_bt)
+        def fn(params, tokens, positions, seq_lens, bt, chunk_bt, last, *rest):
+            *slots, state = rest
+            slots, chunk_slot = slots or (None, None)  # where the model keeps recurrent state
+            view = PagedCacheView.from_state(state, bt, seq_lens, block_size, slots=slots,
+                                             chunk_table=chunk_bt, chunk_slot=chunk_slot)
             with no_grad():
                 logits = functional_call(
                     model, params, Tensor(tokens), cache=view,
@@ -703,6 +710,8 @@ class InferenceEngine:
             jax.ShapeDtypeStruct((B, self.max_pages), i32),
             jax.ShapeDtypeStruct((1, self.max_pages), i32),
             jax.ShapeDtypeStruct((B + 1,), i32),
+            *self._slot_avals(B),
+            *self._slot_avals(1),
             self._state_avals(),
         )
         return self._jit(fn, len(avals)).lower(*avals).compile()
@@ -815,14 +824,12 @@ class InferenceEngine:
         at positions chunk_start.. of `chunk_pages` (chunk_start on a page's
         edge; the pages cover the chunk's last token). One program, the
         weights read once: the chunk's K/V is written by whole pages and its
-        tokens see the sequence's cached context and themselves causally.
+        tokens see the sequence's cached context and themselves causally; a
+        recurrent layer takes the chunk forward from the state its sequence's
+        slot holds (zeros at chunk_start 0) and leaves the new state there.
         Returns (logits [n, V], the chunk's LAST token's logits [V]). The
         span is an `engine.decode` like any step's, with `chunk_tokens`."""
         take = len(chunk_ids)
-        if not self.chunk_width:
-            raise NotImplementedError(
-                "decode_with_chunk: the model has recurrent layers, and several tokens of a row "
-                "over a live recurrent state need state snapshots")
         if take < 1 or take > self.chunk_width:
             raise ValueError(f"a chunk holds 1..{self.chunk_width} tokens, not {take}")
         if chunk_start % self.block_size or chunk_start + take > self.max_seq_len:
@@ -864,6 +871,8 @@ class InferenceEngine:
                     span.args["chunk_context"] = start
                     self._count_page_blocks(span, np.asarray([start]), np.asarray([take]), C)
                 slots = self._slots_of(page_rows, B)
+                if chunk is not None:
+                    slots += self._slots_of([pages], 1)
                 if slots:
                     span.args["state_slots"] = self.pool.state_slots_used()
             if chunk is None:
@@ -872,7 +881,7 @@ class InferenceEngine:
             else:
                 ex = self._get_compiled("chunk", B)
                 operands = (jnp.asarray(tok[None]), jnp.asarray(pos[None]), jnp.asarray(lens),
-                            jnp.asarray(bt), jnp.asarray(chunk_bt), jnp.asarray(last))
+                            jnp.asarray(bt), jnp.asarray(chunk_bt), jnp.asarray(last), *slots)
             with RecordEvent("engine.decode.dispatch"):
                 logits, state = ex(self.params, *operands, self.pool.device_state())
                 self.pool.adopt_state(state)
@@ -906,9 +915,9 @@ class InferenceEngine:
             raise ValueError("extend needs at least one sequence")
         if self.num_state_layers:
             raise NotImplementedError(
-                "extend: the model has recurrent layers, and several query tokens a row over a "
-                "live recurrent state need state snapshots (speculative verify and chunked "
-                "suffix prefill are for models whose layers all keep K/V)")
+                "extend: the model has recurrent layers, and a verify that may go back over a "
+                "live recurrent state needs state snapshots (a prompt's chunk, which only goes "
+                "forward, enters through decode_with_chunk)")
         B = self.bucket_for("decode", n)
         with RecordEvent("engine.extend", args={"rows": n, "bucket": B, "q_len": q_len}) as span:
             with RecordEvent("engine.extend.inputs"):

@@ -185,6 +185,12 @@ class PagedCacheView:
     row at position 0 starts from zero) and `write_state` puts it back: by
     row, or over the whole array in slot order when the step holds a third of
     the slots or more (`slot_major`, with `to_slots` / `from_slots`).
+    `chunk_slot` [1] is the slot of the chunk's sequence, which holds no row
+    of the step: `read_chunk_state` gives what that slot holds (zeros where
+    the chunk starts its sequence), the layer moves it forward over the
+    chunk's tokens and `write_chunk_state` puts it back into that ONE slot,
+    which the rows' write leaves alone. Forward only: no state is kept to go
+    back to (prefix reuse, `extend` and rollback would need snapshots).
     `moe_counts` adds up what the expert layers report of one step.
 
     A LATENT pool's view has its one array a layer in `k_pages` (`[N, bs, W]`,
@@ -197,7 +203,7 @@ class PagedCacheView:
                  seq_lens, block_size: int, k_scales: Optional[Sequence] = None,
                  v_scales: Optional[Sequence] = None, write_mask=None,
                  ssm: Optional[Sequence] = None, conv: Optional[Sequence] = None, slots=None,
-                 chunk_table=None):
+                 chunk_table=None, chunk_slot=None):
         self.k_pages = list(k_pages)
         self.v_pages = list(v_pages)
         self.latent = bool(self.k_pages) and self.k_pages[0].ndim == 3
@@ -211,16 +217,17 @@ class PagedCacheView:
         self.ssm = list(ssm) if ssm is not None else None
         self.conv = list(conv) if conv is not None else None
         self.slots = None if slots is None else jnp.asarray(slots, jnp.int32)
+        self.chunk_slot = None if chunk_slot is None else jnp.asarray(chunk_slot, jnp.int32).reshape(())
         self.moe_counts = None  # [assignments, experts touched, layers] once an expert layer ran
 
     @classmethod
     def from_state(cls, state, block_tables, seq_lens, block_size, write_mask=None, slots=None,
-                   chunk_table=None):
+                   chunk_table=None, chunk_slot=None):
         """A view over a pool's state pytree (`BlockPool.device_state()`)."""
         return cls(state["k"], state["v"], block_tables, seq_lens, block_size,
                    k_scales=state.get("k_scale"), v_scales=state.get("v_scale"),
                    write_mask=write_mask, ssm=state.get("ssm"), conv=state.get("conv"), slots=slots,
-                   chunk_table=chunk_table)
+                   chunk_table=chunk_table, chunk_slot=chunk_slot)
 
     @staticmethod
     def state_of(view) -> Dict[str, List]:
@@ -288,6 +295,12 @@ class PagedCacheView:
             keep = self.to_slots(keep)
         else:
             h, c = h[self.slots], c[self.slots]
+        return self._zero_at_start(keep, h, c)
+
+    @staticmethod
+    def _zero_at_start(keep, h, c):
+        """The states h, c [R, ...] where `keep` [R]; zeros for a sequence
+        that starts in this step."""
         return (jnp.where(keep[:, None, None, None], h, 0.0),
                 jnp.where(keep[:, None, None], c, jnp.zeros((), c.dtype)))
 
@@ -304,6 +317,22 @@ class PagedCacheView:
         else:
             self.ssm[idx] = self.ssm[idx].at[self.slots].set(h)
             self.conv[idx] = self.conv[idx].at[self.slots].set(conv_rows)
+
+    def read_chunk_state(self, idx: int, first_position) -> Tuple:
+        """(ssm [1, heads, head_dim, state] f32, conv [1, rows, channels]) of
+        recurrent layer `idx` as the chunk's sequence left them in its slot:
+        zeros where the chunk starts the sequence (`first_position` 0),
+        whatever the slot held."""
+        keep = jnp.asarray(first_position, jnp.int32).reshape(1) != 0
+        return self._zero_at_start(keep, lax.dynamic_slice_in_dim(self.ssm[idx], self.chunk_slot, 1),
+                                   lax.dynamic_slice_in_dim(self.conv[idx], self.chunk_slot, 1))
+
+    def write_chunk_state(self, idx: int, h, conv_rows) -> None:
+        """The chunk's new state [1, ...] into its ONE slot, in place."""
+        self.ssm[idx] = lax.dynamic_update_slice_in_dim(
+            self.ssm[idx], h.astype(self.ssm[idx].dtype), self.chunk_slot, 0)
+        self.conv[idx] = lax.dynamic_update_slice_in_dim(
+            self.conv[idx], conv_rows.astype(self.conv[idx].dtype), self.chunk_slot, 0)
 
     def token_mask(self, b: int, s: int, positions):
         """[B, S] bool: the tokens of this step that are real. A prefill

@@ -53,13 +53,14 @@ Round 17 — prefix sharing + speculative decoding:
   With `spec_decode` on, prompts stream through the same program
   `draft_len + 1` tokens a row a step, and no chunk is planned.
 - A model with recurrent layers (the pool then holds a state slot a
-  sequence, `pool.has_recurrent_state`) gets none of the three: prefix
-  lookup and registration are skipped, `spec_decode` is refused and a
-  prompt beside decode rows streams ONE token a step through its own decode
-  row (the engine's `chunk_width` is 0), because each needs
-  snapshots of the state. Its slot is bound with the request's first page
-  and released with it: finish, expiry, shed and preemption free the pages,
-  and a preempted request streams again from position 0, from the zero state.
+  sequence, `pool.has_recurrent_state`) gets neither of the two: prefix
+  lookup and registration are skipped and `spec_decode` is refused, because
+  each goes BACK over a sequence and would need snapshots of the state. A
+  prompt beside decode rows enters in chunks like any other: a chunk moves
+  its sequence's state forward only, from what its slot holds. The slot is
+  bound with the request's first page and released with it: finish, expiry,
+  shed and preemption free the pages, and a preempted request, between two
+  chunks too, enters again from position 0, from the zero state.
 
 Round 19 — overload protection & multi-tenant QoS (inference/qos.py):
 
@@ -706,9 +707,8 @@ class ContinuousBatchingScheduler:
 
     def _chunk_width(self) -> int:
         """Prompt tokens a step may carry beside its decode rows; 0 where a
-        prompt streams instead: the pool holds recurrent state (the engine's
-        width is 0: one token a step), or speculative decoding is on (its
-        plans stream the prompt through `engine.extend`)."""
+        prompt streams instead: speculative decoding is on (its plans stream
+        the prompt through `engine.extend`)."""
         return 0 if self.spec is not None else getattr(self.engine, "chunk_width", 0)
 
     def _chunkable(self, req: Request) -> bool:
@@ -734,9 +734,9 @@ class ContinuousBatchingScheduler:
         rows, up to `engine.chunk_width` tokens of ONE prompt a step, oldest
         first (`_step_inner`); meanwhile it holds no decode row, and it
         emits its first token in the step that carries its last chunk.
-        Where no chunk can be planned (`_chunk_width()` 0: recurrent state,
-        or `spec_decode`) the prompt is "streamed" through the request's own
-        decode row, one token a step (`draft_len + 1` under `spec_decode`).
+        Where no chunk can be planned (`_chunk_width()` 0: `spec_decode`)
+        the prompt is "streamed" through the request's own row, `draft_len +
+        1` tokens a step.
 
         Round 17: admission consults the prefix index first. A hit shares
         the resident pages (refcounted) and NEVER takes the bucketed prefill

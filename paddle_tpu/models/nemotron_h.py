@@ -27,9 +27,13 @@ layers read and write the cache's recurrent state, one slot a sequence
 (`cache.slots`): a bucketed prefill starts from the zero state and leaves the
 state as the last REAL token left it, a decode step gathers each row's slot,
 steps it once and scatters it back; a row at position 0 starts from zero.
-Several query tokens a row over a live state (`extend`) need state snapshots
-and are refused. The cache path is inference-only; the plain forward is
-differentiable through `core.apply`.
+A chunk step (`cache.chunk_table` set: the decode rows and then a chunk of ONE
+more sequence's prompt, `models/cache_segments.py`) steps the rows so and
+moves the chunk's slot forward over the chunk's tokens in block form
+(`ssm_block`), from zero where the chunk starts its sequence. Going BACK over
+a live state (`extend`: speculative verify, a suffix after a prefix hit) needs
+state snapshots and is refused. The cache path is inference-only; the plain
+forward is differentiable through `core.apply`.
 """
 from __future__ import annotations
 
@@ -77,20 +81,95 @@ def _relu2(x):
 # Mamba-2
 # ---------------------------------------------------------------------------
 
-def mamba2_mix(x, w_in, conv_w, conv_b, dt_bias, a_log, d_skip, norm_w, w_out, *,
-               heads, head_dim, groups, state, eps, h0=None, conv0=None, valid_len=None):
-    """The Mamba-2 mixer over x [B, S, hidden], from the state (h0 [B, H, P, N]
-    float32, conv0 [B, K-1, C]: the rows of pre-conv xBC before this call;
-    zeros when None). `valid_len` [B]: positions at or past it are padding
-    and leave the state as the last real token left it. Returns (out
-    [B, S, hidden], h [B, H, P, N] float32, conv tail [B, K-1, C])."""
-    b, s, _ = x.shape
+def mamba2_in_proj(x, w_in, inner, conv_dim):
+    """`[z | xBC | dt] = x W_in` over x [B, S, hidden]: z [B, S, inner] and dt
+    [B, S, H] float32, xBC [B, S, conv_dim] in x's dtype."""
+    zxd = _dot_f32(x, w_in)
+    z, dt = zxd[..., :inner], zxd[..., inner + conv_dim:]
+    # the conv's inputs are what the state keeps: rounded to its dtype
+    return z, zxd[..., inner:inner + conv_dim].astype(x.dtype), dt
+
+
+def ssm_scan(xs, bm, cm, dt, a, h0):
+    """The recurrence a token at a time: `h_t = exp(dt_t a) h_{t-1} + dt_t (xs_t
+    outer B_t)`, `y_t = h_t C_t`, over xs [B, S, H, P], bm / cm [B, S, G, N], dt
+    [B, S, H] from h0 [B, H, P, N], all float32. Returns (h after the last
+    token, y [B, S, H, P]). One step at S == 1 (a decode row), a `lax.scan`
+    whose carry is the whole state otherwise (a bucketed prefill)."""
+    b, s, heads, head_dim = xs.shape
+    groups, state = bm.shape[2:]
+    per = heads // groups
+
+    def step(h, t):
+        xs_t, b_t, c_t, dt_t = t  # [B, H, P], [B, G, N], [B, G, N], [B, H]
+        hg = h.reshape(b, groups, per, head_dim, state)
+        add = (dt_t[..., None] * xs_t).reshape(b, groups, per, head_dim)[..., None] * b_t[:, :, None, None, :]
+        hg = jnp.exp(dt_t * a).reshape(b, groups, per)[..., None, None] * hg + add
+        y = jnp.einsum("bgrpn,bgn->bgrp", hg, c_t, preferred_element_type=jnp.float32)
+        return hg.reshape(h.shape), y.reshape(b, heads, head_dim)
+
+    if s == 1:
+        h, y = step(h0, (xs[:, 0], bm[:, 0], cm[:, 0], dt[:, 0]))
+        return h, y[:, None]
+    h, y = jax.lax.scan(step, h0, tuple(jnp.swapaxes(v, 0, 1) for v in (xs, bm, cm, dt)))
+    return h, jnp.swapaxes(y, 0, 1)
+
+
+def _segsum(a):
+    """s[..., i, j] = the sum of a[..., k] over j < k <= i (0 on the diagonal),
+    -inf above it: masked and then accumulated, as the published `segsum`
+    builds it, never the difference of two cumulative sums."""
+    t = a.shape[-1]
+    i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    s = jnp.cumsum(jnp.where(i > j, a[..., :, None], 0.0), axis=-2)
+    return jnp.where(i >= j, s, -jnp.inf)
+
+
+def ssm_block(xs, bm, cm, dt, a, h0):
+    """The same recurrence (operands and results as `ssm_scan`) over the S
+    tokens as ONE block of the published state-space-duality form, which is
+    matmuls and no loop over the tokens: with `a_t = dt_t a` and `s(i, j)` the
+    sum of `a_k` over j < k <= i, `y_i = sum_{j<=i} exp(s(i, j)) (C_i . B_j)
+    dt_j xs_j + exp(s(i, -1)) h0 C_i` and `h = exp(s(S-1, -1)) h0 + sum_j
+    exp(s(S-1, j)) dt_j xs_j outer B_j`. Every exponent is <= 0; float32, every
+    contraction at the highest precision (the loop's arithmetic is float32)."""
+    b, s, heads, head_dim = xs.shape
+    groups, state = bm.shape[2:]
+    per = heads // groups
+    hi = jax.lax.Precision.HIGHEST
+    da = jnp.moveaxis(dt * a, 1, -1)  # [B, H, S]
+    decay = jnp.exp(_segsum(da))  # [B, H, S, S]: exp(s(i, j)), 0 above the diagonal
+    since_start = jnp.exp(jnp.cumsum(da, -1))  # exp(s(i, -1))
+    to_end = decay[..., -1, :]  # exp(s(S-1, j))
+
+    def by_token(v):  # [B, H, S] -> [B, S, G, per, 1]
+        return jnp.moveaxis(v, -1, 1).reshape(b, s, groups, per, 1)
+
+    xdt = (dt[..., None] * xs).reshape(b, s, groups, per, head_dim)
+    hg = h0.reshape(b, groups, per, head_dim, state)
+    cb = jnp.einsum("bign,bjgn->bgij", cm, bm, precision=hi)
+    mix = decay.reshape(b, groups, per, s, s) * cb[:, :, None]
+    y = jnp.einsum("bgrij,bjgrp->bigrp", mix, xdt, precision=hi)
+    y = y + jnp.einsum("bign,bgrpn->bigrp", cm, hg, precision=hi) * by_token(since_start)
+    h = since_start[..., -1].reshape(b, groups, per, 1, 1) * hg + jnp.einsum(
+        "bjgrp,bjgn->bgrpn", xdt * by_token(to_end), bm, precision=hi)
+    return h.reshape(h0.shape), y.reshape(b, s, heads, head_dim)
+
+
+def mamba2_core(z, xbc, dt, conv_w, conv_b, dt_bias, a_log, d_skip, norm_w, *,
+                heads, head_dim, groups, state, eps, h0=None, conv0=None, valid_len=None,
+                recurrence=ssm_scan):
+    """The mixer between its two projections, over what `mamba2_in_proj` gives
+    of S tokens a row: the causal conv and silu over xBC, the recurrence
+    (`ssm_scan`, or `ssm_block` for a chunk of one sequence), the skip, the
+    gate and the group RMSNorm, from the state (h0 [B, H, P, N] float32, conv0
+    [B, K-1, C]: the rows of pre-conv xBC before this call; zeros when None).
+    `valid_len` [B]: positions at or past it are padding and leave the state
+    as the last real token left it. Returns (y [B, S, inner] in xBC's dtype,
+    h [B, H, P, N] float32, conv tail [B, K-1, C])."""
+    b, s, _ = xbc.shape
     k = conv_w.shape[0]
     inner, gn = heads * head_dim, groups * state
-    zxd = _dot_f32(x, w_in)
-    z, dt = zxd[..., :inner], zxd[..., 2 * inner + 2 * gn:]
-    # the conv's inputs are what the state keeps: rounded to its dtype
-    xbc = zxd[..., inner:2 * inner + 2 * gn].astype(x.dtype)
     if conv0 is None:
         conv0 = jnp.zeros((b, k - 1, xbc.shape[-1]), xbc.dtype)
     window = jnp.concatenate([conv0.astype(xbc.dtype), xbc], axis=1)  # [B, K-1+S, C]
@@ -108,28 +187,24 @@ def mamba2_mix(x, w_in, conv_w, conv_b, dt_bias, a_log, d_skip, norm_w, w_out, *
     else:
         tail = window[:, s:]
     a = -jnp.exp(_f32(a_log))
-    per = heads // groups
     if h0 is None:
         h0 = jnp.zeros((b, heads, head_dim, state), jnp.float32)
-
-    def step(h, t):
-        xs_t, b_t, c_t, dt_t = t  # [B, H, P], [B, G, N], [B, G, N], [B, H]
-        hg = h.reshape(b, groups, per, head_dim, state)
-        add = (dt_t[..., None] * xs_t).reshape(b, groups, per, head_dim)[..., None] * b_t[:, :, None, None, :]
-        hg = jnp.exp(dt_t * a).reshape(b, groups, per)[..., None, None] * hg + add
-        y = jnp.einsum("bgrpn,bgn->bgrp", hg, c_t, preferred_element_type=jnp.float32)
-        return hg.reshape(h.shape), y.reshape(b, heads, head_dim)
-
-    if s == 1:
-        h, y = step(h0, (xs[:, 0], bm[:, 0], cm[:, 0], dt[:, 0]))
-        y = y[:, None]
-    else:
-        h, y = jax.lax.scan(step, h0, tuple(jnp.swapaxes(v, 0, 1) for v in (xs, bm, cm, dt)))
-        y = jnp.swapaxes(y, 0, 1)
+    h, y = recurrence(xs, bm, cm, dt, a, h0)
     y = (y + _f32(d_skip)[:, None] * xs).reshape(b, s, inner) * jax.nn.silu(z)
     yg = y.reshape(b, s, groups, inner // groups)
     yg = yg * jax.lax.rsqrt(jnp.mean(jnp.square(yg), -1, keepdims=True) + eps)
-    y = (yg.reshape(b, s, inner) * _f32(norm_w)).astype(x.dtype)
+    return (yg.reshape(b, s, inner) * _f32(norm_w)).astype(xbc.dtype), h, tail
+
+
+def mamba2_mix(x, w_in, conv_w, conv_b, dt_bias, a_log, d_skip, norm_w, w_out, *,
+               heads, head_dim, groups, state, eps, h0=None, conv0=None, valid_len=None):
+    """The Mamba-2 mixer over x [B, S, hidden], a token at a time from the
+    state (`mamba2_core`'s h0, conv0 and valid_len). Returns (out [B, S,
+    hidden], h [B, H, P, N] float32, conv tail [B, K-1, C])."""
+    inner = heads * head_dim
+    y, h, tail = mamba2_core(*mamba2_in_proj(x, w_in, inner, inner + 2 * groups * state), conv_w, conv_b,
+                             dt_bias, a_log, d_skip, norm_w, heads=heads, head_dim=head_dim, groups=groups,
+                             state=state, eps=eps, h0=h0, conv0=conv0, valid_len=valid_len)
     return jnp.dot(y, w_out), h, tail
 
 
@@ -179,6 +254,8 @@ class Mamba2Mixer(nn.Layer):
         if positions is None:
             # bucketed prefill: from the zero state, the state left at true_len - 1
             out, h, tail = mamba2_mix(x.value, *w, **dims, valid_len=cache.seq_lens)
+        elif cache.chunk_table is not None:
+            return Tensor(self._rows_and_chunk(x.value, w, cache, positions))
         elif x.shape[1] == 1:
             # by row, or over every slot in place (the cache chooses by the
             # share of the slots this step holds)
@@ -193,6 +270,39 @@ class Mamba2Mixer(nn.Layer):
                 "(extend / speculative verify need state snapshots)")
         cache.write_state(self.state_idx, h, tail)
         return Tensor(out)
+
+    def _rows_and_chunk(self, x, w, cache, positions):
+        """A chunk step's ONE row of tokens [1, n + C, hidden] (the n decode
+        rows' one token each, then C consecutive prompt tokens of one more
+        sequence): the two projections run once over all of them; between
+        them the rows step their slots' state once, as a decode step does,
+        and the chunk moves ITS slot's state (zeros at position 0) forward
+        over its real tokens as one block. Forward only: nothing is kept to
+        go back to. The chunk's sequence holds no decode row, so the rows'
+        write leaves its slot alone, and the chunk reads that slot from the
+        arrays AS THE ROWS' WRITE LEFT THEM: one chain of updates, each in
+        place on the donated arrays (a read of the arrays from before the
+        rows' write keeps them alive past it, and costs a copy of a layer's
+        whole state)."""
+        w_in, *mid, w_out = w
+        dims, idx = self._dims(), self.state_idx
+        n, s = cache.block_tables.shape[0], x.shape[1]
+        pos = positions_2d(positions, 1)[0]
+        parts = mamba2_in_proj(x, w_in, self.num_heads * self.head_dim, self.conv_dim)  # z, xBC, dt: [1, n + C, ...]
+        h0, conv0 = cache.read_state(idx, pos[:n])
+        rows = [v[0, :n, None] for v in parts]
+        if cache.slot_major:
+            rows = [cache.to_slots(v) for v in rows]
+        y, h, tail = mamba2_core(*rows, *mid, **dims, h0=h0, conv0=conv0)
+        if cache.slot_major:
+            y = cache.from_slots(y)
+        cache.write_state(idx, h, tail)
+        h0, conv0 = cache.read_chunk_state(idx, pos[n])
+        real = cache.token_mask(1, s, positions)[0, n:].sum()[None]  # pad slots stand behind the real tokens
+        y_c, h, tail = mamba2_core(*(v[:, n:] for v in parts), *mid, **dims, h0=h0, conv0=conv0,
+                                   valid_len=real, recurrence=ssm_block)
+        cache.write_chunk_state(idx, h, tail)
+        return jnp.dot(jnp.concatenate([y[None, :, 0], y_c], axis=1), w_out)
 
 
 # ---------------------------------------------------------------------------

@@ -175,7 +175,7 @@ def _pool_copies(compiled, pool_aval):
     """`copy` instructions of the optimized program whose result has the
     pool's shape: the change of layout XLA puts around a scatter it cannot
     make in place, a layer's whole pool read and written each."""
-    dt = {"bfloat16": "bf16", "int8": "s8"}[str(pool_aval.dtype)]
+    dt = {"bfloat16": "bf16", "int8": "s8", "float32": "f32"}[str(pool_aval.dtype)]
     shape = re.escape(f"{dt}[{','.join(map(str, pool_aval.shape))}]")
     return re.findall(r"= " + shape + r"\S* copy\(", compiled.as_text())
 
@@ -317,6 +317,105 @@ def test_latent_engine_programs_compile_at_published_widths(one_chip, monkeypatc
     assert _pool_copies(compiled, pool["k"][0]) == []
     if program != "prefill":  # a prefill's temporaries are its activations' (0.3 GB at 1024 tokens)
         assert compiled.memory_analysis().temp_size_in_bytes < 96 << 20   # a copy of the pool is 178 MB
+
+
+def test_hybrid_chunk_program_updates_the_state_in_place_at_published_widths(one_chip, monkeypatch):
+    """The reasoning cell's engine over a hybrid of Nemotron-3-Super's
+    published widths (two state-space layers, one attention layer, two expert
+    layers of the 128 held experts; 128 slots, the pool of 8193 pages): its
+    ONE chunk program, 128 decode rows and 128 prompt tokens of one more
+    sequence. The rows' whole-array state update and the chunk's one-slot
+    update both land on the donated arrays (no `copy` of a layer's state,
+    541 MB, nor of the pool), the chunk's recurrence is the block form (no
+    loop carries a state), and the kernels keep their names."""
+    from paddle_tpu.models.nemotron_h import NemotronHForCausalLM
+
+    engine = _described_engine(
+        one_chip, monkeypatch,
+        lambda: NemotronHForCausalLM(
+            vocab_size=32768, hidden_size=4096, hybrid_override_pattern="MEM*E", num_attention_heads=32,
+            num_key_value_heads=2, head_dim=128, mamba_num_heads=128, mamba_head_dim=64, ssm_state_size=128,
+            n_groups=8, conv_kernel=4, n_routed_experts=512, experts_held=[0, 128], num_experts_per_tok=22,
+            moe_latent_size=1024, moe_intermediate_size=2688, moe_shared_expert_intermediate_size=5376),
+        max_seq_len=1024, block_size=16, num_blocks=8193, max_batch=128,
+        prefill_buckets=(16, 32, 64, 128, 256), decode_batch_buckets=(1, 2, 4, 8, 16, 32, 64, 128))
+    state = engine._state_avals()
+    assert state["ssm"][0].shape == (129, 128, 64, 128) and engine.chunk_width == 128
+    compiled = engine._compile_chunk(128)
+    text = compiled.as_text()
+    names = _kernels_of(text)
+    assert {k: names.count(k) for k in set(names)} == {"paged_attn": 2, "moe_gmm": 4}  # rows and chunk; up and down
+    for aval in (state["ssm"][0], state["k"][0]):
+        assert _pool_copies(compiled, aval) == []
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert not [line for line in loops if "64,128]" in line]   # the expert layout's loops carry indices alone
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20   # a copy of a layer's state is 541 MB
+
+
+def _lowered_digest(engine, program, size):
+    """sha256 of a program's lowered text, source locations stripped."""
+    import hashlib
+
+    box = []
+    jit = engine._jit
+
+    class Lowered:
+        def __init__(self, jitted):
+            self.jitted = jitted
+
+        def lower(self, *avals):
+            box.append(self.jitted.lower(*avals).as_text())
+            return self
+
+        def compile(self):
+            return None
+
+    engine._jit = lambda fn, n_args: Lowered(jit(fn, n_args))
+    try:
+        getattr(engine, "_compile_" + program)(*(size if isinstance(size, tuple) else (size,)))
+    finally:
+        engine._jit = jit
+    text = re.sub(r"\s*loc\([^\n]*\)", "", box[0])
+    text = "\n".join(line for line in text.splitlines() if not line.startswith("#loc"))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+LOWERED = {
+    ("llama", "decode", 4): "58f5aae9f4908c32",
+    ("llama", "prefill", 32): "bad05b9a2f237a4d",
+    ("llama", "chunk", 4): "3abec630093d02bd",
+    ("llama", "extend", (4, 4)): "7d875aa6fdd6bf0f",
+    ("pangu", "decode", 4): "ddbfc4c4a9653b7d",
+    ("pangu", "prefill", 32): "f04d8608c8a0240d",
+    ("pangu", "chunk", 4): "e6d82c4247740c66",
+    ("pangu", "extend", (4, 4)): "7eac7881ec210019",
+}
+
+
+@pytest.mark.parametrize("model, program, size", list(LOWERED), ids=lambda v: str(v).replace(" ", ""))
+def test_dense_and_latent_programs_lower_to_what_they_did(monkeypatch, model, program, size):
+    """PR 32 gave the engine's chunk program two more operands where the
+    model keeps recurrent state, and the hybrid's mixer a by-segment branch.
+    The programs of a model WITHOUT such state are, letter for letter but for
+    source locations, what PR 31's tree lowered (the digests are of that
+    tree's text: tiny models, this host's backend, the paged kernels' jnp
+    reference path). A PR that changes one on purpose replaces its digest,
+    and measures the cells that run it."""
+    from paddle_tpu.inference.engine import InferenceEngine
+
+    monkeypatch.setattr(pk, "_on_tpu", lambda: False)
+    paddle.seed(0)
+    if model == "llama":
+        from paddle_tpu.models.llama import llama_tiny
+
+        net = llama_tiny(num_key_value_heads=2)
+    else:
+        from paddle_tpu.models.pangu_ultra_moe import PanguUltraMoEForCausalLM
+
+        net = PanguUltraMoEForCausalLM()
+    net.eval()
+    engine = InferenceEngine(net, max_seq_len=64, block_size=8, max_batch=4)
+    assert _lowered_digest(engine, program, size) == LOWERED[model, program, size]
 
 
 @pytest.mark.parametrize("k, n, gated, out_dtype", [

@@ -6,8 +6,9 @@ many rows decode beside it, after shared prefix pages, across a preemption
 and when the pool runs dry under a chunk.
 
 CPU, the jnp reference path of the paged kernel, a tiny llama. The stream is
-the same scheduler over an engine whose `chunk_width` reads 0, as a pool with
-recurrent state makes it read: steered here, in the test.
+the same scheduler over an engine whose `chunk_width` reads 0: no engine
+reads so by itself, it is steered here, in the test. (A model with recurrent
+layers enters in chunks too: `tests/test_nemotron_h.py`.)
 """
 import numpy as np
 import pytest
@@ -231,13 +232,14 @@ def test_engine_refuses_a_chunk_off_a_pages_edge_or_too_wide(engine):
 
 
 @pytest.mark.parametrize("build, chunk_programs", [
-    ("llama_4_slots", [4]), ("llama_1_slot", []), ("hybrid_4_slots", [])],
+    ("llama_4_slots", [4]), ("llama_1_slot", []), ("hybrid_4_slots", [4])],
     ids=["four_slots", "a_lone_slot", "recurrent_state"])
 def test_readying_the_decode_buckets_readies_the_chunk_program(tiny_model, monkeypatch, build, chunk_programs):
     """Who readies the largest decode bucket readies the one chunk program
-    (the harness's set-up asks for the decode buckets and nothing else); an
-    engine with a lone slot, where no prompt ever rides beside a row, and one
-    whose pool holds recurrent state ready none. Nothing is compiled here."""
+    (the harness's set-up asks for the decode buckets and nothing else), a
+    pool with recurrent state like any other; an engine with a lone slot,
+    where no prompt ever rides beside a row, readies none. Nothing is
+    compiled here."""
     from paddle_tpu.inference.engine import InferenceEngine, clear_shared_executables
 
     if build == "hybrid_4_slots":
@@ -250,7 +252,7 @@ def test_readying_the_decode_buckets_readies_the_chunk_program(tiny_model, monke
         model = tiny_model
     eng = InferenceEngine(model, max_seq_len=64, block_size=PAGE,
                           max_batch=1 if build == "llama_1_slot" else 4)
-    assert (eng.chunk_width > 0) == (build != "hybrid_4_slots")
+    assert eng.chunk_width == 64  # whole pages, at most the table: whatever the layers keep
     clear_shared_executables()
     made = []
     monkeypatch.setattr(eng, "_compile_decode", lambda b: made.append(("decode", b)) or object())

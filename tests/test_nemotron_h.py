@@ -134,41 +134,170 @@ def test_padded_prefill_leaves_the_unpadded_state(seeded):
         np.testing.assert_allclose(a, b, **TOL)
 
 
-# (c) a prompt streamed token by token through decode slots, other rows in flight
-def test_streamed_prompt_beside_rows_in_flight_matches_the_reference(ref, seeded, engine):
+# (c) a prompt entering in chunks beside rows in flight
+C = 128
+
+
+@pytest.fixture(scope="module")
+def chunk_engine(seeded):
+    """Contexts long enough for the engine's full chunk width and two chunks of one prompt."""
+    eng = InferenceEngine(seeded[0], max_seq_len=256, block_size=8, max_batch=4,
+                          prefill_buckets=(16,), decode_batch_buckets=(1, 2, 4))
+    assert eng.chunk_width == C
+    return eng
+
+
+def _recording(engine, seen):
+    """Wrap the engine's two step calls: every logit row they return, by the
+    sequence's first page and the token's position."""
+    decode, with_chunk = engine.decode, engine.decode_with_chunk
+
+    def note(tokens, positions, page_rows, logits):
+        for i, row in enumerate(page_rows):
+            seen.setdefault(row[0], {})[positions[i]] = (tokens[i], logits[i])
+
+    def rec_decode(tokens, positions, seq_lens, page_rows):
+        out = decode(tokens=tokens, positions=positions, seq_lens=seq_lens, page_rows=page_rows)
+        note(tokens, positions, page_rows, out)
+        return out
+
+    def rec_chunk(tokens, positions, seq_lens, page_rows, chunk_ids, chunk_start, chunk_pages):
+        out, last = with_chunk(tokens, positions, seq_lens, page_rows, chunk_ids, chunk_start, chunk_pages)
+        note(tokens, positions, page_rows, out)
+        note([chunk_ids[-1]], [chunk_start + len(chunk_ids) - 1], [chunk_pages], [last])
+        return out, last
+
+    engine.decode, engine.decode_with_chunk = rec_decode, rec_chunk
+    return decode, with_chunk
+
+
+@pytest.mark.parametrize("beside", [1, 3], ids=["one_row", "full_bucket"])
+def test_chunked_prompt_beside_rows_in_flight_matches_the_reference(ref, seeded, chunk_engine, beside):
     model, vals = seeded
+    engine = chunk_engine
     engine.pool.reset()
     sched = ContinuousBatchingScheduler(engine)  # prefix_cache defaults to True
     seen = {}
-    decode = engine.decode
-
-    def recording(tokens, positions, seq_lens, page_rows):
-        out = decode(tokens=tokens, positions=positions, seq_lens=seq_lens, page_rows=page_rows)
-        for i, row in enumerate(page_rows):
-            seen.setdefault(row[0], {})[positions[i]] = (tokens[i], out[i])
-        return out
-
-    engine.decode = recording
+    restore = _recording(engine, seen)
     try:
-        first = Request(rid=0, prompt=_ids(3, 1, 9)[0].tolist(), max_new_tokens=20)
-        sched.submit(first)
-        sched.step()  # bucketed: nothing was in flight
-        late = Request(rid=1, prompt=_ids(4, 1, 13)[0].tolist(), max_new_tokens=6)
+        rows = [Request(rid=10 + i, prompt=_ids(30 + i, 1, 5 + i)[0].tolist(), max_new_tokens=12 + 2 * i)
+                for i in range(beside)]
+        for r in rows:
+            sched.submit(r)
+        while any(r.cursor < len(r.prompt) for r in rows):
+            sched.step()
+        late = Request(rid=1, prompt=_ids(4, 1, C + 21)[0].tolist(), max_new_tokens=6)  # two chunks
         sched.submit(late)
         sched.step()
-        assert late in sched.running and late.cursor < len(late.prompt)  # streaming
-        key = late.pages[0]
+        assert late in sched.running and late.cursor == C and late.slot[1] == "chunked"
+        assert engine.pool.state_slots_used() == beside + 1
+        keys = {r.rid: r.pages[0] for r in rows + [late]}
         _drain(sched)
     finally:
-        engine.decode = decode
-    seq = late.prompt + late.generated
-    want = np.asarray(ref.forward(vals, np.asarray([seq]), CFG))[0]
-    steps = seen[key]
-    assert sorted(steps) == list(range(len(seq) - 1))  # every token through a decode slot
-    for pos, (tok, logits) in steps.items():
-        assert tok == seq[pos]
-        np.testing.assert_allclose(logits, want[pos], **TOL)
+        engine.decode, engine.decode_with_chunk = restore
+    assert late.chunks == 2 and all(r.outcome == "completed" for r in rows + [late])
+    for r in rows + [late]:
+        seq = r.prompt + r.generated
+        want = np.asarray(ref.forward(vals, np.asarray([seq]), CFG))[0]
+        steps = seen[keys[r.rid]]
+        if r is late:  # each chunk's last token, then every token through a decode row
+            assert sorted(steps) == [C - 1] + list(range(len(r.prompt) - 1, len(seq) - 1))
+        for pos, (tok, logits) in steps.items():
+            assert tok == seq[pos]
+            np.testing.assert_allclose(logits, want[pos], **TOL)
     assert engine.pool.used() == 0 and engine.pool.state_slots_used() == 0
+
+
+def test_a_preemption_between_two_chunks_recomputes_from_the_zero_state(seeded, chunk_engine):
+    engine = chunk_engine
+
+    def serve(preempt):
+        engine.pool.reset()
+        sched = ContinuousBatchingScheduler(engine)
+        row = Request(rid=0, prompt=_ids(41, 1, 6)[0].tolist(), max_new_tokens=40)
+        sched.submit(row)
+        sched.step()
+        req = Request(rid=1, prompt=_ids(40, 1, C + 30)[0].tolist(), max_new_tokens=5)
+        sched.submit(req)
+        sched.step()
+        assert req.cursor == C and req.chunks == 1
+        if preempt:
+            slot = engine.pool.state_slot(req.pages[0])
+            # the first chunk left its state in the slot
+            assert all(np.abs(np.asarray(a[slot])).max() > 0 for a in engine.pool.ssm)
+            assert sched._preempt_one()  # the one still in its prompt goes first
+            assert req.cursor == 0 and req.pages == [] and engine.pool.state_slots_used() == 1
+            sched.step()  # admitted again: a first page, a slot on its first chunk, from position 0
+            assert req.cursor == C and engine.pool.state_slots_used() == 2
+        _drain(sched)
+        assert (req.preemptions, req.chunks) == ((1, 3) if preempt else (0, 2))
+        assert engine.pool.used() == 0 and engine.pool.state_slots_used() == 0
+        return req.prompt[req.prompt_len:] + req.generated, row.generated
+
+    assert serve(preempt=True) == serve(preempt=False)
+
+
+# (c') the chunk's recurrence in block form against the token-at-a-time scan
+def _mixer_leaves(seed=0):
+    heads, head_dim, groups, state, k, hidden = (CFG[n] for n in (
+        "mamba_num_heads", "mamba_head_dim", "n_groups", "ssm_state_size", "conv_kernel", "hidden_size"))
+    inner = heads * head_dim
+    conv_dim = inner + 2 * groups * state
+    rng = np.random.RandomState(seed)
+    w = [rng.randn(hidden, inner + conv_dim + heads) * 0.1, rng.randn(k, conv_dim) * 0.5,
+         rng.randn(conv_dim) * 0.02, rng.randn(heads) * 2.0, rng.randn(heads), np.ones(heads),
+         np.ones(inner), rng.randn(inner, hidden) * 0.1]
+    dims = dict(heads=heads, head_dim=head_dim, groups=groups, state=state, eps=1e-5)
+    return [jnp.asarray(v, jnp.float32) for v in w], dims, (heads, head_dim, state, k - 1, conv_dim)
+
+
+def _block_mix(x, w, dims, **kw):
+    """The mixer with the recurrence in block form, from the pieces the cache branch calls."""
+    inner = dims["heads"] * dims["head_dim"]
+    parts = nh.mamba2_in_proj(x, w[0], inner, inner + 2 * dims["groups"] * dims["state"])
+    y, h, tail = nh.mamba2_core(*parts, *w[1:7], **dims, recurrence=nh.ssm_block, **kw)
+    return jnp.dot(y, w[7]), h, tail
+
+
+@pytest.mark.parametrize("start", ["zero_state", "live_state"])
+@pytest.mark.parametrize("length", [1, 5, C - 1, C])
+def test_block_form_matches_the_sequential_scan(length, start):
+    w, dims, (heads, head_dim, state, rows, conv_dim) = _mixer_leaves()
+    rng = np.random.RandomState(length)
+    x = jnp.asarray(rng.randn(1, C, CFG["hidden_size"]), jnp.float32)
+    kw = dict(valid_len=jnp.asarray([length]))  # the chunk's tail past `length` is padding
+    if start == "live_state":
+        kw.update(h0=jnp.asarray(rng.randn(1, heads, head_dim, state), jnp.float32),
+                  conv0=jnp.asarray(rng.randn(1, rows, conv_dim), jnp.float32))
+    want = nh.mamba2_mix(x, *w, **dims, **kw)
+    got = _block_mix(x, w, dims, **kw)
+    np.testing.assert_allclose(got[0][:, :length], want[0][:, :length], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(got[2], want[2])
+    assert np.abs(np.asarray(want[1])).max() > 0
+
+
+def test_two_blocks_in_a_row_match_one_scan_over_both():
+    w, dims, _ = _mixer_leaves(1)
+    x = jnp.asarray(np.random.RandomState(7).randn(1, C + 37, CFG["hidden_size"]), jnp.float32)
+    want = nh.mamba2_mix(x, *w, **dims)
+    _, h, tail = first = _block_mix(x[:, :C], w, dims)
+    second = _block_mix(x[:, C:], w, dims, h0=h, conv0=tail)
+    np.testing.assert_allclose(jnp.concatenate([first[0], second[0]], 1), want[0], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(second[1], want[1], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(second[2], want[2], rtol=1e-5, atol=1e-5)  # projected in another shape
+
+
+def test_segment_sums_are_masked_then_accumulated():
+    a = -jnp.asarray(np.random.RandomState(0).rand(3, 6) * 50.0, jnp.float32)
+    s = np.asarray(nh._segsum(a))
+    for i in range(6):
+        for j in range(6):
+            if j > i:
+                assert np.all(s[:, i, j] == -np.inf)
+            else:
+                np.testing.assert_allclose(s[:, i, j], np.asarray(a)[:, j + 1:i + 1].sum(-1), rtol=1e-6)
+    assert np.all(s <= 0)
 
 
 # (d) the share: four shares' routed parts and the shared expert once make the uncut layer
@@ -275,6 +404,7 @@ def test_scheduler_skips_prefix_reuse_and_refuses_speculation(seeded, engine):
         ContinuousBatchingScheduler(engine, spec_decode=SpecDecodeConfig(draft_len=2))
     with pytest.raises(NotImplementedError, match="recurrent layers"):
         engine.extend([[1, 2]], [[0, 1]], [[1]], 2)
+    assert engine.chunk_width > 0  # a chunk goes forward only: no snapshot, no refusal
 
 
 def test_an_all_attention_model_keeps_its_pool_and_operands():
