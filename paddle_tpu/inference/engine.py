@@ -30,7 +30,10 @@ pool holds pages for the attention layers only and one fixed-size state
 slot a sequence for the recurrent ("mamba") ones; WHAT an attention layer
 keeps a token is the model's `cache_entry` (absent: K and V, kv heads x
 head_dim; `{"layout": "latent", "width": W}`: one latent vector, a pool of
-`[N, bs, W]` pages); the programs of a model with recurrent layers take each
+`[N, bs, W]` pages; with `"index_width": I` a second array a layer,
+`[N, bs, I]`, the keys of the model's token selector, and the step's spans
+then count what the selector scored and chose by the model's `index_topk`);
+the programs of a model with recurrent layers take each
 row's slot as one more operand, and the chunk program the slot of the
 chunk's sequence (resolved here from a sequence's first page: `decode`,
 `prefill` and `decode_with_chunk` keep their signatures), and thread the
@@ -182,6 +185,12 @@ class InferenceEngine:
         # model's own entry (one latent vector: a pool without a head axis)
         entry = cfg.get("cache_entry") or {"layout": "kv"}
         self.cache_layout = str(entry["layout"])
+        # a model that selects cached tokens keeps index keys beside the entry
+        # and attends over `index_topk` positions a query at most
+        self.index_width = int(entry.get("index_width") or 0)
+        self.index_topk = int(cfg.get("index_topk") or 0) if self.index_width else 0
+        self.index_tile = int(cfg.get("index_query_tile") or 0)  # queries that select together
+        self.index_totals = np.zeros((3,), np.int64)  # positions live, selected, sparse queries: all steps
         if self.cache_layout == "latent":
             self.num_kv_heads, self.head_dim = 1, int(entry["width"])
         else:
@@ -256,6 +265,7 @@ class InferenceEngine:
             self.num_kv_heads, self.head_dim, dtype=w_dtype,
             kv_dtype=kv_dtype, state_layers=self.num_state_layers,
             state_spec=state_spec, state_slots=self.max_batch, layout=self.cache_layout,
+            index_width=self.index_width,
         )
         # donation keeps exactly one pool copy live on TPU; CPU's donation
         # path only warns, so gate it on the platform
@@ -527,6 +537,9 @@ class InferenceEngine:
         if self.num_state_layers:
             for key in ("ssm", "conv"):
                 avals[key] = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in getattr(self.pool, key)]
+        if self.index_width:
+            avals["index"] = [jax.ShapeDtypeStruct((*shape[:2], self.index_width), self.pool.dtype)
+                              ] * self.num_kv_layers
         return avals
 
     def _state_shardings(self):
@@ -548,6 +561,8 @@ class InferenceEngine:
             # a recurrent layer's state is whole on every device
             sh["ssm"] = [self._repl] * self.num_state_layers
             sh["conv"] = [self._repl] * self.num_state_layers
+        if self.index_width:
+            sh["index"] = pages
         return sh
 
     def _slot_avals(self, rows: int):
@@ -767,6 +782,10 @@ class InferenceEngine:
                 slots = self._slots_of([pages], 1)
                 if slots:
                     span.args["state_slots"] = self.pool.state_slots_used()
+                if self.index_topk:
+                    tile = self.index_tile or L
+                    for start in range(0, L, tile):
+                        self._count_index(span, [start], [min(tile, L - start)])
             ex = self._get_compiled("prefill", S)
             with RecordEvent("engine.prefill.dispatch"):
                 logits, state = ex(
@@ -794,6 +813,35 @@ class InferenceEngine:
             _, blocks = paged_page_blocks(self.block_size, self.max_pages)
         span.args["page_blocks_live"] = span.args.get("page_blocks_live", 0) + int(live.sum())
         span.args["page_blocks_grid"] = span.args.get("page_blocks_grid", 0) + int(live.size * blocks)
+
+    def _count_index(self, span, first, count):
+        """What the token selector does with ONE tile of a step's queries (the
+        queries that score, select and attend together: a step's decode rows,
+        a prompt's chunk, `index_tile` consecutive queries of a longer row),
+        added up on the span (a layer; every layer does the same). Row i's
+        `count[i]` queries stand at `first[i]` on, each with a context of its
+        position + 1. `index_positions_live` is the sum of those contexts,
+        `index_positions_selected` the positions attended (`index_topk` a query
+        at most), `sparse_queries` the queries whose context is past
+        `index_topk`. A tile with such a query goes through the selector whole:
+        `index_positions_scored` / `sparse_positions_attended` are its share of
+        the first two, `index_keys_read` the index keys it reads (each row's
+        context once)."""
+        if not self.index_topk:
+            return
+        first, count, k = np.asarray(first, np.int64), np.asarray(count, np.int64), self.index_topk
+        last = first + count  # one past the last query's context
+        live = int((count * (first + last + 1) // 2).sum())
+        dense = np.clip(k - first, 0, count)  # queries whose whole context is chosen
+        selected = int((dense * (2 * first + dense + 1) // 2 + (count - dense) * k).sum())
+        add = {"index_positions_live": live, "index_positions_selected": selected,
+               "sparse_queries": int((count - dense).sum())}
+        if add["sparse_queries"]:
+            add.update(index_positions_scored=live, sparse_positions_attended=selected,
+                       index_keys_read=int(last.sum()))
+        self.index_totals += np.asarray([live, selected, add["sparse_queries"]], np.int64)
+        for key, v in add.items():
+            span.args[key] = span.args.get(key, 0) + v
 
     def decode(
         self,
@@ -862,6 +910,7 @@ class InferenceEngine:
                     bt[i] = self.pool.padded_table(row, self.max_pages)
                 span.args["context"] = int(lens[:n].sum())
                 self._count_page_blocks(span, lens - 1, np.ones_like(lens))
+                self._count_index(span, lens[:n] - 1, np.ones((n,), np.int64))
                 if chunk is not None:
                     tok[B:B + take] = np.asarray(ids, np.int32)
                     pos[B:B + take] = start + np.arange(take, dtype=np.int32)  # pad slots stay at 0
@@ -870,6 +919,7 @@ class InferenceEngine:
                     span.args["context"] += start + take
                     span.args["chunk_context"] = start
                     self._count_page_blocks(span, np.asarray([start]), np.asarray([take]), C)
+                    self._count_index(span, [start], [take])
                 slots = self._slots_of(page_rows, B)
                 if chunk is not None:
                     slots += self._slots_of([pages], 1)
@@ -938,6 +988,7 @@ class InferenceEngine:
                     bt[i] = self.pool.padded_table(row, self.max_pages)
                 span.args["context"] = int(pos.max(axis=1)[:n].sum()) + n
                 self._count_page_blocks(span, pos[:, 0], pos.max(axis=1) - pos[:, 0] + 1, q_len)
+                self._count_index(span, pos[:n, 0], pos[:n].max(axis=1) - pos[:n, 0] + 1)
             ex = self._get_compiled("extend", (B, q_len))
             with RecordEvent("engine.extend.dispatch"):
                 logits, state = ex(

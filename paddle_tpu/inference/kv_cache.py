@@ -35,6 +35,10 @@ axis minor, and copies a layer's whole pool to the other layout and back
 around every write and every kernel call.
 The allocator, the ref counts and the prefix index are page-granular and
 the same; int8 storage and page migration are refused for such a pool.
+A latent pool may keep a SECOND array a layer under the same page ids
+(`index_width`: `[num_blocks, block_size, index_width]`, the keys a learned
+token selector scores a query against, one whole lane tile wide): written in
+place by the same three write paths, copied with its page, freed with it.
 
 Round 17 — prefix sharing + int8 storage:
 
@@ -196,17 +200,21 @@ class PagedCacheView:
     A LATENT pool's view has its one array a layer in `k_pages` (`[N, bs, W]`,
     no head axis) and no `v_pages`: `latent` is True, `write` / `write_chunk`
     take the entries `[B, S, width]` alone (zeros fill a slot's tail up to W),
-    and the model reads `k_pages[idx]`.
+    and the model reads `k_pages[idx]`. Where the pool keeps index keys too
+    (`index_pages`, `[N, bs, index width]` a layer), the writes take them as
+    the second array `[B, S, index width]` and put them at the same page and
+    slot.
     """
 
     def __init__(self, k_pages: Sequence, v_pages: Sequence, block_tables,
                  seq_lens, block_size: int, k_scales: Optional[Sequence] = None,
                  v_scales: Optional[Sequence] = None, write_mask=None,
                  ssm: Optional[Sequence] = None, conv: Optional[Sequence] = None, slots=None,
-                 chunk_table=None, chunk_slot=None):
+                 chunk_table=None, chunk_slot=None, index_pages: Optional[Sequence] = None):
         self.k_pages = list(k_pages)
         self.v_pages = list(v_pages)
         self.latent = bool(self.k_pages) and self.k_pages[0].ndim == 3
+        self.index_pages = list(index_pages) if index_pages is not None else None
         self.k_scales = list(k_scales) if k_scales is not None else None
         self.v_scales = list(v_scales) if v_scales is not None else None
         self.block_tables = jnp.asarray(block_tables, jnp.int32)
@@ -227,7 +235,7 @@ class PagedCacheView:
         return cls(state["k"], state["v"], block_tables, seq_lens, block_size,
                    k_scales=state.get("k_scale"), v_scales=state.get("v_scale"),
                    write_mask=write_mask, ssm=state.get("ssm"), conv=state.get("conv"), slots=slots,
-                   chunk_table=chunk_table, chunk_slot=chunk_slot)
+                   chunk_table=chunk_table, chunk_slot=chunk_slot, index_pages=state.get("index"))
 
     @staticmethod
     def state_of(view) -> Dict[str, List]:
@@ -239,6 +247,8 @@ class PagedCacheView:
         if view.ssm is not None:
             state["ssm"] = view.ssm
             state["conv"] = view.conv
+        if view.index_pages is not None:
+            state["index"] = view.index_pages
         return state
 
     @property
@@ -459,6 +469,8 @@ class PagedCacheView:
         self.k_pages[idx] = put(self.k_pages[idx], k_new)
         if not self.latent:
             self.v_pages[idx] = put(self.v_pages[idx], v_new)
+        elif self.index_pages is not None:  # the entry's index key, same page and slot
+            self.index_pages[idx] = put(self.index_pages[idx], v_new.astype(self.index_pages[idx].dtype))
 
 
 class BlockPool:
@@ -472,6 +484,8 @@ class BlockPool:
     (`head_dim` the entry's width, W that in whole lane tiles of 128;
     `num_kv_heads` must be 1: every query head reads the one vector a
     token), kept in `k_pages`; `v_pages` is empty.
+    `index_width` (a latent pool's alone) adds a second array a layer,
+    [num_blocks, block_size, index_width], in `index_pages`.
     `num_blocks` INCLUDES the reserved trash page 0; usable capacity is
     num_blocks - 1 pages.
     `kv_dtype="int8"` stores int8 pages with f32 scale planes alongside (kv
@@ -489,7 +503,7 @@ class BlockPool:
                  num_kv_heads: int, head_dim: int, dtype=jnp.float32,
                  kv_dtype: Optional[str] = None, state_layers: int = 0,
                  state_spec: Optional[StateSpec] = None, state_slots: int = 0,
-                 layout: str = "kv"):
+                 layout: str = "kv", index_width: int = 0):
         if num_blocks < 2:
             raise ValueError("BlockPool needs >= 2 blocks (page 0 is reserved)")
         if kv_dtype not in (None, "int8"):
@@ -505,6 +519,9 @@ class BlockPool:
             raise ValueError("a latent pool keeps one vector a token: num_kv_heads must be 1")
         if state_layers and (state_spec is None or state_slots < 1):
             raise ValueError("a pool with recurrent layers needs their StateSpec and >= 1 slot")
+        if index_width and (layout != "latent" or int(index_width) % _LANES):
+            raise ValueError(
+                f"index keys ({index_width} wide) ride a latent pool's pages, in whole lane tiles of {_LANES}")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.num_layers = int(num_layers)
@@ -518,6 +535,11 @@ class BlockPool:
         self.k_pages: List = [jnp.zeros(shape, self.dtype) for _ in range(self.num_layers)]
         self.v_pages: List = [] if self.latent else [
             jnp.zeros(shape, self.dtype) for _ in range(self.num_layers)]
+        # the selector's keys, a layer: a second array under the same page ids
+        self.index_width = int(index_width)
+        self.index_pages: Optional[List] = [
+            jnp.zeros((*shape[:2], self.index_width), self.dtype) for _ in range(self.num_layers)
+        ] if self.index_width else None
         if kv_dtype == "int8":
             sshape = shape[:3]
             self.k_scales: Optional[List] = [
@@ -608,10 +630,10 @@ class BlockPool:
 
     def page_bytes(self) -> int:
         """Device bytes ONE page costs across all layers (K + V + scale
-        planes, or the one latent array) — the bench's same-pool-bytes
-        comparisons use this."""
+        planes, or the one latent array and its index keys) — the bench's
+        same-pool-bytes comparisons use this."""
         slot = self.block_size * self.num_kv_heads
-        data = (self.arrays_per_layer * self.num_layers * slot * self.page_shape[-1]
+        data = (self.num_layers * slot * (self.arrays_per_layer * self.page_shape[-1] + self.index_width)
                 * jnp.dtype(self.dtype).itemsize)
         scales = 0
         if self.quantized:
@@ -881,6 +903,8 @@ class BlockPool:
             self.k_pages[layer] = self.k_pages[layer].at[new].set(self.k_pages[layer][page])
             if not self.latent:
                 self.v_pages[layer] = self.v_pages[layer].at[new].set(self.v_pages[layer][page])
+            if self.index_pages is not None:
+                self.index_pages[layer] = self.index_pages[layer].at[new].set(self.index_pages[layer][page])
             if self.k_scales is not None:
                 self.k_scales[layer] = self.k_scales[layer].at[new].set(self.k_scales[layer][page])
                 self.v_scales[layer] = self.v_scales[layer].at[new].set(self.v_scales[layer][page])
@@ -918,6 +942,8 @@ class BlockPool:
         if self.state_layers:
             state["ssm"] = list(self.ssm)
             state["conv"] = list(self.conv)
+        if self.index_pages is not None:
+            state["index"] = list(self.index_pages)
         return state
 
     def adopt_state(self, state: Dict[str, List]) -> None:
@@ -932,6 +958,10 @@ class BlockPool:
                 raise ValueError("pool state is missing the recurrent layers' arrays")
             self.ssm = list(state["ssm"])
             self.conv = list(state["conv"])
+        if self.index_pages is not None:
+            if len(state.get("index", ())) != self.num_layers:
+                raise ValueError("pool state is missing the index-key arrays")
+            self.index_pages = list(state["index"])
 
     def adopt(self, k_pages: Sequence, v_pages: Sequence) -> None:
         """Install a step's updated page arrays back into the pool."""

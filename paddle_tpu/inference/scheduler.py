@@ -727,6 +727,8 @@ class ContinuousBatchingScheduler:
         Two admission paths (the continuous-batching TPOT trade): with
         NOTHING in flight there is no one to stall, so the prompt runs
         through a bucketed prefill program in one shot (TTFT-optimal).
+        (A prompt longer than the engine's largest prefill bucket has no
+        such program and enters by the second path.)
         With decode in flight, a monolithic prefill between two decode
         steps would stretch every in-flight request's inter-token interval
         — instead the request takes a slot with its prompt still to come
@@ -760,7 +762,8 @@ class ContinuousBatchingScheduler:
             if n_shareable > 0:
                 keys = prefix_chain_keys(req.prompt, pool.block_size)[:n_shareable]
                 shared = pool.acquire_prefix(keys, owner=req.rid)
-        if not self.running and not shared and self.admission_mode == "auto":
+        if (not self.running and not shared and self.admission_mode == "auto"
+                and len(req.prompt) <= max(getattr(self.engine, "prefill_buckets", None) or (len(req.prompt),))):
             need = pool.blocks_for_tokens(len(req.prompt) + 1)
             if need <= pool.available():
                 self.waiting.pop(idx)
@@ -1012,6 +1015,8 @@ class ContinuousBatchingScheduler:
                 self.spec = None
             # prompt tokens that enter in this step: by any path, and in a chunk
             self._entered = {"prompt_tokens": 0, "chunk_tokens": 0}
+            # a model that selects cached tokens: what its selector scored and chose in this step
+            index_before = self.engine.index_totals.copy() if getattr(self.engine, "index_topk", 0) else None
             try:
                 produced = self._step_inner()
             finally:
@@ -1024,6 +1029,9 @@ class ContinuousBatchingScheduler:
                          "waiting": len(self.waiting), **self._entered}
             if self.engine.pool.has_recurrent_state:
                 span.args["state_slots"] = self.engine.pool.state_slots_used()
+            if index_before is not None:
+                span.args.update(zip(("index_positions_live", "index_positions_selected", "sparse_queries"),
+                                     (int(v) for v in self.engine.index_totals - index_before)))
         return produced
 
     def _qos_pre_step(self, now: float) -> None:

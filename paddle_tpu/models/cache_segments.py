@@ -24,6 +24,7 @@ hands them in. The order of writes and reads is this module's alone.
 """
 from __future__ import annotations
 
+import jax
 from jax import numpy as jnp
 
 __all__ = ["attend_through_cache", "kv_readers", "positions_2d", "take_positions"]
@@ -68,8 +69,10 @@ def kv_readers(cache, idx):
 def attend_through_cache(cache, idx, q, entry, pos, *, prefill, read_one, read_many):
     """Write `entry` (a tuple of arrays [B, S, ...]: what layer `idx` caches
     of this step's tokens) and attend `q` [B, S, H, D] (what the paged reads
-    take; unused, and may be None, in a prefill) over the cache, by the step's
-    segments (module docstring). Returns [B, S, H, Dv].
+    take; unused, and may be None, in a prefill; a tuple of arrays [B, S, ...]
+    where a read takes more than the query, each cut to its segment alike)
+    over the cache, by the step's segments (module docstring). Returns
+    [B, S, H, Dv].
 
     - `prefill()` -> [B, S, H, Dv]: the plain causal attention of a bucketed
       prefill over this call's own keys.
@@ -84,8 +87,8 @@ def attend_through_cache(cache, idx, q, entry, pos, *, prefill, read_one, read_m
         n = cache.block_tables.shape[0]
         cache.write(idx, *(e[0, :n, None] for e in entry), positions=pos[0, :n, None])
         cache.write_chunk(idx, *(e[:, n:] for e in entry), first_position=pos[0, n])
-        rows = read_one(q[0, :n], cache.block_tables, cache.seq_lens)
-        chunk = read_many(q[:, n:], cache.chunk_table, pos[:, n:])
+        rows = read_one(jax.tree.map(lambda a: a[0, :n], q), cache.block_tables, cache.seq_lens)
+        chunk = read_many(jax.tree.map(lambda a: a[:, n:], q), cache.chunk_table, pos[:, n:])
         return jnp.concatenate([rows[None], chunk], axis=1)  # [1, n + C, H, Dv]
     cache.write(idx, *entry, positions=pos)
     if pos is None:
@@ -93,8 +96,8 @@ def attend_through_cache(cache, idx, q, entry, pos, *, prefill, read_one, read_m
         # discarded rows (their queries only ever see real keys at or before
         # themselves)
         return prefill()
-    if q.shape[1] == 1:
-        return read_one(q[:, 0], cache.block_tables, cache.seq_lens)[:, None]
+    if pos.shape[1] == 1:
+        return read_one(jax.tree.map(lambda a: a[:, 0], q), cache.block_tables, cache.seq_lens)[:, None]
     # extend/verify: every query reads the PAGED context up through its own
     # position (the entries of all s tokens were just written above)
     return read_many(q, cache.block_tables, pos)

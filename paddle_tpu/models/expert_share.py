@@ -32,12 +32,22 @@ def _f32(x):
     return x.astype(jnp.float32)
 
 
-def route_topk(x, w_router, b_corr, top_k, scale):
+def route_topk(x, w_router, b_corr, top_k, scale, *, n_group=None, topk_group=None):
     """(chosen [T, k] int32, weights [T, k] float32): sigmoid scores in
     float32; the correction bias (None where the router has none) steers the
-    choice only; the weights are normalised over all the chosen and scaled."""
+    choice only; the weights are normalised over all the chosen and scaled.
+    With `n_group` the choice is group-limited: the experts stand in `n_group`
+    equal groups, a group scores the sum of its two largest biased scores, and
+    only experts of the `topk_group` best groups can be chosen."""
     s = jax.nn.sigmoid(jnp.dot(_f32(x), _f32(w_router), precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(s if b_corr is None else s + _f32(b_corr), top_k)
+    c = s if b_corr is None else s + _f32(b_corr)
+    if n_group:
+        t, e = c.shape
+        by_group = c.reshape(t, n_group, e // n_group)
+        _, best = jax.lax.top_k(jax.lax.top_k(by_group, 2)[0].sum(-1), topk_group)
+        kept = jnp.zeros((t, n_group), bool).at[jnp.arange(t)[:, None], best].set(True)
+        c = jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(t, e)
+    _, chosen = jax.lax.top_k(c, top_k)
     picked = jnp.take_along_axis(s, chosen, axis=-1)
     return chosen.astype(jnp.int32), scale * picked / (picked.sum(-1, keepdims=True) + 1e-20)
 

@@ -39,7 +39,6 @@ from __future__ import annotations
 import inspect
 import math
 
-import jax
 from jax import numpy as jnp
 
 from .. import nn
@@ -47,115 +46,10 @@ from ..core.apply import apply
 from ..core.tensor import Tensor
 from ..ops import pallas as pk
 from .cache_segments import attend_through_cache, positions_2d, take_positions
-from .expert_share import route_topk, routed_experts
-from .llama import _rope_tables, _ROPE_POS_GRANULE
+from .mla_moe import (GatedMLP, SparseMLP, gated_mlp, mla_absorb_query, mla_expanded, mla_project,  # noqa: F401
+                      mla_unabsorb_context, sparse_mlp)
 
 __all__ = ["PanguUltraMoEForCausalLM", "PanguUltraMoEModel"]
-
-
-def _dot_f32(x, w):
-    """x @ w in the storage dtype with a float32 result."""
-    return jnp.dot(x, w, preferred_element_type=jnp.float32)
-
-
-def _rms(x, w, eps):
-    xf = x.astype(jnp.float32)
-    out = (xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)).astype(x.dtype)
-    return out * w.astype(x.dtype)
-
-
-def rope_half(x, positions, theta, max_pos):
-    """Rotate-half rotary embedding of x [B, S, ..., d] (any axes between the
-    sequence and the last): column i pairs with column i + d/2. positions
-    [B, S] int32, or None for tokens at 0..S-1; `max_pos` bounds the table
-    (static under trace)."""
-    d, s = x.shape[-1], x.shape[1]
-    cap = -(-max(int(max_pos), 1) // _ROPE_POS_GRANULE) * _ROPE_POS_GRANULE
-    cos_np, sin_np = _rope_tables(cap, d, float(theta))
-    if positions is None:
-        cos, sin = jnp.asarray(cos_np[:s])[None], jnp.asarray(sin_np[:s])[None]
-    else:
-        cos, sin = jnp.asarray(cos_np)[positions], jnp.asarray(sin_np)[positions]
-    mid = (1,) * (x.ndim - 3)
-    cos, sin = cos.reshape(*cos.shape[:2], *mid, d // 2), sin.reshape(*sin.shape[:2], *mid, d // 2)
-    x1, x2 = x[..., :d // 2].astype(jnp.float32), x[..., d // 2:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
-
-
-def _causal_attention(q, k, v, scale):
-    """Plain causal attention [B, S, H, D]; the flash kernel where it pays
-    (it wants one width: the narrower value is padded to the key's)."""
-    if pk.flash_attention_profitable(q, True, 0.0, k, k):
-        dv = v.shape[-1]
-        v = jnp.pad(v, ((0, 0),) * 3 + ((0, k.shape[-1] - dv),))
-        return pk.flash_attention_bshd(q, k, v, causal=True, sm_scale=scale)[..., :dv]
-    return pk._ref_attention_bshd(q, k, v, True, scale)
-
-
-# ---------------------------------------------------------------------------
-# multi-head latent attention
-# ---------------------------------------------------------------------------
-
-def mla_project(a, w_qa, g_qa, w_qb, w_kva, g_kva, *, heads, nope, rope, rank, eps, theta,
-                positions, max_pos):
-    """From the normed input a [B, S, hidden]: (q_nope [B, S, H, nope], q_rope
-    [B, S, H, rope] rotated, c_kv [B, S, rank] normed, k_r [B, S, rope]
-    rotated)."""
-    b, s, _ = a.shape
-    q = jnp.dot(_rms(jnp.dot(a, w_qa), g_qa, eps), w_qb).reshape(b, s, heads, nope + rope)
-    kv = jnp.dot(a, w_kva)
-    c_kv = _rms(kv[..., :rank], g_kva, eps)
-    q_rope = rope_half(q[..., nope:], positions, theta, max_pos)
-    k_r = rope_half(kv[..., rank:], positions, theta, max_pos)
-    return q[..., :nope], q_rope, c_kv, k_r
-
-
-_HEAD_GROUP = 16  # heads the expanded path holds keys and values of at once
-
-
-def mla_expanded(q_nope, q_rope, c_kv, k_r, w_kvb, *, heads, nope, v_dim, scale):
-    """The expanded path: keys and values a head from the latent, plain
-    causal attention. Returns [B, S, H * v_dim]. Many heads go through in
-    groups of `_HEAD_GROUP` (their keys and values projected a group at a
-    time): at 128 heads an 8,192-token prefill's q, k, v and the kernel's
-    head-major copies of them are 2.8 GB whole, and with the expert layer's rows in
-    blocks of 1024 tokens the 8,192 bucket's temporaries are 2.7 GB where they
-    were 4.2 (compiled for a described v5e)."""
-    b, s = c_kv.shape[:2]
-    hg = _HEAD_GROUP if heads > _HEAD_GROUP and heads % _HEAD_GROUP == 0 else heads
-
-    def group(args):
-        q_n, q_r, w = args  # [B, S, hg, .] queries, the group's columns of W_kvb
-        kv = jnp.dot(c_kv, w).reshape(b, s, hg, nope + v_dim)
-        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_r[:, :, None], (b, s, hg, k_r.shape[-1]))], -1)
-        return _causal_attention(jnp.concatenate([q_n, q_r], -1), k, kv[..., nope:], scale)
-
-    if hg == heads:
-        return group((q_nope, q_rope, w_kvb)).reshape(b, s, heads * v_dim)
-    n = heads // hg
-
-    def by_group(x):  # [B, S, H, d] -> [n, B, S, hg, d]
-        return jnp.moveaxis(x.reshape(b, s, n, hg, x.shape[-1]), 2, 0)
-
-    out = jax.lax.map(group, (by_group(q_nope), by_group(q_rope),
-                              jnp.moveaxis(w_kvb.reshape(-1, n, hg * (nope + v_dim)), 1, 0)))
-    return jnp.moveaxis(out, 0, 2).reshape(b, s, heads * v_dim)
-
-
-def mla_absorb_query(q_nope, q_rope, w_kvb, *, heads, nope, v_dim):
-    """The query as the latent cache is read: `q_nope W_UK` then `q_rope`,
-    [B, S, H, rank + rope]."""
-    w_uk = w_kvb.reshape(w_kvb.shape[0], heads, nope + v_dim)[..., :nope]  # [rank, H, nope]
-    q_lat = jnp.einsum("bshd,chd->bshc", q_nope, w_uk, preferred_element_type=jnp.float32)
-    return jnp.concatenate([q_lat.astype(q_nope.dtype), q_rope], -1)
-
-
-def mla_unabsorb_context(ctx, w_kvb, *, heads, nope, v_dim):
-    """The context summed in the latent [..., H, rank] through `W_UV`:
-    [..., H * v_dim]."""
-    w_uv = w_kvb.reshape(w_kvb.shape[0], heads, nope + v_dim)[..., nope:]  # [rank, H, v]
-    out = jnp.einsum("...hc,chd->...hd", ctx, w_uv, preferred_element_type=jnp.float32)
-    return out.astype(ctx.dtype).reshape(*ctx.shape[:-2], heads * v_dim)
 
 
 class PanguMLAttention(nn.Layer):
@@ -189,14 +83,14 @@ class PanguMLAttention(nn.Layer):
             dims = dict(self.dims, positions=None, max_pos=s)
 
             def f(xv, *w):
-                return mla_expanded(*mla_project(xv, *w[:5], **dims), w[5], **hd, scale=scale)
+                return mla_expanded(*mla_project(xv, *w[:5], **dims)[:4], w[5], **hd, scale=scale)
 
             return self.o_proj(apply("mla", f, x, *self._leaves()))
         # ---- serving cache mode (inference-only) ----
         idx, rank = self.layer_idx, self.dims["rank"]
         w = [t.value for t in self._leaves()]
         pos2d = positions_2d(positions, b)
-        q_nope, q_rope, c_kv, k_r = mla_project(
+        q_nope, q_rope, c_kv, k_r, _ = mla_project(
             x.value, *w[:5], **self.dims, positions=pos2d,
             max_pos=cache.block_tables.shape[1] * cache.block_size)
         entry = jnp.concatenate([c_kv, k_r], -1)  # what the layer caches: [B, S, rank + rope]
@@ -215,78 +109,6 @@ class PanguMLAttention(nn.Layer):
         out = attend_through_cache(cache, idx, q, (entry,), pos2d, prefill=prefill,
                                    read_one=read_one, read_many=read_many)
         return self.o_proj(Tensor(out.reshape(b, s, -1)))
-
-
-# ---------------------------------------------------------------------------
-# feed-forward: dense, and the share of a sparse layer
-# ---------------------------------------------------------------------------
-
-def gated_mlp(x, w_gate, w_up, w_down):
-    """`(silu(x W_g) * (x W_u)) W_d`, float32 out. The two wide products come
-    out in the storage dtype (a prefill's are [tokens, width] each: float32
-    would double them), the gate is applied in float32."""
-    h = jax.nn.silu(jnp.dot(x, w_gate).astype(jnp.float32)) * jnp.dot(x, w_up).astype(jnp.float32)
-    return _dot_f32(h.astype(x.dtype), w_down)
-
-
-def sparse_mlp(x, w_router, e_gate, e_up, e_down, s_gate, s_up, s_down, *, top_k, scale, first, valid=None):
-    """x [T, hidden] -> (this share's output [T, hidden], assignments,
-    experts touched): the held experts' part of the routed sum plus the
-    shared expert, whole on every chip."""
-    chosen, weights = route_topk(x, w_router, None, top_k, scale)
-    routed, n_assign, n_touched = routed_experts(x, chosen, weights, e_up, e_down, first, valid,
-                                                 w_gate=e_gate, activation="silu")
-    return (routed + gated_mlp(x, s_gate, s_up, s_down)).astype(x.dtype), n_assign, n_touched
-
-
-class PanguMLP(nn.Layer):
-    def __init__(self, hidden_size, intermediate_size):
-        super().__init__()
-        self.gate_proj = nn.Linear(hidden_size, intermediate_size, bias_attr=False)
-        self.up_proj = nn.Linear(hidden_size, intermediate_size, bias_attr=False)
-        self.down_proj = nn.Linear(intermediate_size, hidden_size, bias_attr=False)
-
-    def _leaves(self):
-        return (self.gate_proj.weight, self.up_proj.weight, self.down_proj.weight)
-
-    def forward(self, x, cache=None, positions=None):
-        return apply("gated_mlp", lambda xv, *w: gated_mlp(xv, *w).astype(xv.dtype), x, *self._leaves())
-
-
-class PanguSparseMLP(nn.Layer):
-    def __init__(self, hidden_size, n_routed_experts, experts_held, top_k, moe_intermediate_size,
-                 shared_intermediate_size, routed_scaling_factor, initializer_range=0.02):
-        super().__init__()
-        from ..nn.initializer import Normal
-
-        first, count = (int(v) for v in experts_held)
-        if first < 0 or count < 1 or first + count > n_routed_experts:
-            raise ValueError(f"experts_held {experts_held} outside the {n_routed_experts} routed experts")
-        self.kw = dict(top_k=int(top_k), scale=float(routed_scaling_factor), first=first)
-        init = Normal(0.0, initializer_range)
-        self.router = self.create_parameter([hidden_size, n_routed_experts], default_initializer=init)
-        shape = [count, hidden_size, moe_intermediate_size]
-        self.experts_gate = self.create_parameter(shape, default_initializer=init)
-        self.experts_up = self.create_parameter(shape, default_initializer=init)
-        self.experts_down = self.create_parameter([count, moe_intermediate_size, hidden_size],
-                                                  default_initializer=init)
-        self.shared_experts = PanguMLP(hidden_size, shared_intermediate_size)
-
-    def _leaves(self):
-        return (self.router, self.experts_gate, self.experts_up, self.experts_down,
-                *self.shared_experts._leaves())
-
-    def forward(self, x, cache=None, positions=None):
-        b, s, h = x.shape
-        if cache is None:
-            return apply("sparse_mlp",
-                         lambda xv, *w: sparse_mlp(xv.reshape(b * s, h), *w, **self.kw)[0].reshape(b, s, h),
-                         x, *self._leaves())
-        valid = cache.token_mask(b, s, positions)
-        out, n_assign, n_touched = sparse_mlp(x.value.reshape(b * s, h), *[t.value for t in self._leaves()],
-                                              valid=valid.reshape(-1), **self.kw)
-        cache.count_moe(n_assign, n_touched)
-        return Tensor(out.reshape(b, s, h))
 
 
 # ---------------------------------------------------------------------------
@@ -326,9 +148,9 @@ class PanguUltraMoEModel(nn.Layer):
                                     qk_nope_head_dim, qk_rope_head_dim, v_head_dim, rms_norm_eps, rope_theta)
             attn.layer_idx = i
             if i < first_k_dense_replace:
-                mlp = PanguMLP(hidden_size, intermediate_size)
+                mlp = GatedMLP(hidden_size, intermediate_size)
             else:
-                mlp = PanguSparseMLP(hidden_size, n_routed_experts, held, num_experts_per_tok,
+                mlp = SparseMLP(hidden_size, n_routed_experts, held, num_experts_per_tok,
                                      moe_intermediate_size, n_shared_experts * moe_intermediate_size,
                                      routed_scaling_factor, initializer_range)
             return PanguDecoderLayer(hidden_size, rms_norm_eps, attn, mlp)

@@ -1290,7 +1290,11 @@ def mla_live_blocks(first, count, q_len, heads, block_size, table_width):
     to the position of the tile's last real query (`first + count - 1` at
     most), one for a tile of padding. numpy or jax `first` / `count` [B]
     alike (the serving engine counts with this too)."""
-    tq = mla_query_tile(heads, q_len)
+    return _tile_live_blocks(first, count, q_len, mla_query_tile(heads, q_len), block_size, table_width)
+
+
+def _tile_live_blocks(first, count, q_len, tq, block_size, table_width):
+    """`mla_live_blocks` for tiles of `tq` queries (`dsa_index` holds larger ones)."""
     tiles = -(-q_len // tq)
     pages, blocks = mla_page_blocks(block_size, table_width)
     start = np.arange(tiles, dtype=np.int32)[None, :] * tq  # each tile's first query
@@ -1364,6 +1368,18 @@ def _mla_paged_kernel(pages, width, heads, tq, tiles, value_width, scale):
     return kernel
 
 
+def _tile_page_spec(j, pages, tiles, block_size, width):
+    """The j-th page of a grid step's page block, for a grid (rows, query
+    tiles, page blocks) whose scalar operands are (block table, (first, count)
+    a row, live blocks a tile): a step past the tile's live blocks names the
+    last live block's page again, so it starts no copy."""
+    def index(bi, ti, pi, bt, qp, lv):
+        col = jnp.minimum(pi, lv[bi * tiles + ti] - 1) * pages + j
+        return (bt[bi, col], 0, 0)
+
+    return pl.BlockSpec((None, block_size, width), index)
+
+
 def _mla_paged_impl(q, pages_arr, block_tables, q_positions, value_width, sm_scale):
     n, bs, w = pages_arr.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
@@ -1381,18 +1397,11 @@ def _mla_paged_impl(q, pages_arr, block_tables, q_positions, value_width, sm_sca
     live = mla_live_blocks(first, count, qn, h, bs, m).astype(jnp.int32).reshape(-1)
     qr = jnp.pad(q, ((0, 0), (0, tiles * tq - qn), (0, 0), (0, 0))).reshape(b, tiles * rows, w)
 
-    def page_spec(j):
-        def index(bi, ti, pi, bt, qp, lv):
-            col = jnp.minimum(pi, lv[bi * tiles + ti] - 1) * pages + j
-            return (bt[bi, col], 0, 0)
-
-        return pl.BlockSpec((None, bs, w), index)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # block table, (first, count) a row, live blocks a tile
         grid=(b, tiles, blocks),
         in_specs=[pl.BlockSpec((None, rows, w), lambda bi, ti, pi, *_: (bi, ti, 0))]
-        + [page_spec(j) for j in range(pages)],
+        + [_tile_page_spec(j, pages, tiles, bs, w) for j in range(pages)],
         out_specs=pl.BlockSpec((None, rows, value_width), lambda bi, ti, pi, *_: (bi, ti, 0)),
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),
@@ -1463,6 +1472,347 @@ def mla_paged_attention(q, pages, block_tables, q_positions, value_width, sm_sca
         with jax.enable_x64(False):
             return _mla_paged_jit(q, pages, block_tables, q_positions, value_width, sm_scale)
     return mla_paged_reference(q, pages, block_tables, q_positions, value_width, sm_scale)
+
+
+# ---------------------------------------------------------------------------
+# learned sparse attention over a latent pool (dsa_index, mla_sparse_paged_attn)
+# ---------------------------------------------------------------------------
+#
+# A decoder with a token selector (DeepSeek sparse attention) keeps, beside a
+# token's latent entry, an INDEX KEY (one lane tile wide) under the same page
+# and slot. A query first scores every cached position of its sequence with a
+# light many-headed dot product against those keys,
+#
+#     I[t, s] = sum_j w[t, j] * relu(q_idx[t, j] . k_idx[s])      (s <= t)
+#
+# (`dsa_index`), keeps the `topk` positions of largest score (`dsa_select`,
+# exact), and attends, in the absorbed latent form, over the chosen positions'
+# entries alone (`mla_sparse_attention`): what it reads of the latent pool
+# scales with the positions chosen, not with the context.
+#
+# `dsa_index`: the grid is (rows, query tiles, page blocks) over the index-key
+# pages [N, bs, D], as `mla_paged_attn`'s over the latent ones: a query tile is
+# up to `_DSA_TILE_QUERIES` consecutive queries of a row with all their index
+# heads as [queries * J, D] rows, held in VMEM while the row's page blocks
+# (`_MLA_POSITIONS` positions each) pass under it, so a page is copied once a
+# tile; inside a step the tile's queries are scored `sub` at a time ([sub * J,
+# positions] float32 logits), ReLU, each head's weight (a column, float32),
+# the sum over heads. Blocks past a tile's frontier compute nothing and start
+# no copy; they, and positions past a query's own, read -inf.
+#
+# `mla_sparse_paged_attn`: the chosen positions come out of the selection as
+# rows of the pool (page * bs + slot: `pool_rows` rides the sort, so no lookup
+# through the block table follows) and are gathered into [queries, K, W]
+# (K = topk; XLA's gather: 2 x W bytes a (query, chosen position) pair, nothing
+# of [queries, context, W]); the kernel's grid is (queries, K blocks), a step
+# all H heads of ONE query ([H, W] rows) against a block of its own entries,
+# the first `value_width` columns also the value, the slots past the query's
+# count of chosen positions masked.
+
+_DSA_TILE_QUERIES = 128   # queries of a row the index kernel holds in VMEM
+_DSA_SUB_ROWS = 1024      # (query, head) rows it scores at a time
+_DSA_SPARSE_POSITIONS = 2048  # chosen entries a step of the sparse kernel holds
+
+
+def dsa_query_tiles(heads: int, q_len: int):
+    """(queries scored at a time, queries a grid step of `dsa_index` holds):
+    whole sublane tiles of (query, head) rows, about `_DSA_SUB_ROWS` rows at a
+    time, `_DSA_TILE_QUERIES` queries a step at most, never more than the
+    call has (rounded up to the unit)."""
+    unit = 16 // math.gcd(heads, 16)
+    sub = unit * max(1, min(-(-q_len // unit), _DSA_SUB_ROWS // (unit * heads)))
+    return sub, sub * max(1, min(-(-q_len // sub), _DSA_TILE_QUERIES // sub))
+
+
+def dsa_live_blocks(first, count, q_len, heads, block_size, table_width):
+    """[B, tiles] page blocks each query tile of `dsa_index` scores: up to the
+    position of the tile's last real query, one for a tile of padding."""
+    return _tile_live_blocks(first, count, q_len, dsa_query_tiles(heads, q_len)[1], block_size, table_width)
+
+
+def dsa_index_reference(q, w, pages, block_tables, q_positions):
+    """jnp oracle for `dsa_index` (and the off-TPU path). q [B, Q, J, D] index
+    queries, w [B, Q, J] float32 head weights, pages [N, bs, D] index keys;
+    returns scores [B, Q, M * bs] float32: `sum_j w_j relu(q_j . k_s)` at the
+    positions s <= q_positions[b, q], -inf past them."""
+    d = q.shape[-1]
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    q_positions = jnp.asarray(q_positions, jnp.int32)
+
+    def one(qb, wb, bt, qp):
+        kg = pages[bt].reshape(-1, d)
+        dots = jnp.einsum("qjd,sd->qjs", qb, kg, preferred_element_type=jnp.float32)
+        sc = jnp.sum(jnp.maximum(dots, 0.0) * wb[..., None].astype(jnp.float32), axis=1)
+        pos = jnp.arange(kg.shape[0], dtype=jnp.int32)
+        return jnp.where(pos[None, :] <= qp[:, None], sc, -jnp.inf)
+
+    return jax.vmap(one)(q, w, block_tables, q_positions)
+
+
+def _dsa_index_kernel(pages, width, heads, sub, tq, tiles):
+    def kernel(bt_ref, qpos_ref, live_ref, q_ref, w_ref, *rest):
+        page_refs, o_ref = rest[:pages], rest[pages]
+        b = pl.program_id(0)
+        t = pl.program_id(1)
+        i = pl.program_id(2)
+        live = live_ref[b * tiles + t]
+
+        @pl.when(i >= live)
+        def _dead():
+            o_ref[...] = jnp.full_like(o_ref, -jnp.inf)
+
+        @pl.when(i < live)
+        def _block():
+            kb = jnp.concatenate([r[...] for r in page_refs], axis=0)  # [width, D]
+            pos = i * width + lax.broadcasted_iota(jnp.int32, (sub, width), 1)
+            for s in range(tq // sub):
+                rows = pl.ds(s * sub * heads, sub * heads)
+                dots = _dot_nt(q_ref[rows, :], kb)  # [sub * J, width] f32
+                sc = (jnp.maximum(dots, 0.0) * w_ref[rows, :]).reshape(sub, heads, width).sum(axis=1)
+                qi = t * tq + s * sub + lax.broadcasted_iota(jnp.int32, (sub, width), 0)
+                frontier = jnp.where(qi < qpos_ref[b, 1], qpos_ref[b, 0] + qi, 0)
+                o_ref[pl.ds(s * sub, sub), :] = jnp.where(pos <= frontier, sc, -jnp.inf)
+
+    return kernel
+
+
+def _dsa_index_impl(q, w, pages_arr, block_tables, q_positions):
+    n, bs, d = pages_arr.shape
+    b, qn, h, _ = q.shape
+    m = block_tables.shape[1]
+    pages, blocks = mla_page_blocks(bs, m)
+    width = pages * bs
+    block_tables = jnp.pad(block_tables, ((0, 0), (0, blocks * pages - m)))
+    sub, tq = dsa_query_tiles(h, qn)
+    tiles = -(-qn // tq)
+    rows = tq * h
+    first = q_positions[:, 0]
+    count = jnp.max(q_positions, axis=1) - first + 1
+    live = dsa_live_blocks(first, count, qn, h, bs, m).astype(jnp.int32).reshape(-1)
+    pad = ((0, 0), (0, tiles * tq - qn), (0, 0))
+    qr = jnp.pad(q, pad + ((0, 0),)).reshape(b, tiles * rows, d)
+    wr = jnp.pad(w.astype(jnp.float32), pad).reshape(b, tiles * rows, 1)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # block table, (first, count) a row, live blocks a tile
+        grid=(b, tiles, blocks),
+        in_specs=[pl.BlockSpec((None, rows, d), lambda bi, ti, pi, *_: (bi, ti, 0)),
+                  pl.BlockSpec((None, rows, 1), lambda bi, ti, pi, *_: (bi, ti, 0))]
+        + [_tile_page_spec(j, pages, tiles, bs, d) for j in range(pages)],
+        out_specs=pl.BlockSpec((None, tq, width), lambda bi, ti, pi, *_: (bi, ti, pi)),
+    )
+    out = pl.pallas_call(
+        _dsa_index_kernel(pages, width, h, sub, tq, tiles),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, tiles * tq, blocks * width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=_INTERPRET,
+        name="dsa_index",
+    )(block_tables, jnp.stack([first, count], axis=1), live, qr, wr, *(pages * [pages_arr]))
+    return out[:, :qn, :m * bs]
+
+
+@jax.jit
+def _dsa_index_jit(q, w, pages, block_tables, q_positions):
+    return _dsa_index_impl(q, w, pages, block_tables, q_positions)
+
+
+def dsa_index_usable(q, pages) -> bool:
+    """Kernel constraints: TPU platform (or interpret mode), page slots a
+    multiple of the sublane, keys whole lane tiles wide."""
+    if not _on_tpu() or q.ndim != 4 or pages.ndim != 3:
+        return False
+    return pages.shape[1] % _DECODE_SUBLANE == 0 and pages.shape[2] % _DECODE_LANES == 0
+
+
+def dsa_index_scores(q, w, pages, block_tables, q_positions):
+    """Index scores of a step's queries against the paged index keys.
+
+    q            [B, Q, J, D]  — Q index queries a row, J heads each
+    w            [B, Q, J]     — each head's weight (float32)
+    pages        [N, bs, D]    — the pool's index-key pages (one model layer)
+    block_tables [B, M] int32  — page indices, padded with the reserved page 0
+    q_positions  [B, Q] int32  — each query's position, CONSECUTIVE from the
+                                 row's first, pad slots 0 (as `mla_paged_attention`)
+
+    Returns [B, Q, M * bs] float32: `sum_j w_j relu(q_j . k_s)` for the
+    positions s up to each query's own, -inf past them. Dispatches the Pallas
+    kernel `dsa_index` on TPU (or under interpret mode), else the jnp
+    reference."""
+    if q.ndim != 4 or pages.ndim != 3 or q.shape[-1] != pages.shape[2] or w.shape != q.shape[:3]:
+        raise ValueError(f"dsa_index_scores: q {q.shape}, w {w.shape} do not fit index pages {pages.shape} "
+                         "([B, Q, J, D], [B, Q, J] against [N, bs, D])")
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    q_positions = jnp.asarray(q_positions, jnp.int32)
+    if dsa_index_usable(q, pages):
+        with jax.enable_x64(False):
+            return _dsa_index_jit(q, w, pages, block_tables, q_positions)
+    return dsa_index_reference(q, w, pages, block_tables, q_positions)
+
+
+def pool_rows(block_tables, block_size: int):
+    """[B, M * bs] int32: the row of the pool (page * bs + slot, the pool seen
+    as [N * bs, W]) that holds each position of a row's sequence."""
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    rows = block_tables[:, :, None] * block_size + jnp.arange(block_size, dtype=jnp.int32)
+    return rows.reshape(block_tables.shape[0], -1)
+
+
+def dsa_select(scores, topk: int, carry=None, frontier=None):
+    """The `min(topk, P)` positions of largest score a query, [B, Q, K] int32,
+    best first; exact: ONE stable sort of a query's scores (of equal scores
+    the earlier position). `carry` [B, P] int32 rides the sort in the
+    positions' place, so that what comes back is `carry` at the chosen
+    positions (their rows of the pool: no lookup of 2048 positions a query
+    afterwards). `frontier` (a traced scalar: every score at or past it is
+    -inf) lets the sort run over the narrowest of a quarter, a half or all of
+    the P positions that holds the frontier. A query with fewer than K
+    positions at or before its own has -inf scores chosen last: the reader
+    masks them by the query's count."""
+    b, _, p = scores.shape
+    k = min(int(topk), p)
+    if carry is None:
+        carry = jnp.broadcast_to(jnp.arange(p, dtype=jnp.int32), (b, p))
+
+    def over(width):
+        def pick(scores, carry):
+            keys = -scores[..., :width]
+            vals = jnp.broadcast_to(carry[:, None, :width], keys.shape)
+            return lax.sort_key_val(keys, vals, dimension=-1, is_stable=True)[1][..., :k]
+
+        return pick
+
+    widths = sorted({w for w in (p // 4, p // 2) if w >= k and w % _DECODE_LANES == 0} | {p})
+    with jax.enable_x64(False):
+        if frontier is None or len(widths) == 1:
+            return over(p)(scores, carry)
+        branch = sum((frontier > w).astype(jnp.int32) for w in widths[:-1])
+        return lax.switch(branch, [over(w) for w in widths], scores, carry)
+
+
+def mla_sparse_reference(q, pages, rows, counts, value_width, sm_scale=None):
+    """jnp oracle for the sparse latent attention (and the off-TPU path). q
+    [B, Q, H, E] absorbed queries; pages [N, bs, W]; rows [B, Q, K] int32 rows
+    of the pool (`pool_rows`) of each query's chosen positions; counts [B, Q]:
+    query (b, q) attends to its first counts[b, q] of them. Returns
+    [B, Q, H, value_width]."""
+    e = q.shape[-1]
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(e)
+    flat = pages.reshape(-1, pages.shape[-1])
+
+    def one(qb, ch, cnt):
+        kg = flat[ch][..., :e]  # [Q, K, E]
+        logits = jnp.einsum("qhe,qke->qhk", qb, kg, preferred_element_type=jnp.float32) * scale
+        live = jnp.arange(ch.shape[-1], dtype=jnp.int32)[None, :] < cnt[:, None]
+        p = jax.nn.softmax(jnp.where(live[:, None, :], logits, -1e30), axis=-1).astype(kg.dtype)
+        return jnp.einsum("qhk,qkv->qhv", p, kg[..., :value_width],
+                          preferred_element_type=jnp.float32).astype(qb.dtype)
+
+    return jax.vmap(one)(q, jnp.asarray(rows, jnp.int32), jnp.asarray(counts, jnp.int32))
+
+
+def _mla_sparse_kernel(width, value_width, scale):
+    def kernel(cnt_ref, q_ref, kv_ref, o_ref, m_scr, l_scr, acc_scr):
+        r = pl.program_id(0)
+        i = pl.program_id(1)
+
+        @pl.when(i == 0)
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, -1e30)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        @pl.when(i * width < jnp.maximum(cnt_ref[r], 1))
+        def _block():
+            kb = kv_ref[...]  # [width, W], the query's own chosen entries
+            logits = _dot_nt(q_ref[...], kb) * scale  # [H, width] f32
+            slot = i * width + lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+            logits = jnp.where(slot < cnt_ref[r], logits, -1e30)
+            m_prev = m_scr[...]
+            m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+            p = jnp.exp(logits - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[...] = acc_scr[...] * alpha + _dot_nn(p.astype(kb.dtype), kb[:, :value_width])
+            m_scr[...] = m_new
+
+        @pl.when(i == pl.num_programs(1) - 1)
+        def _emit():
+            o_ref[...] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+    return kernel
+
+
+def _mla_sparse_impl(q, pages_arr, rows, counts, value_width, sm_scale):
+    n, bs, w = pages_arr.shape
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    b, qn, h, _ = q.shape
+    k = rows.shape[-1]
+    width = min(_DSA_SPARSE_POSITIONS, -(-k // 16) * 16)
+    blocks = -(-k // width)
+    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, blocks * width - k)))  # the trash page's: masked by the count
+    # ONE gather of whole entries: [queries, K, W]
+    entries = jnp.take(pages_arr.reshape(n * bs, w), rows.reshape(-1), axis=0).reshape(b * qn, blocks * width, w)
+    qr = jnp.pad(q, ((0, 0),) * 3 + ((0, w - q.shape[-1]),)).reshape(b * qn, h, w)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # chosen positions a query
+        grid=(b * qn, blocks),
+        in_specs=[pl.BlockSpec((None, h, w), lambda r, i, *_: (r, 0, 0)),
+                  pl.BlockSpec((None, width, w), lambda r, i, *_: (r, i, 0))],
+        out_specs=pl.BlockSpec((None, h, value_width), lambda r, i, *_: (r, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, value_width), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        _mla_sparse_kernel(width, value_width, scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b * qn, h, value_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=_INTERPRET,
+        name="mla_sparse_paged_attn",
+    )(counts.reshape(-1).astype(jnp.int32), qr, entries)
+    return out.reshape(b, qn, h, value_width)
+
+
+@functools.partial(jax.jit, static_argnames=("value_width", "sm_scale"))
+def _mla_sparse_jit(q, pages, rows, counts, value_width, sm_scale=None):
+    return _mla_sparse_impl(q, pages, rows, counts, value_width, sm_scale)
+
+
+def mla_sparse_attention(q, pages, rows, counts, value_width, sm_scale=None):
+    """Absorbed multi-head latent attention over CHOSEN positions of a paged
+    latent cache.
+
+    q       [B, Q, H, E]    — absorbed queries (as `mla_paged_attention`)
+    pages   [N, bs, W]      — the pool's latent pages (one model layer)
+    rows    [B, Q, K] int32 — each query's chosen positions as rows of the pool
+                              (page * bs + slot: `dsa_select` with `carry=
+                              pool_rows(block_tables, bs)`), any order, the
+                              real ones first
+    counts  [B, Q] int32    — how many of them are real (at least 1)
+
+    Returns the context in the latent, [B, Q, H, value_width]. What it reads
+    of the pool is the chosen entries alone (one gather of [B * Q * K] whole
+    entries), whatever the context. Dispatches the Pallas kernel
+    `mla_sparse_paged_attn` on TPU (or under interpret mode), else the jnp
+    reference."""
+    if q.ndim != 4 or pages.ndim != 3 or q.shape[-1] > pages.shape[2] or rows.shape[:2] != q.shape[:2]:
+        raise ValueError(f"mla_sparse_attention: q {q.shape}, rows {rows.shape} do not fit latent "
+                         f"pages {pages.shape} ([B, Q, H, E], [B, Q, K] against [N, bs, W >= E])")
+    rows, counts = jnp.asarray(rows, jnp.int32), jnp.asarray(counts, jnp.int32)
+    if mla_paged_usable(q, pages, value_width):
+        with jax.enable_x64(False):
+            return _mla_sparse_jit(q, pages, rows, counts, value_width, sm_scale)
+    return mla_sparse_reference(q, pages, rows, counts, value_width, sm_scale)
 
 
 # ---------------------------------------------------------------------------

@@ -319,6 +319,75 @@ def test_latent_engine_programs_compile_at_published_widths(one_chip, monkeypatc
         assert compiled.memory_analysis().temp_size_in_bytes < 96 << 20   # a copy of the pool is 178 MB
 
 
+@pytest.mark.parametrize("program, size, kernels", [
+    ("decode", 8, {"dsa_index": 2, "mla_sparse_paged_attn": 2, "mla_paged_attn": 2, "moe_gmm": 2}),
+    ("chunk", 8, {"dsa_index": 4, "mla_sparse_paged_attn": 4, "mla_paged_attn": 4, "moe_gmm": 2}),  # rows and chunk
+    ("prefill", 4096, {"dsa_index": 2, "mla_sparse_paged_attn": 2, "mla_paged_attn": 2, "moe_gmm": 2}),
+], ids=["decode_b8", "chunk_b8_c128", "prefill_s4096"])
+def test_sparse_latent_engine_programs_compile_at_published_widths(one_chip, monkeypatch, program, size, kernels):
+    """The long-context cell's engine over a decoder of DeepSeek-V3.2's
+    published widths, one leading dense and one sparse layer (16 of 256
+    experts held): the 8-row decode, the chunk step (8 rows and 128 prompt
+    tokens) and the 4096 prefill bucket, which attends through the cache in
+    tiles of 128 queries. A layer keeps two arrays under one page id, the
+    latent entries `[8705, 16, 640]` and the index keys `[8705, 16, 128]`; no
+    program holds a `copy` of either shape (the three write paths land in
+    place, the kernels and the gather of chosen entries read the pool where
+    it lies), and each tile carries both branches: the selector's two kernels
+    and the dense kernel for a tile below `index_topk`."""
+    from paddle_tpu.models.deepseek_v32 import DeepseekV32ForCausalLM
+
+    engine = _described_engine(
+        one_chip, monkeypatch,
+        lambda: DeepseekV32ForCausalLM(
+            vocab_size=16160, hidden_size=7168, num_hidden_layers=2, first_k_dense_replace=1,
+            num_attention_heads=128, q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128, index_n_heads=64, index_head_dim=128, index_topk=2048,
+            intermediate_size=18432, moe_intermediate_size=2048, n_routed_experts=256, experts_held=[0, 16],
+            num_experts_per_tok=8, n_group=8, topk_group=4,
+            rope_scaling={"factor": 40, "original_max_position_embeddings": 4096, "beta_fast": 32,
+                          "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1}),
+        max_seq_len=17408, block_size=16, num_blocks=8705, max_batch=8,
+        prefill_buckets=(4096, 8192), decode_batch_buckets=(1, 2, 4, 8))
+    pool = engine._state_avals()
+    assert pool["k"][0].shape == (8705, 16, 640) and pool["index"][0].shape == (8705, 16, 128)
+    assert pool["v"] == [] and engine.chunk_width == 128 and engine.index_topk == 2048
+    compiled = getattr(engine, "_compile_" + program)(size)
+    text = compiled.as_text()
+    names = _kernels_of(text)
+    assert {k: names.count(k) for k in set(names)} == kernels
+    for aval in (pool["k"][0], pool["index"][0]):
+        assert _pool_copies(compiled, aval) == []
+    # a tile's gathered entries are 128 x 2048 x 640 bf16 = 335 MB, held twice; a copy of the latent pool is 178 MB more
+    limit = {"decode": 96 << 20, "chunk": 1024 << 20, "prefill": 2048 << 20}[program]
+    assert compiled.memory_analysis().temp_size_in_bytes < limit
+
+
+def test_selector_kernels_compile_for_rows_chunk_and_extend(one_chip):
+    """The selector's two kernels alone at the cell's shapes: 8 rows of one
+    query, one row of a 128-query chunk and a (4, 4) extend, over the
+    `[8705, 16, 128]` index keys and the `[8705, 16, 640]` latent pool and a
+    1088-page table; and the exact selection between them (a sort of the
+    table's 17,408 positions a query)."""
+    keys, lat = _aval(one_chip, (8705, 16, 128), BF16), _aval(one_chip, (8705, 16, 640), BF16)
+
+    def attend(q, lat, rows, counts):
+        return pk.mla_sparse_attention(q, lat, rows, counts, 512, 192 ** -0.5)
+
+    for b, q_len in ((8, 1), (1, 128), (4, 4)):
+        table = _aval(one_chip, (b, 1088), jnp.int32)
+        assert _kernel_names(pk.dsa_index_scores, _aval(one_chip, (b, q_len, 64, 128), BF16),
+                             _aval(one_chip, (b, q_len, 64), jnp.float32), keys, table,
+                             _aval(one_chip, (b, q_len), jnp.int32)) == ["dsa_index"]
+        assert _kernel_names(attend, _aval(one_chip, (b, q_len, 128, 576), BF16), lat,
+                             _aval(one_chip, (b, q_len, 2048), jnp.int32),
+                             _aval(one_chip, (b, q_len), jnp.int32)) == ["mla_sparse_paged_attn"]
+    chosen = jax.jit(lambda s, c, f: pk.dsa_select(s, 2048, carry=c, frontier=f)).lower(
+        _aval(one_chip, (1, 128, 17408), jnp.float32), _aval(one_chip, (1, 17408), jnp.int32),
+        _aval(one_chip, (), jnp.int32)).compile()
+    assert "s32[1,128,2048]" in chosen.as_text() and chosen.as_text().count(" sort(") == 3  # a quarter, a half, all
+
+
 def test_hybrid_chunk_program_updates_the_state_in_place_at_published_widths(one_chip, monkeypatch):
     """The reasoning cell's engine over a hybrid of Nemotron-3-Super's
     published widths (two state-space layers, one attention layer, two expert
@@ -389,6 +458,9 @@ LOWERED = {
     ("pangu", "prefill", 32): "f04d8608c8a0240d",
     ("pangu", "chunk", 4): "e6d82c4247740c66",
     ("pangu", "extend", (4, 4)): "7eac7881ec210019",
+    ("hybrid", "decode", 4): "47969056fd6d512a",   # PR 34: the tree of PR 32 lowers to these three
+    ("hybrid", "prefill", 32): "4d56eb5cb37d11c8",
+    ("hybrid", "chunk", 4): "ef603b09643ac3d5",
 }
 
 
@@ -409,6 +481,10 @@ def test_dense_and_latent_programs_lower_to_what_they_did(monkeypatch, model, pr
         from paddle_tpu.models.llama import llama_tiny
 
         net = llama_tiny(num_key_value_heads=2)
+    elif model == "hybrid":
+        from paddle_tpu.models.nemotron_h import NemotronHForCausalLM
+
+        net = NemotronHForCausalLM()
     else:
         from paddle_tpu.models.pangu_ultra_moe import PanguUltraMoEForCausalLM
 

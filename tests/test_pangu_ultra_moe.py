@@ -28,6 +28,7 @@ from paddle_tpu.inference.engine import InferenceEngine
 from paddle_tpu.inference.kv_cache import BlockPool, PagedCacheView, export_pages, import_pages
 from paddle_tpu.inference.scheduler import ContinuousBatchingScheduler, Request
 from paddle_tpu.models import expert_share
+from paddle_tpu.models import mla_moe
 from paddle_tpu.models import pangu_ultra_moe as pm
 from paddle_tpu.ops import pallas as pk
 from paddle_tpu.profiler import utils as spans
@@ -209,7 +210,7 @@ def test_absorbed_attention_equals_expanded(ref, seeded, group, monkeypatch):
     through `mla_paged_attention`). Same mathematics, other order of sums:
     float32 roundings."""
     model, _ = seeded
-    monkeypatch.setattr(pm, "_HEAD_GROUP", group)
+    monkeypatch.setattr(mla_moe, "_HEAD_GROUP", group)
     attn = model.model.layers[1].self_attn
     x = paddle.to_tensor(np.random.RandomState(2).randn(1, 24, 64).astype(np.float32))
     with paddle.no_grad():

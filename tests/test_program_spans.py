@@ -465,3 +465,72 @@ def test_profiler_host_events_are_the_rings_records():
     # one store: what Profiler reported is what the ring holds
     ring = {r[0]: r for r in spans.records()}
     assert ev["ts"] == pytest.approx(ring["inside"][1] * 1e6, abs=1.0)
+
+
+# ---------------------------------------------------------------------------
+# a model that selects cached tokens: what its selector scored and chose
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def selecting_engine():
+    from paddle_tpu.inference.engine import InferenceEngine
+    from paddle_tpu.models.deepseek_v32 import DeepseekV32ForCausalLM
+
+    paddle.seed(0)
+    model = DeepseekV32ForCausalLM(index_topk=8)
+    model.eval()
+    return InferenceEngine(model, max_seq_len=64, block_size=8, max_batch=4)
+
+
+INDEX_KEYS = ("index_positions_live", "index_positions_selected", "sparse_queries")
+
+
+@pytest.mark.parametrize("call, want", [
+    # one row at position 20: 21 positions scored, 8 chosen; a row at 3: all 4 chosen, no selection runs for it alone
+    ("decode", {"index_positions_live": 21 + 4, "index_positions_selected": 8 + 4, "sparse_queries": 1,
+                "index_positions_scored": 25, "sparse_positions_attended": 12, "index_keys_read": 25}),
+    ("decode_low", {"index_positions_live": 4 + 6, "index_positions_selected": 4 + 6, "sparse_queries": 0}),
+    # 12 prompt tokens: contexts 1..12, the first 8 whole, the last 4 cut to 8
+    ("prefill", {"index_positions_live": 78, "index_positions_selected": 36 + 32, "sparse_queries": 4,
+                 "index_positions_scored": 78, "sparse_positions_attended": 68, "index_keys_read": 12}),
+    # two rows of 3 and 2 queries from positions 10 and 2
+    ("extend", {"index_positions_live": (11 + 12 + 13) + (3 + 4), "index_positions_selected": 24 + 7,
+                "sparse_queries": 3, "index_positions_scored": 43, "sparse_positions_attended": 31,
+                "index_keys_read": 13 + 4}),
+], ids=["decode", "decode_below_topk", "prefill", "extend"])
+def test_engine_spans_count_what_the_selector_scored_and_chose(selecting_engine, call, want):
+    engine = selecting_engine
+    pages = [engine.pool.alloc(3, owner=i) for i in range(2)]
+    try:
+        if call == "decode":
+            engine.decode(tokens=[5, 6], positions=[20, 3], seq_lens=[21, 4], page_rows=pages)
+        elif call == "decode_low":
+            engine.decode(tokens=[5, 6], positions=[3, 5], seq_lens=[4, 6], page_rows=pages)
+        elif call == "prefill":
+            engine.prefill(list(range(3, 15)), pages[0])
+        else:
+            engine.extend([[5, 6, 7], [8, 9]], [[10, 11, 12], [2, 3]], pages, 4)
+    finally:
+        for i, p in enumerate(pages):
+            engine.pool.free(p, owner=i, retain=False)
+    (span,) = [r[6] for r in spans.records() if r[0] == "engine." + call.split("_")[0]]
+    assert {k: v for k, v in span.items() if k.startswith(("index_", "sparse_"))} == want
+
+
+def test_sched_step_sums_the_selectors_counters_and_a_dense_decoders_spans_carry_none(selecting_engine, tiny_engine):
+    sched = _scheduler(selecting_engine, prefix_cache=False)
+    sched.submit(_request(0, n_prompt=30, max_new=3))
+    while not sched.idle():
+        sched.step()
+    recs = spans.records()
+    steps = [r[6] for r in recs if r[0] == "sched.step"]
+    calls = [r[6] for r in recs if r[0] in ("engine.decode", "engine.prefill")]
+    assert steps and all(set(INDEX_KEYS) <= set(s) for s in steps)
+    for key in INDEX_KEYS:
+        assert sum(s[key] for s in steps) == sum(c[key] for c in calls) > 0
+    assert selecting_engine.pool.used() == 0
+    spans.clear()
+    dense = _scheduler(tiny_engine)
+    dense.submit(_request(1))
+    while not dense.idle():
+        dense.step()
+    assert not [r for r in spans.records() if r[6] and any(k.startswith(("index_", "sparse_")) for k in r[6])]
