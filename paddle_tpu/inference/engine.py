@@ -38,7 +38,16 @@ row's slot as one more operand, and the chunk program the slot of the
 chunk's sequence (resolved here from a sequence's first page: `decode`,
 `prefill` and `decode_with_chunk` keep their signatures), and thread the
 state arrays through with the pages; a model with expert layers returns
-their counters beside the logits in the same fetch. A chunk moves its
+their counters beside the logits in the same fetch.
+
+A decode step (`decode`, `decode_with_chunk`) is dispatched and not waited
+for: its program chooses each row's token on the device (`argmax` over the
+vocabulary, the first maximum as `np.argmax` takes it) and the call returns
+a `StepResult`, which copies the ids (and the counters) to the host when
+asked for them and the logits only when treated as an array. An entry of
+`tokens` may be a `RowToken` of the step dispatched last: that row's input
+is then the id the last step chose, taken on the device, so the next step can
+be dispatched before the last one's ids are read. A chunk moves its
 sequence's recurrent state forward only, from what its slot holds; going
 back (`extend`) would need snapshots and is refused for such a model. For a
 model whose layers are all attention every array and every operand is as it
@@ -62,7 +71,7 @@ from ..telemetry import metrics as _metrics
 from ..telemetry import request_trace as _rt
 from .kv_cache import BlockPool, PagedCacheView, StateSpec
 
-__all__ = ["InferenceEngine"]
+__all__ = ["InferenceEngine", "RowToken", "StepResult"]
 
 
 def _bucket_counter():
@@ -127,6 +136,126 @@ def _default_batch_buckets(max_batch: int) -> Tuple[int, ...]:
         b *= 2
     out.append(max_batch)
     return tuple(sorted(set(out)))
+
+
+def _note_counters(args: dict, counts) -> None:
+    """The expert layers' counters of one program onto its span's args."""
+    args["moe_assignments"] = int(counts[0])
+    args["moe_experts_touched"] = int(counts[1])
+    args["moe_layers"] = int(counts[2])
+
+
+class RowToken:
+    """The token a dispatched step chose for one of its rows (`index` into the
+    ids its program returned), as an entry of the next step's `tokens`: where
+    `step` is the step the engine dispatched last, the value never leaves the
+    device; anywhere else `int()` reads it."""
+
+    __slots__ = ("step", "index")
+
+    def __init__(self, step: "StepResult", index: int):
+        self.step, self.index = step, index
+
+    def __int__(self) -> int:
+        return int(self.step._host_ids()[self.index])
+
+
+class _Logits:
+    """Logits that lie on the device until they are treated as an array."""
+
+    __slots__ = ()
+
+    def _host(self) -> np.ndarray:
+        raise NotImplementedError
+
+    def __array__(self, dtype=None, copy=None):
+        a = self._host()
+        return a if dtype is None else a.astype(dtype)
+
+    def __getitem__(self, i):
+        return self._host()[i]
+
+    def __len__(self) -> int:
+        return len(self._host())
+
+    def __iter__(self):
+        return iter(self._host())
+
+    def __getattr__(self, name):  # shape, dtype, argmax, ...
+        if name.startswith("_"):
+            raise AttributeError(name)
+        return getattr(self._host(), name)
+
+
+class StepResult(_Logits):
+    """What a dispatched decode step hands back: the logits `[n, V]` of its
+    `n` rows when treated as an array (one copy, on first use), and
+
+    - `ids()`: the token the program chose a row, int32 `[n]`: the step's ONE
+      fetch for who serves greedy tokens (a few bytes, and for a model with
+      expert layers its counters, which land on the step's `engine.decode`
+      span's args whenever they arrive);
+    - `token(i)`: row i's choice as an entry of the next step's `tokens`;
+    - `chunk`: where the step carried a chunk, the same for the chunk's last
+      token (logits `[V]`, `ids()` its one id, `token()`), else None.
+
+    Waiting for either is an `engine.decode.fetch` span where it happens. A
+    result nobody reads costs nothing and leaves nothing behind."""
+
+    __slots__ = ("chunk", "_out", "_n", "_bucket", "_args", "_ids", "_rows")
+
+    def __init__(self, out, n: int, bucket: int, with_chunk: bool, args: dict):
+        self._out = out  # (logits [B(+1), V], ids, counters where the model has expert layers), on the device
+        self._n, self._bucket, self._args = n, bucket, args
+        self._ids = self._rows = None
+        self.chunk = _ChunkLast(self) if with_chunk else None
+
+    def _read(self, logits: bool) -> None:
+        want = self._out[0 if logits else 1:]
+        with RecordEvent("engine.decode.fetch"):
+            got = list(jax.device_get(want))
+        if logits:
+            self._rows = got.pop(0)
+        self._ids = got.pop(0)
+        if got:
+            _note_counters(self._args, got[0])
+
+    def _host_ids(self) -> np.ndarray:
+        if self._ids is None:
+            self._read(logits=False)
+        return self._ids
+
+    def _host_rows(self) -> np.ndarray:
+        if self._rows is None:
+            self._read(logits=True)
+        return self._rows
+
+    def _host(self) -> np.ndarray:
+        return self._host_rows()[:self._n]
+
+    def ids(self) -> np.ndarray:
+        return self._host_ids()[:self._n]
+
+    def token(self, i: int) -> RowToken:
+        return RowToken(self, i)
+
+
+class _ChunkLast(_Logits):
+    """The last token of a step's chunk: row `bucket` of the step's outputs."""
+
+    __slots__ = ("step",)
+
+    def __init__(self, step: StepResult):
+        self.step = step
+
+    def _host(self) -> np.ndarray:
+        return self.step._host_rows()[self.step._bucket]
+
+    def ids(self) -> np.ndarray:
+        return self.step._host_ids()[self.step._bucket]
+
+    def token(self) -> RowToken:
+        return RowToken(self.step, self.step._bucket)
 
 
 class InferenceEngine:
@@ -271,6 +400,12 @@ class InferenceEngine:
         # path only warns, so gate it on the platform
         self._donate = jax.devices()[0].platform == "tpu"
         self._compiled: Dict[Tuple[str, int], object] = {}
+        # ids a decode or chunk program hands back: one length for every
+        # bucket (the largest's rows and a chunk's last token), so that any
+        # of them can take its rows' tokens from any other's
+        self._ids_width = self.decode_batch_buckets[-1] + 1
+        self._last_step: Optional[StepResult] = None  # the decode step dispatched last
+        self._no_ids = None  # what a step takes as the last step's ids where there is none
         self.bucket_stats = {"hits": 0, "compiles": 0}
         # bumped by every load_weights(); the fleet exports it per replica
         # so a half-finished rollout is visible in telemetry
@@ -391,7 +526,7 @@ class InferenceEngine:
                 _cc.aval_signature(self._param_avals()),
                 _cc.aval_signature(self._state_avals()),
                 f"block={self.block_size},pages={self.max_pages},"
-                f"vocab={self.vocab_size},donate={self._donate}",
+                f"vocab={self.vocab_size},donate={self._donate},ids={self._ids_width}",
                 f"model={type(self._model).__name__}",
                 shard_txt,
             ))
@@ -588,19 +723,29 @@ class InferenceEngine:
         them the expert layers' counters where the model has such layers."""
         return logits if view.moe_counts is None else (logits, view.moe_counts)
 
-    def _fetch(self, out, take, span):
-        """The step's ONE fetch: the logits at `take` (the real rows; None
-        for all of them, which starts no slicing program) and, for a model
-        with expert layers, its counters onto the span."""
-        def rows(a):
-            return a if take is None else a[take]
+    def _step_outputs(self, logits, view):
+        """What a decode or chunk program hands back beside the state: the
+        rows' logits, the token each row's logits choose (greedy: the first
+        maximum, as `np.argmax` takes it on the same array), padded to
+        `_ids_width`, and the expert layers' counters where the model has
+        such layers."""
+        ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        ids = jnp.pad(ids, (0, self._ids_width - ids.shape[0]))
+        return (logits, ids) if view.moe_counts is None else (logits, ids, view.moe_counts)
 
+    @staticmethod
+    def _row_tokens(tokens, prev_ids, src):
+        """Each row's input token: the host's, or where `src` names a row of
+        the last step's ids (not -1), the id that step chose."""
+        return jnp.where(src >= 0, prev_ids[jnp.maximum(src, 0)], tokens)
+
+    def _fetch(self, out, span):
+        """A prefill's ONE fetch: its one row of logits and, for a model with
+        expert layers, its counters onto the span."""
         if not self._has_moe:
-            return np.asarray(rows(out))
-        logits, counts = jax.device_get((rows(out[0]), out[1]))
-        span.args["moe_assignments"] = int(counts[0])
-        span.args["moe_experts_touched"] = int(counts[1])
-        span.args["moe_layers"] = int(counts[2])
+            return np.asarray(out[0])
+        logits, counts = jax.device_get((out[0][0], out[1]))
+        _note_counters(span.args, counts)
         return logits
 
     def _jit(self, fn, n_args: int):
@@ -665,17 +810,18 @@ class InferenceEngine:
         from ..autograd import no_grad
 
         model, block_size = self._model, self.block_size
-        with_counters = self._with_counters
+        outputs, row_tokens = self._step_outputs, self._row_tokens
 
-        def fn(params, tokens, positions, seq_lens, bt, *rest):
+        def fn(params, tokens, positions, seq_lens, bt, prev_ids, src, *rest):
             *slots, state = rest
             view = PagedCacheView.from_state(state, bt, seq_lens, block_size, slots=slots[0] if slots else None)
+            tokens = row_tokens(tokens, prev_ids, src)
             with no_grad():
                 logits = functional_call(
                     model, params, Tensor(tokens[:, None]), cache=view,
                     positions=positions, training=False,
                 )
-            return with_counters(logits.value[:, 0], view), PagedCacheView.state_of(view)
+            return outputs(logits.value[:, 0], view), PagedCacheView.state_of(view)
 
         i32 = jnp.int32
         avals = (
@@ -684,6 +830,8 @@ class InferenceEngine:
             jax.ShapeDtypeStruct((B,), i32),
             jax.ShapeDtypeStruct((B,), i32),
             jax.ShapeDtypeStruct((B, self.max_pages), i32),
+            jax.ShapeDtypeStruct((self._ids_width,), i32),
+            jax.ShapeDtypeStruct((B,), i32),
             *self._slot_avals(B),
             self._state_avals(),
         )
@@ -696,25 +844,28 @@ class InferenceEngine:
         are read once a step. Attention splits by segment (the model's cache
         branch, on the view's `chunk_table`); the vocabulary head runs on the
         rows and the chunk's last real token only (`last`), so the logits
-        are [B + 1, V]."""
+        are [B + 1, V] and the ids [B + 1], the chunk's last. A row's token
+        may be the last step's, as in the decode program; the chunk's tokens
+        are the prompt's and the host's."""
         from ..core.tensor import Tensor
         from ..jit.api import functional_call
         from ..autograd import no_grad
 
         model, block_size = self._model, self.block_size
-        with_counters = self._with_counters
+        outputs, row_tokens = self._step_outputs, self._row_tokens
 
-        def fn(params, tokens, positions, seq_lens, bt, chunk_bt, last, *rest):
+        def fn(params, tokens, positions, seq_lens, bt, chunk_bt, last, prev_ids, src, *rest):
             *slots, state = rest
             slots, chunk_slot = slots or (None, None)  # where the model keeps recurrent state
             view = PagedCacheView.from_state(state, bt, seq_lens, block_size, slots=slots,
                                              chunk_table=chunk_bt, chunk_slot=chunk_slot)
+            tokens = jnp.concatenate([row_tokens(tokens[:, :B], prev_ids, src[None]), tokens[:, B:]], axis=1)
             with no_grad():
                 logits = functional_call(
                     model, params, Tensor(tokens), cache=view,
                     positions=positions, last_index=last, training=False,
                 )
-            return with_counters(logits.value, view), PagedCacheView.state_of(view)
+            return outputs(logits.value, view), PagedCacheView.state_of(view)
 
         i32 = jnp.int32
         avals = (
@@ -725,6 +876,8 @@ class InferenceEngine:
             jax.ShapeDtypeStruct((B, self.max_pages), i32),
             jax.ShapeDtypeStruct((1, self.max_pages), i32),
             jax.ShapeDtypeStruct((B + 1,), i32),
+            jax.ShapeDtypeStruct((self._ids_width,), i32),
+            jax.ShapeDtypeStruct((B,), i32),
             *self._slot_avals(B),
             *self._slot_avals(1),
             self._state_avals(),
@@ -794,7 +947,7 @@ class InferenceEngine:
                 )
                 self.pool.adopt_state(state)
             with RecordEvent("engine.prefill.fetch"):
-                out = self._fetch(logits, 0, span)
+                out = self._fetch(logits, span)
         self._mark_first_token()
         return out
 
@@ -845,28 +998,30 @@ class InferenceEngine:
 
     def decode(
         self,
-        tokens: Sequence[int],
+        tokens: Sequence,
         positions: Sequence[int],
         seq_lens: Sequence[int],
         page_rows: Sequence[Sequence[int]],
-    ) -> np.ndarray:
+    ) -> StepResult:
         """One decode step for `n` in-flight sequences (token i at absolute
-        position positions[i], context length seq_lens[i] AFTER this token);
-        returns logits [n, V]."""
+        position positions[i], context length seq_lens[i] AFTER this token;
+        a token is an int, or a `RowToken` of an earlier step). Dispatched,
+        not waited for: the `StepResult` is the logits [n, V] when treated as
+        an array, and its `ids()` the token each row's logits choose."""
         if len(tokens) < 1:
             raise ValueError("decode needs at least one sequence")
-        return self._decode_step(tokens, positions, seq_lens, page_rows)[0]
+        return self._decode_step(tokens, positions, seq_lens, page_rows)
 
     def decode_with_chunk(
         self,
-        tokens: Sequence[int],
+        tokens: Sequence,
         positions: Sequence[int],
         seq_lens: Sequence[int],
         page_rows: Sequence[Sequence[int]],
         chunk_ids: Sequence[int],
         chunk_start: int,
         chunk_pages: Sequence[int],
-    ) -> Tuple[np.ndarray, np.ndarray]:
+    ) -> Tuple[StepResult, "_ChunkLast"]:
         """A decode step (as `decode`; `n` may be 0) that also carries the
         next 1..`chunk_width` prompt tokens `chunk_ids` of ONE more sequence,
         at positions chunk_start.. of `chunk_pages` (chunk_start on a page's
@@ -875,8 +1030,9 @@ class InferenceEngine:
         tokens see the sequence's cached context and themselves causally; a
         recurrent layer takes the chunk forward from the state its sequence's
         slot holds (zeros at chunk_start 0) and leaves the new state there.
-        Returns (logits [n, V], the chunk's LAST token's logits [V]). The
-        span is an `engine.decode` like any step's, with `chunk_tokens`."""
+        Returns (the step's result: logits [n, V], the result's `chunk`: the
+        chunk's LAST token's logits [V]). The span is an `engine.decode` like
+        any step's, with `chunk_tokens`."""
         take = len(chunk_ids)
         if take < 1 or take > self.chunk_width:
             raise ValueError(f"a chunk holds 1..{self.chunk_width} tokens, not {take}")
@@ -884,26 +1040,33 @@ class InferenceEngine:
             raise ValueError(
                 f"a chunk starts on a page's edge and ends inside the table: start {chunk_start}, "
                 f"{take} tokens, pages of {self.block_size}, max_seq_len {self.max_seq_len}")
-        return self._decode_step(tokens, positions, seq_lens, page_rows,
+        step = self._decode_step(tokens, positions, seq_lens, page_rows,
                                  (chunk_ids, int(chunk_start), chunk_pages))
+        return step, step.chunk
 
-    def _decode_step(self, tokens, positions, seq_lens, page_rows, chunk=None):
+    def _decode_step(self, tokens, positions, seq_lens, page_rows, chunk=None) -> StepResult:
         """`decode` and `decode_with_chunk`: the rows into their bucket (the
         largest, the chunk program's, where a chunk rides), the chunk's
-        tokens behind them; (logits [n, V], the chunk's logits or None)."""
+        tokens behind them; dispatched and handed back unread."""
         n = len(tokens)
         ids, start, pages = chunk or ((), 0, ())
         take = len(ids)
         B = self.bucket_for("decode", n) if chunk is None else self.decode_batch_buckets[-1]
         C = self.chunk_width if chunk is not None else 0
         args = {"rows": n, "bucket": B, "chunk_tokens": take, "chunk_width": self.chunk_width}
+        prev = self._last_step
         with RecordEvent("engine.decode", args=args) as span:
             with RecordEvent("engine.decode.inputs"):
                 tok = np.zeros((B + C,), np.int32)
                 pos = np.zeros((B + C,), np.int32)
                 lens = np.ones((B,), np.int32)  # inactive rows read 1 trash slot
                 bt = np.zeros((B, self.max_pages), np.int32)
-                tok[:n] = np.asarray(tokens, np.int32)
+                src = np.full((B,), -1, np.int32)  # the host's token
+                for i, t in enumerate(tokens):
+                    if type(t) is RowToken and t.step is prev:
+                        src[i] = t.index  # still on the device: the last step's choice
+                    else:
+                        tok[i] = int(t)
                 pos[:n] = np.asarray(positions, np.int32)
                 lens[:n] = np.asarray(seq_lens, np.int32)
                 for i, row in enumerate(page_rows):
@@ -925,25 +1088,27 @@ class InferenceEngine:
                     slots += self._slots_of([pages], 1)
                 if slots:
                     span.args["state_slots"] = self.pool.state_slots_used()
+            if prev is not None:
+                prev_ids = prev._out[1]
+            else:
+                if self._no_ids is None:
+                    self._no_ids = jnp.zeros((self._ids_width,), jnp.int32)
+                prev_ids = self._no_ids
             if chunk is None:
                 ex = self._get_compiled("decode", B)
-                operands = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(lens), jnp.asarray(bt), *slots)
+                operands = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(lens), jnp.asarray(bt),
+                            prev_ids, jnp.asarray(src), *slots)
             else:
                 ex = self._get_compiled("chunk", B)
                 operands = (jnp.asarray(tok[None]), jnp.asarray(pos[None]), jnp.asarray(lens),
-                            jnp.asarray(bt), jnp.asarray(chunk_bt), jnp.asarray(last), *slots)
+                            jnp.asarray(bt), jnp.asarray(chunk_bt), jnp.asarray(last),
+                            prev_ids, jnp.asarray(src), *slots)
             with RecordEvent("engine.decode.dispatch"):
-                logits, state = ex(self.params, *operands, self.pool.device_state())
+                out, state = ex(self.params, *operands, self.pool.device_state())
                 self.pool.adopt_state(state)
-            with RecordEvent("engine.decode.fetch"):
-                if chunk is None:
-                    out = self._fetch(logits, slice(0, n), span), None
-                else:
-                    # all B + 1 rows: a slice a row count is a program a count
-                    rows = self._fetch(logits, None, span)
-                    out = rows[:n], rows[B]
         self._mark_first_token()
-        return out
+        self._last_step = StepResult(out, n, B, chunk is not None, args)
+        return self._last_step
 
     def extend(
         self,

@@ -843,6 +843,10 @@ class ReplicaFleet:
         if self._resplit is not None:
             sources = [r for r in sources if r.idx == self._resplit[0]]
         for src in sources:
+            if any(not req.done and req.cursor >= len(req.prompt) for req in src.sched.running):
+                # a request about to move may hold a row of the source's step
+                # in flight: its token is read, and its write done, first
+                src.sched.sync("handoff")
             for req in list(src.sched.running):
                 # prefill-complete means the CURRENT prompt (which folds
                 # recomputed tokens after a resume) is fully consumed

@@ -78,6 +78,22 @@ Round 19 — overload protection & multi-tenant QoS (inference/qos.py):
   step 3. The ladder (spec off -> cap low-priority max_new -> shed lowest
   class) degrades only in output-exact ways: greedy spec-off is
   byte-identical, a capped budget is an exact prefix.
+
+One step ahead: a plain or a chunk step is dispatched BEFORE the last one's
+tokens are read. The program chooses each row's token on the device, the next
+program takes it from there (`engine.RowToken`), and what the scheduler needs
+to plan a step is known without the token's value: who goes on (a row ends by
+`max_new_tokens`), each row's next position and page, the step's one chunk.
+So `step()` call j dispatches program j + 1, THEN reads program j's ids,
+emits and finishes: the device has the next program queued behind the one
+that runs, and the host's work of a step runs beside it. A request carries
+at most one token not read yet (`Request.unread`). Whatever frees or moves
+the pages of a row in flight, or needs a token's value now, reads the step
+in flight out first (`sync`): a preemption, a cancellation or an expiry of
+a running request, evacuation and adoption, `drain`, and every step under
+`spec_decode`; the step after it is dispatched with nothing in flight, as
+every step was before. With `eos_id` a row can end in step j when step j + 1
+already holds it: that row's extra result is dropped.
 """
 from __future__ import annotations
 
@@ -243,6 +259,9 @@ class Request:
     # steps that carried a chunk of this prompt
     cursor: int = 0
     chunks: int = 0
+    # tokens of this request that a dispatched step computes and the host has
+    # not read yet (0 or 1 between two calls of `step`): they count as context
+    unread: int = 0
     # recompute-on-resume: prompt tokens re-prefilled after a preemption
     # include the already-generated prefix; `_prompt_len` keeps the original
     _prompt_len: Optional[int] = None
@@ -269,7 +288,7 @@ class Request:
 
     @property
     def context_len(self) -> int:
-        return len(self.prompt) + len(self.generated)
+        return len(self.prompt) + len(self.generated) + self.unread
 
     @property
     def done(self) -> bool:
@@ -290,13 +309,38 @@ class Request:
         return (self.token_times[-1] - self.token_times[0]) / (len(self.token_times) - 1)
 
 
+class _Abandon(Exception):
+    """Raised inside the plan of a step AHEAD by what cannot happen beside a
+    step in flight (`sync`): the plan is given up, the step in flight is read
+    out, and the same call plans again with nothing in flight."""
+
+    def __init__(self, reason: str):
+        super().__init__(reason)
+        self.reason = reason
+
+
+@dataclass(eq=False)
+class _Flight:
+    """A dispatched step whose ids the host has not read: the engine's result,
+    the requests it carries (rows and chunk), those among them whose token it
+    computes, each with its `RowToken` (the step after takes it from there,
+    the read-out reads it), and how it was dispatched (`ahead` or `sync`, for
+    the span of the call that reads it)."""
+
+    result: object
+    held: List[Request]
+    emits: Dict[int, Tuple[Request, object]]
+    how: Dict[str, object]
+
+
 class ContinuousBatchingScheduler:
     """Token-level admission into the in-flight decode batch.
 
     step() = [complete finished] -> [admit waiting while slots + pages
     allow] -> [grow running sequences' page allocation, preempting when the
     pool is dry] -> [one engine step: a token for everyone who decodes and,
-    beside them, a chunk of the oldest prompt still to come].
+    beside them, a chunk of the oldest prompt still to come]; planned and
+    dispatched one step ahead of the tokens it reads (the module docstring).
     """
 
     def __init__(self, engine, *, max_running: Optional[int] = None,
@@ -350,14 +394,34 @@ class ContinuousBatchingScheduler:
         # caller is expected to route elsewhere; anything queued here just
         # waits out the drain)
         self.draining = False
-        self._entered = {"prompt_tokens": 0, "chunk_tokens": 0}  # of the step that runs
+        self._entered = {"prompt_tokens": 0, "chunk_tokens": 0}  # of the steps this call dispatched
+        self._flight: Optional[_Flight] = None  # the step dispatched and not read
+        self._planning = False    # inside the plan of a step ahead
+        self._why: Optional[str] = None  # what the last read-out was for: the next step's `sync`
+        self._carried = 0         # tokens a read-out outside `step` emitted: the next call returns them
+        self._ran: Dict[str, object] = {}  # of the program this call read: ahead or sync, rows_dropped
 
     # ---- queue surface ----
     def drain(self) -> None:
         """Stop admitting new work into decode slots (in-flight requests
         run to completion). The fleet swap protocol: drain -> swap weights
         -> resume_admission."""
+        self.sync("drain")
         self.draining = True
+
+    def sync(self, reason: str) -> None:
+        """Read the step in flight out (its tokens emitted, its ended
+        requests finished; the next `step` returns their count), before
+        something that frees or moves the pages of a row in flight or needs
+        a token's value now. Nothing in flight: nothing to do. Inside the plan
+        of a step ahead the plan is given up instead (`_Abandon`)."""
+        if self._flight is None:
+            return
+        if self._planning:
+            raise _Abandon(reason)  # `_step_inner` reads the step out, then plans again
+        with RecordEvent("sched.sync", args={"reason": reason}):
+            self._carried += self._read_out()
+            self._why = reason
 
     def resume_admission(self) -> None:
         self.draining = False
@@ -536,6 +600,8 @@ class ContinuousBatchingScheduler:
         free its pages IMMEDIATELY (a stuck/gone client must not pin pool
         pages for the rest of the process). Returns False when `rid` is not
         in flight (already finished or never submitted)."""
+        if any(r.rid == rid for r in self.running):
+            self.sync("cancel")  # its row may be in the step in flight
         for queue in (self.waiting, self.running):
             for req in queue:
                 if req.rid == rid:
@@ -551,17 +617,23 @@ class ContinuousBatchingScheduler:
         """Per-request TTL: requests past their deadline_s (scheduler-clock
         seconds since submit) finish with outcome="expired" and free their
         pages right now — the serving-tier analogue of a dead client."""
-        due = [
-            (queue, req)
-            for queue in (self.waiting, self.running) for req in queue
-            if (
-                req.deadline_s is not None
-                and req.submitted_time is not None
-                and now - req.submitted_time > req.deadline_s
-            )
-        ]
+        def sweep():
+            return [
+                (queue, req)
+                for queue in (self.waiting, self.running) for req in queue
+                if (
+                    req.deadline_s is not None
+                    and req.submitted_time is not None
+                    and now - req.submitted_time > req.deadline_s
+                )
+            ]
+
+        due = sweep()
         if not due:
             return  # a sweep that finds nothing is no phase of the step
+        if self._flight is not None and any(queue is self.running for queue, _ in due):
+            self.sync("expire")
+            due = sweep()  # the read-out may have finished one of them
         with RecordEvent("sched.expire", args={"expired": len(due)}):
             for queue, req in due:
                 queue.remove(req)
@@ -579,6 +651,7 @@ class ContinuousBatchingScheduler:
         req.prompt = req.prompt + req.generated
         req.generated = []
         req.cursor = 0
+        req.unread = 0
         req._registered_pages = 0
         req._chain_digest = b""
         return req
@@ -591,14 +664,17 @@ class ContinuousBatchingScheduler:
         `below_priority` restricts victims to strictly lower classes —
         the QoS priority-preemption path; equal-priority traffic (the
         default) keeps the original pool-dry victim order exactly."""
-        candidates = (
-            [r for r in self.running if r.priority > below_priority]
-            if below_priority is not None else self.running
-        )
-        if not candidates:
+        def candidates():
+            return ([r for r in self.running if r.priority > below_priority]
+                    if below_priority is not None else self.running)
+
+        if not candidates():
+            return False
+        self.sync("preempt")  # the victim's row may be in the step in flight
+        if not candidates():
             return False
         victim = max(
-            candidates,
+            candidates(),
             key=lambda r: (r.priority, r.first_token_time is None,
                            r.first_token_time or 0.0, r.rid),
         )
@@ -633,6 +709,11 @@ class ContinuousBatchingScheduler:
         calls this when a replica's circuit breaker opens: the requests are
         re-submitted to a healthy replica and their K/V pages are rebuilt
         from the folded prompt there."""
+        try:
+            self.sync("handoff")
+        except Exception:  # noqa: BLE001 — a replica that died with a step in flight
+            # the tokens of that step are computed again where the requests resume
+            self._flight = None
         evacuated: List[Request] = []
         now = self.clock()
         for req in self.running:
@@ -661,6 +742,7 @@ class ContinuousBatchingScheduler:
         exactly where it left off. The caller owns the page handoff (pages
         allocated here, CRC-verified) and the prefix-registration reset so
         this pool republishes the chain itself."""
+        self.sync("handoff")
         if len(self.running) >= self.max_running:
             raise RuntimeError(
                 f"adopt_running: no free decode slot for request {req.rid}")
@@ -669,7 +751,11 @@ class ContinuousBatchingScheduler:
             self._sync_gauges()
 
     def _emit_token(self, req: Request, logits: np.ndarray, now: float) -> None:
-        token = int(np.argmax(logits))
+        """A token chosen on the host, where the logits are here: a bucketed
+        prefill's, a verify step's."""
+        self._emit(req, int(np.argmax(logits)), now)
+
+    def _emit(self, req: Request, token: int, now: float) -> None:
         req.generated.append(token)
         req.token_times.append(now)
         # every emitted token belongs to the decode phase — keyed on the
@@ -997,7 +1083,14 @@ class ContinuousBatchingScheduler:
         return produced
 
     def step(self) -> int:
-        """One scheduler tick; returns the number of tokens produced.
+        """One scheduler tick; returns the number of tokens produced: those of
+        ONE program, which this call reads (and of a read-out since the last
+        call, `sync`). The span's `ahead` is 1 where that program was
+        dispatched before the step before it was read, else `sync` says why
+        not: `first` (nothing was in flight), `prefill` (a bucketed prefill
+        ran, the engine idle), `spec`, or what the read-out before it was for
+        (`preempt`, `cancel`, `expire`, `handoff`, `drain`); `rows_dropped`
+        counts rows computed one step past their end by `eos_id`.
 
         With QoS: sweep the queue-wait bound, feed measured pressure into
         the brownout ladder (transitions counted + trace-annotated), gate
@@ -1013,8 +1106,9 @@ class ContinuousBatchingScheduler:
             if (self.spec is not None and self.qos is not None
                     and not self.qos.brownout.spec_allowed()):
                 self.spec = None
-            # prompt tokens that enter in this step: by any path, and in a chunk
+            # prompt tokens that enter in this call (it dispatches their step): by any path, and in a chunk
             self._entered = {"prompt_tokens": 0, "chunk_tokens": 0}
+            self._ran = {}
             # a model that selects cached tokens: what its selector scored and chose in this step
             index_before = self.engine.index_totals.copy() if getattr(self.engine, "index_topk", 0) else None
             try:
@@ -1026,7 +1120,7 @@ class ContinuousBatchingScheduler:
                 self.ewma_step_s = (dt if self.ewma_step_s is None
                                     else 0.8 * self.ewma_step_s + 0.2 * dt)
             span.args = {"produced": produced, "running": len(self.running),
-                         "waiting": len(self.waiting), **self._entered}
+                         "waiting": len(self.waiting), **self._entered, **self._ran}
             if self.engine.pool.has_recurrent_state:
                 span.args["state_slots"] = self.engine.pool.state_slots_used()
             if index_before is not None:
@@ -1067,7 +1161,63 @@ class ContinuousBatchingScheduler:
                      pressure=round(qos.last_pressure, 4))
 
     def _step_inner(self) -> int:
+        produced, self._carried = self._carried, 0
+        if self._flight is not None and self.spec is not None:
+            # the brownout ladder gave speculation back: its steps go through
+            # `engine.extend`, with nothing in flight
+            self._why = "spec"
+            return produced + self._read_out()
+        if self._flight is None:
+            # nothing in flight: this call's own program first, as every step was
+            emitted, plan = self._plan()
+            produced += emitted
+            if plan is None:
+                if self.running or self._ran:  # a step under `spec_decode`, or one that ran no program
+                    self._publish()
+                return produced
+            reason, self._why = self._why or ("prefill" if emitted else "first"), None
+            self._flight = self._dispatch(plan, {"sync": reason})
+        nxt, gave_up = None, None
+        self._planning = True
+        try:
+            _, plan = self._plan()
+            if plan is not None:
+                nxt = self._dispatch(plan, {"ahead": 1})
+        except _Abandon as e:
+            gave_up = e.reason
+        finally:
+            self._planning = False
+        produced += self._read_out()
+        if gave_up is not None:
+            # what could not happen beside a step in flight happens now, and the
+            # step after it is dispatched behind it: one synchronous step
+            emitted, plan = self._plan()
+            produced += emitted
+            if plan is not None:
+                nxt = self._dispatch(plan, {"sync": gave_up})
+        elif nxt is not None and not self._lives(nxt):
+            nxt = None
+        self._flight = nxt
+        self._publish()
+        return produced
+
+    def _ends_in_flight(self, req: Request) -> bool:
+        """Whether the token the step in flight computes is the request's
+        last by `max_new_tokens`: known without its value."""
+        return req.unread > 0 and (
+            (len(req.prompt) - req.prompt_len) + len(req.generated) + req.unread >= req.max_new_tokens)
+
+    def _plan(self):
+        """Everything of a step before the engine's call: the TTL sweep,
+        admission, page growth, the step's chunk, its rows. Returns (tokens
+        admission emitted, the plan or None where no program is to run); a
+        step under `spec_decode` runs whole in here. With a step in flight
+        (`_planning`) it plans the step after it: over the requests that go
+        on, each from where the step in flight leaves it."""
         produced = 0
+        staying = [r for r in self.running if not self._ends_in_flight(r)]
+        if self._planning and not staying:
+            return 0, None  # the next call starts over, and may take a bucketed prefill
         # TTL sweep first: an expired request must not consume an admission
         # slot or grow pages this very tick
         self._expire_due(self.clock())
@@ -1087,19 +1237,20 @@ class ContinuousBatchingScheduler:
         if not self.running:
             if telemetry.enabled():
                 self._sync_gauges()
-            return produced
+            return produced, None
 
         with RecordEvent("sched.grow"):
+            staying = [r for r in self.running if not self._ends_in_flight(r)]
             # speculative plans first: growth must cover every position the
             # draft chain will write, not just the next token
             plans: Dict[int, Tuple[str, List[int], List[int]]] = {}
             if self.spec is not None:
-                for req in self.running:
+                for req in staying:
                     plans[req.rid] = self._plan_row(req)
             # the step's ONE chunk: the oldest request with prompt left (the
             # order of admission, which is `_try_admit`'s); the others with
             # prompt left keep their slot, wait their turn and write nothing
-            chunk_req = next((r for r in self.running if self._chunkable(r)), None)
+            chunk_req = next((r for r in staying if self._chunkable(r)), None)
             take = 0 if chunk_req is None else min(
                 self._chunk_width(), len(chunk_req.prompt) - chunk_req.cursor)
 
@@ -1107,7 +1258,7 @@ class ContinuousBatchingScheduler:
             # this step writes; allocate at block boundaries, preempting until
             # the pool yields one
             pool = self.engine.pool
-            for req in list(self.running):
+            for req in staying:
                 if req not in self.running:
                     # evicted by an earlier iteration's preemption — allocating
                     # into it now would leak the page at re-admission
@@ -1124,12 +1275,14 @@ class ContinuousBatchingScheduler:
                     lo = hi = self._tokens_needed(req) - 1
                 if hi + 1 > self.engine.max_seq_len:
                     # capacity guard (submit() bounds this; belt-and-braces)
+                    self.sync("first")
                     self._finish(req, self.clock())
                     continue
                 while pool.blocks_for_tokens(hi + 1) > len(req.pages):
                     try:
                         req.pages.extend(pool.alloc(1, owner=req.rid))
                     except PoolExhausted:
+                        self.sync("preempt")  # a step ahead is given up here
                         if req in self.running and len(self.running) == 1:
                             raise  # nothing left to evict but ourselves
                         if not self._preempt_one():
@@ -1146,63 +1299,108 @@ class ContinuousBatchingScheduler:
                                     min(hi // pool.block_size, len(req.pages) - 1) + 1):
                         if pool.refcount(req.pages[pi]) > 1:
                             req.pages[pi] = pool.make_private(req.pages[pi], owner=req.rid)
-            alive = [r for r in self.running if r.pages]
+            alive = [r for r in staying if r.pages and r in self.running]
             if chunk_req not in alive:
                 chunk_req = None  # finished by the guard, or a later row's growth evicted it
 
-        if alive and self.spec is not None:
+        if not alive:
+            return produced, None
+        if self.spec is not None:
             with RecordEvent("sched.spec"):
                 produced += self._spec_decode_step(alive, plans)
                 self.running = [r for r in self.running if not r.done]
-        elif alive:
-            with RecordEvent("sched.rows"):
-                rows = []
-                for r in alive:
-                    if r is chunk_req or self._chunkable(r):
-                        continue  # the chunk, or a prompt that waits its turn: no decode row
-                    if r.cursor < len(r.prompt):  # streaming its prompt in
-                        rows.append((r, r.prompt[r.cursor], r.cursor))
-                    else:
-                        rows.append((r, r.generated[-1], r.context_len - 1))
-                tokens = [t for _, t, _ in rows]
-                positions = [p for _, _, p in rows]
-                seq_lens = [p + 1 for _, _, p in rows]
-                page_rows = [r.pages for r, _, _ in rows]
-            logits, chunk_logits = (), None
-            if chunk_req is not None:
-                logits, chunk_logits = self.engine.decode_with_chunk(
-                    tokens, positions, seq_lens, page_rows,
-                    chunk_req.prompt[chunk_req.cursor:chunk_req.cursor + take],
-                    chunk_req.cursor, chunk_req.pages)
-            elif rows:
-                logits = self.engine.decode(
-                    tokens=tokens, positions=positions, seq_lens=seq_lens,
-                    page_rows=page_rows,
-                )
-            with RecordEvent("sched.emit"):
-                now = self.clock()
-                for (r, _, _), lg in zip(rows, logits):
-                    if r.cursor < len(r.prompt):
-                        r.cursor += 1
-                        self._entered["prompt_tokens"] += 1
-                        if r.cursor == len(r.prompt):
-                            # the last prompt token's logits ARE the first
-                            # generated token
-                            self._emit_token(r, lg, now)
-                            produced += 1
-                    else:
-                        self._emit_token(r, lg, now)
-                        produced += 1
-                if chunk_logits is not None:
-                    chunk_req.cursor += take
-                    chunk_req.chunks += 1
-                    self._entered["prompt_tokens"] += take
-                    self._entered["chunk_tokens"] += take
-                    if chunk_req.cursor == len(chunk_req.prompt):
-                        # the step that carries a prompt's last chunk emits its first token
-                        self._emit_token(chunk_req, chunk_logits, now)
-                        produced += 1
-                self.running = [r for r in self.running if not r.done]
+            self._ran = {"sync": "spec"}
+            self._why = None
+            return produced, None
+        with RecordEvent("sched.rows"):
+            rows = []
+            for r in alive:
+                if r is chunk_req or self._chunkable(r):
+                    continue  # the chunk, or a prompt that waits its turn: no decode row
+                if r.cursor < len(r.prompt):  # streaming its prompt in
+                    rows.append((r, r.prompt[r.cursor], r.cursor))
+                elif r.unread:  # the step in flight chooses it: taken on the device
+                    rows.append((r, self._flight.emits[id(r)][1], r.context_len - 1))
+                else:
+                    rows.append((r, r.generated[-1], r.context_len - 1))
+        if chunk_req is None and not rows:
+            return produced, None
+        return produced, (rows, chunk_req, take)
+
+    def _dispatch(self, plan, how: Dict[str, object]) -> _Flight:
+        """One engine call for the plan, not waited for. What the step does to
+        its requests without its tokens' values happens here: a prompt's
+        cursor moves past what the step writes, and a request whose token the
+        step computes carries it as `unread`."""
+        rows, chunk_req, take = plan
+        tokens = [t for _, t, _ in rows]
+        positions = [p for _, _, p in rows]
+        seq_lens = [p + 1 for _, _, p in rows]
+        page_rows = [r.pages for r, _, _ in rows]
+        if chunk_req is not None:
+            result, _ = self.engine.decode_with_chunk(
+                tokens, positions, seq_lens, page_rows,
+                chunk_req.prompt[chunk_req.cursor:chunk_req.cursor + take],
+                chunk_req.cursor, chunk_req.pages)
+        else:
+            result = self.engine.decode(
+                tokens=tokens, positions=positions, seq_lens=seq_lens,
+                page_rows=page_rows,
+            )
+        flight = _Flight(result, [r for r, _, _ in rows], {}, how)
+        for i, (r, _, _) in enumerate(rows):
+            if r.cursor < len(r.prompt):  # streaming its prompt in
+                r.cursor += 1
+                self._entered["prompt_tokens"] += 1
+            if r.cursor == len(r.prompt):
+                # the last prompt token's logits ARE the first generated token
+                flight.emits[id(r)] = (r, result.token(i))
+        if chunk_req is not None:
+            flight.held.append(chunk_req)
+            chunk_req.cursor += take
+            chunk_req.chunks += 1
+            self._entered["prompt_tokens"] += take
+            self._entered["chunk_tokens"] += take
+            if chunk_req.cursor == len(chunk_req.prompt):
+                # the step that carries a prompt's last chunk emits its first token
+                flight.emits[id(chunk_req)] = (chunk_req, result.chunk.token())
+        for r, _ in flight.emits.values():
+            r.unread += 1
+        return flight
+
+    def _lives(self, flight: _Flight) -> bool:
+        """Whether a dispatched step still serves someone. Its every request
+        may have ended by `eos_id` in the step before it: then nobody reads
+        it, and nothing is in flight."""
+        if any(not r.done for r in flight.held):
+            return True
+        for r, _ in flight.emits.values():
+            r.unread -= 1
+        self._ran["rows_dropped"] += len(flight.emits)
+        return False
+
+    def _read_out(self) -> int:
+        """The step in flight, read: the ONE wait for the device (ids, a few
+        bytes). Emits each row's token and finishes who ends; a row whose
+        request ended in the step before (by `eos_id`), or left `running`,
+        is dropped. Returns the tokens emitted; nothing is in flight after."""
+        flight = self._flight
+        flight.result.ids()  # a step that cannot be read stays in flight
+        self._flight = None
+        with RecordEvent("sched.emit"):
+            now = self.clock()
+            produced = 0
+            here = {id(r) for r in self.running}
+            for r, token in flight.emits.values():
+                r.unread -= 1
+                if not r.done and id(r) in here:
+                    self._emit(r, int(token), now)
+                    produced += 1
+            self.running = [r for r in self.running if not r.done]
+        self._ran = {**flight.how, "rows_dropped": len(flight.emits) - produced}
+        return produced
+
+    def _publish(self) -> None:
         with RecordEvent("sched.publish"):
             if self.prefix_cache:
                 for r in self.running:
@@ -1210,8 +1408,7 @@ class ContinuousBatchingScheduler:
             if telemetry.enabled():
                 self._sync_gauges()
                 active_tokens = sum(self._tokens_needed(r) for r in self.running)
-                pool.note_fragmentation(active_tokens)
-        return produced
+                self.engine.pool.note_fragmentation(active_tokens)
 
 
 class StaticBatchingScheduler:

@@ -256,6 +256,17 @@ def _kernels_of(text):
             re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"" + KERNEL + "\"", text)]
 
 
+def _assert_ids(compiled, program, engine):
+    """A decode or a chunk program hands back, beside the logits, the token it
+    chose a row: int32, the largest bucket's rows and a chunk's last token
+    (one length whatever the bucket, so any step can take any step's ids)."""
+    if program not in ("decode", "chunk"):
+        return
+    logits, ids = compiled.out_info[0][:2]
+    assert ids.shape == (engine.decode_batch_buckets[-1] + 1,) and ids.dtype == jnp.int32
+    assert len(logits.shape) == 2 and logits.shape[1] == engine.vocab_size
+
+
 @pytest.mark.parametrize("program, size, scatters", [
     ("decode", 32, 4), ("prefill", 512, 4), ("extend", (4, 4), 4),
     ("chunk", 32, 8),   # the rows' positioned write and the chunk's whole pages, K and V, a layer
@@ -281,8 +292,35 @@ def test_engine_program_holds_no_copy_of_the_pool(one_chip, monkeypatch, program
     if program == "chunk":
         assert engine.chunk_width == 128
         assert _kernels_of(text) == ["paged_attn"] * 4
+    if program == "decode":
+        assert _kernels_of(text) == ["paged_attn"] * 2   # one query a row still goes through the kernel
+    # the `where` on the token operand (a row's token may be the last step's, on the device) and the
+    # ids beside the logits leave the donated pool where it lies
     assert _pool_copies(compiled, engine._state_avals()["k"][0]) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20   # a copy of the pool is 134 MB
+    _assert_ids(compiled, program, engine)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_single_stream_programs_choose_the_token_on_the_device(one_chip, monkeypatch, program):
+    """The document cell's engine (one slot, a 4096-token table, the pool of
+    4097 pages) over a 2-layer decoder at Mistral widths: its one-row decode
+    and the lone slot's chunk program return the ids `[2]` (the row's and a
+    chunk's last), hold no `copy` of the pool, and run the paged kernel."""
+    from paddle_tpu.models.llama import LlamaForCausalLM
+
+    engine = _described_engine(
+        one_chip, monkeypatch,
+        lambda: LlamaForCausalLM(vocab_size=32000, hidden_size=4096, num_hidden_layers=2,
+                                 num_attention_heads=32, num_key_value_heads=8,
+                                 intermediate_size=14336, rms_norm_eps=1e-5),
+        max_seq_len=4096, block_size=16, num_blocks=4097, max_batch=1,
+        prefill_buckets=(1024, 2048, 4096), decode_batch_buckets=(1,))
+    compiled = getattr(engine, "_compile_" + program)(1)
+    assert _kernels_of(compiled.as_text()) == ["paged_attn"] * (2 if program == "decode" else 4)
+    assert _pool_copies(compiled, engine._state_avals()["k"][0]) == []
+    _assert_ids(compiled, program, engine)
+    assert compiled.out_info[0][1].shape == (2,)
 
 
 @pytest.mark.parametrize("program, size, kernels", [
@@ -315,6 +353,7 @@ def test_latent_engine_programs_compile_at_published_widths(one_chip, monkeypatc
     names = _kernels_of(text)
     assert {k: names.count(k) for k in set(names)} == kernels
     assert _pool_copies(compiled, pool["k"][0]) == []
+    _assert_ids(compiled, program, engine)
     if program != "prefill":  # a prefill's temporaries are its activations' (0.3 GB at 1024 tokens)
         assert compiled.memory_analysis().temp_size_in_bytes < 96 << 20   # a copy of the pool is 178 MB
 
@@ -358,6 +397,7 @@ def test_sparse_latent_engine_programs_compile_at_published_widths(one_chip, mon
     assert {k: names.count(k) for k in set(names)} == kernels
     for aval in (pool["k"][0], pool["index"][0]):
         assert _pool_copies(compiled, aval) == []
+    _assert_ids(compiled, program, engine)
     # a tile's gathered entries are 128 x 2048 x 640 bf16 = 335 MB, held twice; a copy of the latent pool is 178 MB more
     limit = {"decode": 96 << 20, "chunk": 1024 << 20, "prefill": 2048 << 20}[program]
     assert compiled.memory_analysis().temp_size_in_bytes < limit
@@ -416,6 +456,7 @@ def test_hybrid_chunk_program_updates_the_state_in_place_at_published_widths(one
     assert {k: names.count(k) for k in set(names)} == {"paged_attn": 2, "moe_gmm": 4}  # rows and chunk; up and down
     for aval in (state["ssm"][0], state["k"][0]):
         assert _pool_copies(compiled, aval) == []
+    _assert_ids(compiled, "chunk", engine)
     loops = [line for line in text.splitlines() if " while(" in line]
     assert not [line for line in loops if "64,128]" in line]   # the expert layout's loops carry indices alone
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20   # a copy of a layer's state is 541 MB
@@ -449,18 +490,21 @@ def _lowered_digest(engine, program, size):
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
+# PR 35: the decode and chunk programs choose the token (ids beside the logits) and take a row's token
+# from the last step's ids (two operands more): their six digests are new; `prefill` and `extend` keep
+# theirs, which is the change's scope.
 LOWERED = {
-    ("llama", "decode", 4): "58f5aae9f4908c32",
+    ("llama", "decode", 4): "4e798e053ee0185a",
     ("llama", "prefill", 32): "bad05b9a2f237a4d",
-    ("llama", "chunk", 4): "3abec630093d02bd",
+    ("llama", "chunk", 4): "a40c2251fa1ee927",
     ("llama", "extend", (4, 4)): "7d875aa6fdd6bf0f",
-    ("pangu", "decode", 4): "ddbfc4c4a9653b7d",
+    ("pangu", "decode", 4): "658273ec7fa6d58d",
     ("pangu", "prefill", 32): "f04d8608c8a0240d",
-    ("pangu", "chunk", 4): "e6d82c4247740c66",
+    ("pangu", "chunk", 4): "aa65fe8b39e12a14",
     ("pangu", "extend", (4, 4)): "7eac7881ec210019",
-    ("hybrid", "decode", 4): "47969056fd6d512a",   # PR 34: the tree of PR 32 lowers to these three
+    ("hybrid", "decode", 4): "04e5c50de0830a52",
     ("hybrid", "prefill", 32): "4d56eb5cb37d11c8",
-    ("hybrid", "chunk", 4): "ef603b09643ac3d5",
+    ("hybrid", "chunk", 4): "4d3a54478dc28847",
 }
 
 
@@ -472,7 +516,9 @@ def test_dense_and_latent_programs_lower_to_what_they_did(monkeypatch, model, pr
     source locations, what PR 31's tree lowered (the digests are of that
     tree's text: tiny models, this host's backend, the paged kernels' jnp
     reference path). A PR that changes one on purpose replaces its digest,
-    and measures the cells that run it."""
+    and measures the cells that run it: PR 35 did so for every decode and
+    chunk program (ids beside the logits, a row's token from the last step's
+    ids), and for no `prefill` and no `extend`."""
     from paddle_tpu.inference.engine import InferenceEngine
 
     monkeypatch.setattr(pk, "_on_tpu", lambda: False)

@@ -119,8 +119,10 @@ def expected(engine):
 def test_chunked_ids_equal_the_bucketed_prefills(engine, expected, n, rows):
     req, steps = _beside_rows(engine, _prompt(n, n), rows)
     assert req.generated == expected[n]
-    # one chunk a step, the first token in the step that carries the last
-    assert req.chunks == -(-n // C) and steps == req.chunks + MAX_NEW - 1
+    # one chunk a step, the first token in the step that carries the last; the
+    # rows' next step was in flight when the prompt came, so its first chunk
+    # rides the step after that one: one call more than the steps it takes
+    assert req.chunks == -(-n // C) and steps == req.chunks + MAX_NEW
     assert req.slot[1] == "chunked"
 
 
@@ -128,7 +130,7 @@ def test_chunked_ids_equal_the_bucketed_prefills(engine, expected, n, rows):
 def test_streamed_ids_equal_the_chunked(engine, expected, n, monkeypatch):
     req, steps = _beside_rows(engine, _prompt(n, n), 1, monkeypatch)
     assert req.generated == expected[n]
-    assert req.chunks == 0 and steps == n + MAX_NEW - 1 and req.slot[1] == "streamed"
+    assert req.chunks == 0 and steps == n + MAX_NEW and req.slot[1] == "streamed"
 
 
 def test_only_the_oldest_prompt_rides_a_step_and_the_others_hold_no_row(engine):
@@ -147,7 +149,9 @@ def test_only_the_oldest_prompt_rides_a_step_and_the_others_hold_no_row(engine):
         assert (a.cursor, b.cursor) == (want_a, want_b)
         (dec,) = [r for r in spans.records() if r[0] == "engine.decode"]
         assert (dec[6]["chunk_tokens"], dec[6]["rows"]) == (chunk, rows)
-    assert len(a.generated) == 2 and len(b.generated) == 1
+    # each call dispatched the step above and read the one before it: the last
+    # step's tokens (a's second, b's first) are computed and not read yet
+    assert (len(a.generated), a.unread) == (1, 1) and (len(b.generated), b.unread) == (0, 1)
     for r in list(sched.running):
         sched.cancel(r.rid)
     assert engine.pool.used() == 0

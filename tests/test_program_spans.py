@@ -180,15 +180,19 @@ def test_scheduler_step_yields_named_phases_that_add_up(tiny_engine):
     sched.step()          # admission by a bucketed prefill, then a decode
     sched.submit(_request(1))
     spans.clear()
-    produced = sched.step()  # a busy step: request 1's prompt rides request 0's decode, one chunk
+    # a busy step: request 1's prompt rides request 0's decode, one chunk, in the
+    # step this call dispatches; the tokens it returns are the step before's
+    produced = sched.step()
     recs = spans.records()
     by = _by_name(recs)
     (step,) = by["sched.step"]
     assert step[6] == {"produced": produced, "running": len(sched.running),
-                       "waiting": len(sched.waiting), "prompt_tokens": 5, "chunk_tokens": 5}
-    # nothing expires here: a sweep that finds nothing is no phase of the step
+                       "waiting": len(sched.waiting), "prompt_tokens": 5, "chunk_tokens": 5,
+                       "ahead": 1, "rows_dropped": 0}
+    # nothing expires here: a sweep that finds nothing is no phase of the step.
+    # The next step is planned and dispatched, THEN the one in flight is waited for
     phases = ["sched.admit", "sched.grow", "sched.rows", "engine.decode",
-              "sched.emit", "sched.publish"]
+              "engine.decode.fetch", "sched.emit", "sched.publish"]
     children = [r for r in recs if r[4] == step[3]]
     assert [r[0] for r in children] == phases  # in order of ending = order of running
     for c in children:
@@ -262,7 +266,7 @@ def test_engine_decode_carries_rows_bucket_context(tiny_engine):
     engine = tiny_engine
     pages = [engine.pool.alloc(1, owner=i) for i in range(3)]
     try:
-        engine.decode(tokens=[5, 6, 7], positions=[0, 1, 2], seq_lens=[1, 2, 3], page_rows=pages)
+        out = engine.decode(tokens=[5, 6, 7], positions=[0, 1, 2], seq_lens=[1, 2, 3], page_rows=pages)
     finally:
         for i, p in enumerate(pages):
             engine.pool.free(p, owner=i, retain=False)
@@ -272,9 +276,13 @@ def test_engine_decode_carries_rows_bucket_context(tiny_engine):
     assert dec[6] == {"rows": 3, "bucket": 4, "context": 6, "chunk_tokens": 0, "chunk_width": 64,
                       "page_blocks_live": 4, "page_blocks_grid": 4}
     kids = [r for r in recs if r[4] == dec[3] and r[0].startswith("engine.decode.")]
-    assert [r[0] for r in kids] == ["engine.decode.inputs", "engine.decode.dispatch",
-                                    "engine.decode.fetch"]
+    # the call dispatches and does not wait: the fetch is where the result is read
+    assert [r[0] for r in kids] == ["engine.decode.inputs", "engine.decode.dispatch"]
     assert sum(r[2] - r[1] for r in kids) <= dec[2] - dec[1]
+    assert not [r for r in recs if r[0] == "engine.decode.fetch"]
+    assert out.ids().shape == (3,) and out.shape == (3, engine.vocab_size)  # ids, then the logits: a fetch each
+    fetches = [r for r in spans.records() if r[0] == "engine.decode.fetch"]
+    assert len(fetches) == 2 and all(r[4] == 0 and r[1] >= dec[2] for r in fetches)
 
 
 @pytest.mark.parametrize("n_prompt, chunks", [(9, [9]), (128, [128]), (150, [128, 22])],
@@ -292,7 +300,7 @@ def test_a_chunk_step_is_an_engine_decode_span_that_counts_its_chunk(tiny_engine
     wide = InferenceEngine(tiny_engine._model, max_seq_len=512, block_size=8, max_batch=4)
     sched = _scheduler(wide, prefix_cache=False)
     sched.submit(_request(0, n_prompt=3, max_new=8))
-    sched.step()                  # bucketed, then one decode: context 4 after it
+    sched.step()                  # bucketed, then two decodes dispatched (one read): context 5 after them
     late = _request(1, n_prompt=n_prompt, max_new=2)
     sched.submit(late)
     start = 0
@@ -301,22 +309,24 @@ def test_a_chunk_step_is_an_engine_decode_span_that_counts_its_chunk(tiny_engine
         sched.step()
         recs = spans.records()
         (dec,) = [r for r in recs if r[0] == "engine.decode"]
-        row_context = 5 + i       # the one decode row, a token a step
+        row_context = 6 + i       # the one decode row, a token a step
         frontier_blocks = (start + take - 1) // 128 + 1
         assert dec[6] == {"rows": 1, "bucket": 4, "chunk_tokens": take, "chunk_width": 128,
                           "context": row_context + start + take, "chunk_context": start,
                           "page_blocks_live": 4 + frontier_blocks, "page_blocks_grid": 5 * 4}
         kids = [r[0] for r in recs if r[4] == dec[3] and r[0].startswith("engine.decode.")]
-        assert kids == ["engine.decode.inputs", "engine.decode.dispatch", "engine.decode.fetch"]
+        assert kids == ["engine.decode.inputs", "engine.decode.dispatch"]
         (step,) = [r for r in recs if r[0] == "sched.step"]
-        assert (step[6]["chunk_tokens"], step[6]["prompt_tokens"]) == (take, take)
+        assert (step[6]["chunk_tokens"], step[6]["prompt_tokens"], step[6]["ahead"]) == (take, take, 1)
         assert dec[4] == step[3]  # a child of the step, where `engine.decode` always was
+        (fetch,) = [r for r in recs if r[0] == "engine.decode.fetch"]
+        assert fetch[4] == step[3] and fetch[1] >= dec[2]  # the wait for the step BEFORE, after this one went
         start += take
+    spans.clear()
+    sched.step()                  # both decode: a plain step says so; the last chunk's step is read in it
     (prompt,) = [r for r in spans.records() if r[0] == "request.prompt"]
     assert prompt[5] == 1 and prompt[6] == {"mode": "chunked", "prompt_len": n_prompt, "cached": 0,
                                             "chunks": len(chunks)}
-    spans.clear()
-    sched.step()                  # both decode: a plain step says so
     (dec,) = [r for r in spans.records() if r[0] == "engine.decode"]
     (step,) = [r for r in spans.records() if r[0] == "sched.step"]
     assert (dec[6]["rows"], dec[6]["chunk_tokens"], dec[6]["chunk_width"]) == (2, 0, 128)
@@ -339,6 +349,7 @@ def test_a_bucketed_prefill_and_a_streamed_row_count_as_prompt_tokens_not_chunks
         sched.step()
         (step,) = [r for r in spans.records() if r[0] == "sched.step"]
         assert (step[6]["prompt_tokens"], step[6]["chunk_tokens"]) == (1, 0)
+    sched.step()  # reads the step that carried the prompt's last token
     (prompt,) = [r for r in spans.records() if r[0] == "request.prompt"]
     assert (prompt[6]["mode"], prompt[6]["chunks"]) == ("streamed", 0)
     while not sched.idle():

@@ -317,12 +317,13 @@ def test_scheduler_token_level_admission_seeded_trace(tiny_model, shared_engine)
         sched.step()
         # token-level admission: r1/r2 joined the in-flight batch, chunked
         # (no further prefill calls); r3 waits for a slot (max_running=3).
-        # The step carried r1's whole prompt as its one chunk, so r1 has its
-        # first token; r2 holds its slot and waits its turn
+        # The step this call dispatched carries r1's whole prompt as its one
+        # chunk (the call read r0's step before it): r1's first token is
+        # computed and not read yet; r2 holds its slot and waits its turn
         assert prefills == [r0.prompt]
         assert {r.rid for r in sched.running} == {0, 1, 2}
         assert [r.rid for r in sched.waiting] == [3]
-        assert r1.cursor == 6 and len(r1.generated) == 1 and r1.chunks == 1
+        assert r1.cursor == 6 and (len(r1.generated), r1.unread) == (0, 1) and r1.chunks == 1
         assert r2.cursor == 0 and r2.generated == []
 
         while not sched.idle():
