@@ -14,10 +14,11 @@ outcome —
 - ``hit``      the caller's own in-memory cache served the signature
 - ``error``    what `record` files an outcome it does not know under
 
-Hits are counter-only: they happen per dispatch (per decode step on the
-serving path), so appending them to the bounded event store would age out
-the rare, interesting compile-path events. Everything else lands in a
-bounded deque the cold-start report reads.
+Hits are counter-only: they happen per dispatch, so appending them to the
+bounded event store would age out the rare, interesting compile-path
+events. The serving engine files none: its per-step lookup is counted by
+``paddle_tpu_serving_bucket_events_total{event="hit"}`` alone. Everything
+else lands in a bounded deque the cold-start report reads.
 
 Telemetry (all labeled ``{origin, outcome}``):
 ``paddle_tpu_compile_events_total``, ``paddle_tpu_compile_seconds_total``,
@@ -92,7 +93,7 @@ def _counters(origin: str, outcome: str, seconds: float) -> None:
     if outcome in _HIT_LIKE:
         _tm.counter(
             "paddle_tpu_compile_cache_hits_total",
-            "compile-cache hits (in-memory hit, in-process shared)",
+            "compile-cache hits (static-executor replay, in-process shared; not serving-engine lookups)",
             ("origin", "outcome"),
         ).labels(**lbl).inc()
     elif outcome in _MISS_LIKE:

@@ -199,8 +199,11 @@ class StepResult(_Logits):
     - `chunk`: where the step carried a chunk, the same for the chunk's last
       token (logits `[V]`, `ids()` its one id, `token()`), else None.
 
-    Waiting for either is an `engine.decode.fetch` span where it happens. A
-    result nobody reads costs nothing and leaves nothing behind."""
+    Waiting for either is an `engine.decode.fetch` span where it happens, and
+    the first read stamps `read_at` (`time.perf_counter`, the ring's clock) on
+    the step's span's args: the span ends at the dispatch, `read_at` is when
+    the step's outputs were on the host. A result nobody reads costs nothing
+    and leaves nothing behind."""
 
     __slots__ = ("chunk", "_out", "_n", "_bucket", "_args", "_ids", "_rows")
 
@@ -214,6 +217,8 @@ class StepResult(_Logits):
         want = self._out[0 if logits else 1:]
         with RecordEvent("engine.decode.fetch"):
             got = list(jax.device_get(want))
+        if self._ids is None:  # the first read: when the step's outputs were on the host
+            self._args["read_at"] = time.perf_counter()
         if logits:
             self._rows = got.pop(0)
         self._ids = got.pop(0)
@@ -544,12 +549,11 @@ class InferenceEngine:
         sz = size if isinstance(size, int) else "x".join(str(s) for s in size)
         ex = self._compiled.get(key)
         if ex is not None:
+            # a dictionary lookup, every served step: the bucket counter only
+            # (the compile ledger counts compiles, misses and shared programs)
             self.bucket_stats["hits"] += 1
             if telemetry.enabled():
                 _bucket_counter().labels(kind=kind, event="hit").inc()
-                from .. import compile_cache as _cc
-
-                _cc.record("serving", f"{kind}_{sz}", "hit")
             if _rt.enabled():
                 _rt.record_event("engine", "dispatch", kind=kind, size=sz,
                                  event="hit")
@@ -1094,16 +1098,15 @@ class InferenceEngine:
                 if self._no_ids is None:
                     self._no_ids = jnp.zeros((self._ids_width,), jnp.int32)
                 prev_ids = self._no_ids
-            if chunk is None:
-                ex = self._get_compiled("decode", B)
-                operands = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(lens), jnp.asarray(bt),
-                            prev_ids, jnp.asarray(src), *slots)
-            else:
-                ex = self._get_compiled("chunk", B)
-                operands = (jnp.asarray(tok[None]), jnp.asarray(pos[None]), jnp.asarray(lens),
-                            jnp.asarray(bt), jnp.asarray(chunk_bt), jnp.asarray(last),
-                            prev_ids, jnp.asarray(src), *slots)
-            with RecordEvent("engine.decode.dispatch"):
+            ex = self._get_compiled("decode" if chunk is None else "chunk", B)
+            with RecordEvent("engine.decode.dispatch"):  # the transfers, the call, the state adopted
+                if chunk is None:
+                    operands = (jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(lens), jnp.asarray(bt),
+                                prev_ids, jnp.asarray(src), *slots)
+                else:
+                    operands = (jnp.asarray(tok[None]), jnp.asarray(pos[None]), jnp.asarray(lens),
+                                jnp.asarray(bt), jnp.asarray(chunk_bt), jnp.asarray(last),
+                                prev_ids, jnp.asarray(src), *slots)
                 out, state = ex(self.params, *operands, self.pool.device_state())
                 self.pool.adopt_state(state)
         self._mark_first_token()

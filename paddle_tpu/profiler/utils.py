@@ -22,6 +22,13 @@ Entering a `RecordEvent` does two things:
     `Profiler` records. `records(lo, hi)` reads it, `clear()` empties it,
     `evicted()` counts what fell off its far end.
 
+The host's own pauses are records too: a `gc.callbacks` hook puts a
+`host.gc` record (`generation`, `collected`, `uncollectable`) in the ring for
+every collection of generation 1 or 2 and for any that lasted `GC_RECORD_S`
+or more, and while a capture runs annotates generations 1 and 2 as
+`paddle_tpu:host.gc` (a short generation-0 collection, hundreds a second, is
+recorded nowhere). With the ring off the hook returns after one test.
+
 `Profiler`'s host events ARE the ring's records between its start and its
 stop (plus the spans still open at the stop, closed there): no second list.
 """
@@ -29,6 +36,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import gc
 import itertools
 import threading
 import time
@@ -40,10 +48,13 @@ from ..telemetry import metrics as _metrics
 
 SPAN_PREFIX = "paddle_tpu:"
 
-# Longest cell of the benchmark: the one-client document server, about 70 s
-# of 24 ms scheduler steps (set-up calls, 40 s window, drain) at 11 spans a
-# step is 32k records; a minute of chat at 134 ms a step is 6k. Twice that.
-RING_LEN = 1 << 16
+# The readers run after the drain, so the ring must reach from the window's
+# start to then, twice over. Busiest cell since PR 35 (one step ahead):
+# longdoc-open, 796 records a second in its 40 s window and 34.5k from the
+# window's start to the read of a traced run (a capture's stop, the drain);
+# doc-single 717 a second, 29.4k (builders' chip runs, PR 36). Twice the
+# first is past 1 << 16.
+RING_LEN = 1 << 17
 
 _clock = time.perf_counter
 _ring: collections.deque = collections.deque(maxlen=RING_LEN)
@@ -51,6 +62,8 @@ _ids = itertools.count(1)
 _lock = threading.Lock()    # an append and the count of what it pushed out are one step
 _stacks: dict = {}          # thread id -> that thread's stack of open spans
 _state = {"evicted": 0, "profiling": False, "start": 0.0}
+GC_RECORD_S = 1e-3          # a generation-0 collection this long is recorded too
+_gc_done: list = []         # host.gc records not yet in the ring (see `_on_gc`)
 
 
 class TracerEventType:
@@ -88,18 +101,27 @@ def in_profiler_mode():
 
 
 # ---- the ring ----
+def _push(rec) -> None:  # under _lock
+    if len(_ring) == RING_LEN:
+        _state["evicted"] += 1
+    _ring.append(rec)
+
+
 def _append(rec) -> None:
     with _lock:
-        if len(_ring) == RING_LEN:
-            _state["evicted"] += 1
-        _ring.append(rec)
+        while _gc_done:
+            _push(_gc_done.pop(0))
+        _push(rec)
 
 
 def records(lo: Optional[float] = None, hi: Optional[float] = None) -> list:
     """The ring's records, oldest first; with `lo`/`hi` (perf_counter
     seconds) those that started at or after `lo` and ended at or before
     `hi`."""
-    out = list(_ring)
+    with _lock:
+        while _gc_done:
+            _push(_gc_done.pop(0))
+        out = list(_ring)
     if lo is not None or hi is not None:
         out = [r for r in out
                if (lo is None or r[1] >= lo) and (hi is None or r[2] <= hi)]
@@ -109,6 +131,7 @@ def records(lo: Optional[float] = None, hi: Optional[float] = None) -> list:
 def clear() -> None:
     with _lock:
         _ring.clear()
+        _gc_done.clear()
         _state["evicted"] = 0
 
 
@@ -139,6 +162,36 @@ class _Thread(threading.local):
 
 _thread = _Thread()
 _capturing = TraceAnnotation.is_enabled  # the profiler's own session check
+_gc = {"t0": 0.0, "ann": None}
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks` hook: one `host.gc` record a collection worth one. A
+    collection can start at any bytecode, inside `_append`'s lock among
+    others, so the record waits in `_gc_done` for the next `_append` or
+    `records()` to move it into the ring under the lock."""
+    if not (_metrics._enabled or _state["profiling"]):
+        return
+    if phase == "start":
+        if info["generation"] and _capturing():
+            _gc["ann"] = TraceAnnotation(SPAN_PREFIX + "host.gc")
+            _gc["ann"].__enter__()
+        _gc["t0"] = _clock()
+        return
+    t1 = _clock()
+    t0 = _gc["t0"]
+    if _gc["ann"] is not None:
+        _gc["ann"].__exit__(None, None, None)
+        _gc["ann"] = None
+    if t0 and (info["generation"] or t1 - t0 >= GC_RECORD_S):
+        _gc_done.append(("host.gc", t0, t1, next(_ids), 0, None,
+                         {"generation": info["generation"], "collected": info["collected"],
+                          "uncollectable": info["uncollectable"]},
+                         TracerEventType.UserDefined, threading.get_ident()))
+    _gc["t0"] = 0.0
+
+
+gc.callbacks.append(_on_gc)
 
 
 class RecordEvent:

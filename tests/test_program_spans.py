@@ -4,6 +4,8 @@ the ring, and the spans of the scheduler, the engine and `to_static`.
 CPU, a tiny engine and a tiny `to_static` step. What a span costs is kept
 here as a test with a loose limit; the measured figure is in PERF.md.
 """
+import contextlib
+import gc
 import glob
 import os
 import time
@@ -64,6 +66,20 @@ def _tiny_step():
     return train_step, x, y
 
 
+@contextlib.contextmanager
+def _no_collections():
+    """No automatic collection inside: a collection of generation 1 or 2 is a
+    `host.gc` record, so a test that counts the ring's records holds them
+    off (`gc.collect()` still runs, and is recorded)."""
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was:
+            gc.enable()
+
+
 def _by_name(recs):
     out = {}
     for r in recs:
@@ -76,7 +92,8 @@ def _by_name(recs):
 # ---------------------------------------------------------------------------
 
 def test_ring_nests_by_parent():
-    with RecordEvent("outer", ident=7, args={"n": 1}) as outer:
+    with _no_collections(), RecordEvent("outer", ident=7, args={"n": 1}) as outer:
+        spans.clear()
         with RecordEvent("inner"):
             pass
         with RecordEvent("second"):
@@ -87,18 +104,21 @@ def test_ring_nests_by_parent():
     assert parent == 0 and ident == 7 and args == {"n": 2}
     assert by["inner"][4] == sid and by["second"][4] == sid
     assert t0 <= by["inner"][1] <= by["inner"][2] <= by["second"][1] <= by["second"][2] <= t1
-    # children end first: the ring is in order of ending
-    assert [r[0] for r in spans.records()] == ["inner", "second", "outer"]
+    # children end first: the ring is in order of ending (a collection since is a record too)
+    assert [r[0] for r in spans.records() if r[0] != "host.gc"] == ["inner", "second", "outer"]
     # lo/hi keep what lies inside
     assert [r[0] for r in spans.records(lo=by["inner"][1], hi=by["second"][2])] == ["inner", "second"]
 
 
 def test_ring_is_bounded_and_counts_evictions():
     extra = 10
-    for _ in range(spans.RING_LEN + extra):
-        spans.record_span("x", 0.0, 1.0)
-    assert len(spans.records()) == spans.RING_LEN
-    assert spans.evicted() == extra
+    with _no_collections():
+        spans.clear()
+        for _ in range(spans.RING_LEN + extra):
+            spans.record_span("x", 0.0, 1.0)
+        n, lost = len(spans.records()), spans.evicted()
+    assert n == spans.RING_LEN
+    assert lost == extra
     spans.clear()
     assert spans.records() == [] and spans.evicted() == 0
 
@@ -110,7 +130,8 @@ def test_ring_keeps_count_under_threads():
     import sys
     import threading
 
-    n_threads, n_each = 16, 3000   # 2 records a turn: 96000 in all, past the ring's end
+    n_threads = 16
+    n_each = spans.RING_LEN // (2 * n_threads) + 1000   # 2 records a turn: past the ring's end
     was = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
 
@@ -121,18 +142,20 @@ def test_ring_keeps_count_under_threads():
                     pass
 
     try:
-        threads = [threading.Thread(target=work) for _ in range(n_threads)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=120)
+        with _no_collections():
+            spans.clear()
+            threads = [threading.Thread(target=work) for _ in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            recs, lost = spans.records(), spans.evicted()
         assert not any(t.is_alive() for t in threads)
     finally:
         sys.setswitchinterval(was)
-    recs = spans.records()
     total = 2 * n_threads * n_each
     assert total > spans.RING_LEN
-    assert len(recs) + spans.evicted() == total
+    assert len(recs) + lost == total
     assert len({r[3] for r in recs}) == len(recs)
     outer = {r[3]: r[8] for r in recs if r[0] == "outer"}
     for r in recs:
@@ -151,9 +174,11 @@ def test_ring_records_nothing_with_telemetry_off():
         assert spans.records() == []
     finally:
         paddle.set_flags({"PADDLE_TPU_TELEMETRY": True})
-    with RecordEvent("lit"):
-        pass
-    assert [r[0] for r in spans.records()] == ["lit"]
+    with _no_collections():
+        spans.clear()
+        with RecordEvent("lit"):
+            pass
+        assert [r[0] for r in spans.records()] == ["lit"]
 
 
 def test_span_cost_with_no_profiler_session():
@@ -397,6 +422,108 @@ def test_engine_compile_is_a_span_of_the_call_that_paid(tiny_engine):
 
 
 # ---------------------------------------------------------------------------
+# when a step's outputs came back (`read_at`), and the host's own pauses (`host.gc`)
+# ---------------------------------------------------------------------------
+
+def _serve(engine, n_requests=3, max_new=6, between=None):
+    """Requests through a scheduler to idle; `between(i)` runs after call i."""
+    sched = _scheduler(engine)
+    for i in range(n_requests):
+        sched.submit(_request(i, max_new=max_new))
+    i = 0
+    while not sched.idle():
+        sched.step()
+        if between is not None:
+            between(i)
+        i += 1
+    return sched
+
+
+def test_read_at_is_stamped_once_after_the_dispatch_of_the_next_step(tiny_engine):
+    _serve(tiny_engine)
+    recs = spans.records()
+    steps = sorted((r for r in recs if r[0] == "engine.decode"), key=lambda r: r[1])
+    assert len(steps) > 4 and all("read_at" in r[6] for r in steps)
+    for r in steps:
+        assert r[6]["read_at"] > r[2]  # the span ends at the dispatch; the outputs come later
+    ahead = [(prev, cur) for prev, cur in zip(steps, steps[1:]) if cur[2] < prev[6]["read_at"]]
+    # one step ahead: step j + 1 was on its way before step j was read
+    assert len(ahead) >= len(steps) - 3
+    for prev, cur in ahead:
+        (fetch,) = [f for f in recs if f[0] == "engine.decode.fetch" and cur[2] <= f[1] <= f[2] <= prev[6]["read_at"]]
+    # a later copy of the logits leaves the first read's stamp alone
+    page = tiny_engine.pool.alloc(1, owner=0)
+    try:
+        out = tiny_engine.decode(tokens=[5], positions=[0], seq_lens=[1], page_rows=[page])
+    finally:
+        tiny_engine.pool.free(page, owner=0, retain=False)
+    (dec,) = [r for r in spans.records() if r[0] == "engine.decode" and r[1] > steps[-1][2]]
+    out.ids()
+    first = dec[6]["read_at"]
+    assert np.asarray(out).shape == (1, tiny_engine.vocab_size)
+    assert dec[6]["read_at"] == first and first > dec[2]
+
+
+def test_a_result_never_read_has_no_read_at(tiny_engine):
+    for n in range(1, tiny_engine.max_batch + 1):  # the harness's warm calls
+        tiny_engine.decode(tokens=[1] * n, positions=[0] * n, seq_lens=[1] * n, page_rows=[[] for _ in range(n)])
+    calls = [r for r in spans.records() if r[0] == "engine.decode"]
+    assert len(calls) == tiny_engine.max_batch and not [r for r in calls if "read_at" in r[6]]
+
+
+def test_a_forced_collection_in_a_served_loop_is_one_host_gc_record_inside_its_step_period(tiny_engine):
+    with _no_collections():
+        _serve(tiny_engine, max_new=8, between=lambda i: gc.collect() if i == 3 else None)
+    recs = spans.records()
+    (pause,) = [r for r in recs if r[0] == "host.gc"]
+    assert pause[6]["generation"] == 2 and {"collected", "uncollectable"} <= set(pause[6])
+    assert pause[4] == 0 and pause[2] > pause[1]
+    # it lies between two reads of steps dispatched ahead: inside the period a step reader counts
+    steps = sorted((r for r in recs if r[0] == "engine.decode"), key=lambda r: r[1])
+    (held,) = [(prev, cur) for prev, cur in zip(steps, steps[1:])
+               if prev[6]["read_at"] <= pause[1] and pause[2] <= cur[6]["read_at"]]
+    assert held[1][2] < held[0][6]["read_at"]
+
+
+def test_with_the_ring_off_neither_read_at_nor_host_gc_is_in_the_ring(tiny_engine):
+    paddle.set_flags({"PADDLE_TPU_TELEMETRY": False})
+    try:
+        with _no_collections():
+            _serve(tiny_engine, n_requests=2, between=lambda i: gc.collect() if i == 1 else None)
+        assert spans.records() == []
+    finally:
+        paddle.set_flags({"PADDLE_TPU_TELEMETRY": True})
+
+
+def test_a_short_generation_0_collection_leaves_no_record_and_a_long_one_does(monkeypatch):
+    now = [100.0]
+    monkeypatch.setattr(spans, "_clock", lambda: now[0])
+
+    def collection(generation, seconds):
+        info = {"generation": generation, "collected": 3, "uncollectable": 0}
+        spans._on_gc("start", info)
+        now[0] += seconds
+        spans._on_gc("stop", info)
+        now[0] += 1.0
+
+    collection(0, 0.0002)
+    assert spans.records() == []
+    collection(0, spans.GC_RECORD_S)
+    collection(1, 0.0001)
+    got = [(r[0], r[2] - r[1], r[6]["generation"]) for r in spans.records()]
+    assert got == [("host.gc", pytest.approx(spans.GC_RECORD_S), 0), ("host.gc", pytest.approx(0.0001), 1)]
+    # and a real one: a generation-0 collection of a few objects is far under a millisecond
+    spans.clear()
+    monkeypatch.undo()
+    with _no_collections():
+        t0 = time.perf_counter()
+        gc.collect(0)
+        took = time.perf_counter() - t0
+    if took < spans.GC_RECORD_S:
+        assert not [r for r in spans.records() if r[0] == "host.gc"]
+
+
+# ---------------------------------------------------------------------------
 # to_static
 # ---------------------------------------------------------------------------
 
@@ -456,6 +583,25 @@ def test_jax_profiler_capture_holds_the_programs_spans(tmp_path, tiny_engine):
     assert {"paddle_tpu:sched.step", "paddle_tpu:sched.admit", "paddle_tpu:engine.decode",
             "paddle_tpu:engine.decode.fetch", "paddle_tpu:to_static.call",
             "paddle_tpu:to_static.dispatch"} <= names
+
+
+def test_a_capture_holds_the_hosts_collections(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True)
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(ev.name for ev in line.events)
+    assert "paddle_tpu:host.gc" in names
+    assert [r[6]["generation"] for r in spans.records() if r[0] == "host.gc"][-1] == 2
 
 
 def test_profiler_host_events_are_the_rings_records():
